@@ -941,7 +941,7 @@ def precompile(profile: Profile = BENCH, mode: str = "compile",
     import jax
 
     stats = stats or STATS
-    listener = install_cache_listener()
+    install_cache_listener()
     if log is None:
         log = lambda m: print(f"[precompile] {m}", file=sys.stderr,
                               flush=True)
@@ -961,10 +961,7 @@ def precompile(profile: Profile = BENCH, mode: str = "compile",
             if mode == "execute":
                 jax.block_until_ready(spec.call())
                 t1 = time.perf_counter()
-                cache = None
-                if listener:
-                    cache = ("hit" if stats.listener_hits > h0
-                             else "miss")
+                cache = "hit" if stats.listener_hits > h0 else "miss"
                 stats.record(spec.name, "executed", lower_s=t1 - t0,
                              cache=cache)
                 continue
@@ -975,9 +972,7 @@ def precompile(profile: Profile = BENCH, mode: str = "compile",
                 continue
             lowered.compile()
             t2 = time.perf_counter()
-            cache = None
-            if listener:
-                cache = "hit" if stats.listener_hits > h0 else "miss"
+            cache = "hit" if stats.listener_hits > h0 else "miss"
             stats.record(spec.name, "compiled", lower_s=t1 - t0,
                          compile_s=t2 - t1, cache=cache)
         except Exception as e:  # record + keep going; CLI exits nonzero

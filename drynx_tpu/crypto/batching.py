@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..resilience.policy import named_lock
+
 
 def _next_bucket(b: int, min_bucket: int = 8) -> int:
     p = min_bucket
@@ -105,6 +107,9 @@ def bucketed(fn, tail_ranks, out_tail_ranks, min_bucket: int = 8,
             hook(hook_name)
         return fn(*a, **k)
 
+    # the program's name in compile logs, profiles and the persistent cache
+    _traced_fn.__name__ = _traced_fn.__qualname__ = hook_name
+
     def _jit():
         mode = _trace_mode()
         j = jits.get(mode)
@@ -192,6 +197,19 @@ def bucketed(fn, tail_ranks, out_tail_ranks, min_bucket: int = 8,
     return wrapped
 
 
+# Host detours taken this process, by host function name (host_dispatch
+# and gt_order_ok's host branch). On the chip path every family runs as a
+# kernel, so chip_smoke.py fails a proofs-on survey that leaves anything
+# here. Proof threads dispatch concurrently, hence the lock.
+HOST_ORACLE_CALLS: dict = {}
+_HOST_COUNT_LOCK = named_lock("host_oracle_count_lock")
+
+
+def _count_host_call(name: str) -> None:
+    with _HOST_COUNT_LOCK:
+        HOST_ORACLE_CALLS[name] = HOST_ORACLE_CALLS.get(name, 0) + 1
+
+
 def host_dispatch(host_fn, tail_ranks, kernel_wrapped, gate=None):
     """Route a crypto-family op to the host backend when Pallas is
     unavailable (crypto/host_oracle.py -> the native C++ library or the
@@ -231,6 +249,7 @@ def host_dispatch(host_fn, tail_ranks, kernel_wrapped, gate=None):
             tail = a.shape[a.ndim - r:] if r else ()
             flat.append(np.ascontiguousarray(
                 np.broadcast_to(a, batch + tail)).reshape((-1,) + tail))
+        _count_host_call(host_fn.__name__)
         out = host_fn(*flat)
         if isinstance(out, tuple):
             return tuple(jnp.asarray(o.reshape(batch + o.shape[1:]))  # drynx: noqa[implicit-dtype]
@@ -469,6 +488,7 @@ def gt_order_ok(a) -> bool:
         from . import native_pairing as npair
         from . import refimpl
 
+        _count_host_call("gt_order_ok")
         flat = np.asarray(a).reshape(-1, 6, 2, params.NUM_LIMBS)
         if npair.available():  # bit-identical C++ backend
             return bool(np.all(npair.gt_order_check_batch(flat)))

@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 import threading
 
 import numpy as np
@@ -83,17 +82,12 @@ def _load():
                 fn.argtypes = args
             _LIB = lib
         except Exception as e:  # no toolchain / build error: Python oracle
-            # LOUD fallback: a silent flip to the ~80 ms/op Python path
-            # would also skip the whole parity suite (skipif-unavailable)
-            import warnings
+            from ..utils.native_build import warn_unavailable
 
-            detail = ""
-            if isinstance(e, subprocess.CalledProcessError):
-                detail = (e.stderr or "")[-500:]
-            warnings.warn(
-                f"native pairing backend unavailable ({e!r}) {detail} — "
-                f"falling back to the pure-Python oracle (30-80x slower); "
-                f"tests/test_native_pairing.py will SKIP")
+            warn_unavailable(
+                "native pairing backend", e,
+                "the pure-Python oracle (30-80x slower); "
+                "tests/test_native_pairing.py will SKIP")
             _LIB_FAILED = True
     return _LIB
 

@@ -44,10 +44,6 @@ LANES = 128                      # batch tile width
 # leaking a stale interpret-mode executable out of the jit cache.
 INTERPRET = os.environ.get("DRYNX_PALLAS_INTERPRET", "0") == "1"
 
-# jax.enable_x64 exists as a top-level context manager only on some jax
-# versions; on others (e.g. 0.4.37) it lives in jax.experimental.
-enable_x64 = getattr(jax, "enable_x64", None) or jax.experimental.enable_x64
-
 
 # ---------------------------------------------------------------------------
 # Field arithmetic on (16, B) tiles (trace-time unrolled; ~16-step chains)
@@ -295,7 +291,7 @@ def _scalar_mul_flat(p, k, n_windows: int, interpret: bool):
     np_in = jnp.asarray([[_NPRIME_FP]], dtype=jnp.uint32)
     # x64 mode would make BlockSpec index maps / loop bounds i64, which
     # Mosaic cannot legalize; every value here is uint32, so drop to x32
-    with enable_x64(False):
+    with jax.enable_x64(False):
         out = _pallas_scalar_mul(m_in, np_in, pt, kt, n_tiles, Np,
                                  n_windows, interpret)
     return jnp.transpose(out, (2, 0, 1))[:N]
@@ -392,7 +388,7 @@ def _fixed_base_mul_flat(table, k, n_windows: int, interpret: bool):
 
     m_in = jnp.asarray(_M_FP[:, None], dtype=jnp.uint32)
     np_in = jnp.asarray([[_NPRIME_FP]], dtype=jnp.uint32)
-    with enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             _fixed_base_kernel,
             grid=(n_tiles,),
@@ -490,7 +486,7 @@ def _point_add_flat(p, q, interpret: bool):
         pl.BlockSpec((3, NL, LANES), lambda i: (0, 0, i),
                      memory_space=pltpu.VMEM),
     ])
-    with enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(_point_add_kernel, interpret=interpret, **io)(m_in, np_in, pt, qt)
     return jnp.transpose(out, (2, 0, 1))[:N]
 
@@ -512,7 +508,7 @@ def _point_reduce_flat(pts, interpret: bool):
         pl.BlockSpec((R, 3, NL, LANES), lambda i: (0, 0, 0, i),
                      memory_space=pltpu.VMEM),
     ])
-    with enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(_point_reduce_kernel, interpret=interpret, **io)(m_in, np_in, pt)
     return jnp.transpose(out, (2, 0, 1))[:N]
 
@@ -524,15 +520,16 @@ def point_reduce_flat(pts):
 
 
 def available() -> bool:
-    """True when the Mosaic TPU path can run here (kill: DRYNX_NO_PALLAS=1)."""
+    """True when the Mosaic TPU path runs here (tests: DRYNX_NO_PALLAS=1).
+
+    A backend that fails to initialise raises out of here: a chip that is
+    busy or broken must not read as "no TPU", which would send the whole
+    proof family to the host oracle and return a passing survey."""
     if os.environ.get("DRYNX_NO_PALLAS", "0") == "1":
         return False
     if INTERPRET:
         return True
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 __all__ = ["scalar_mul_flat", "fixed_base_mul_flat", "point_add_flat",

@@ -32,7 +32,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import params
 from .pallas_ops import (INTERPRET, LANES, MASK, NL, _M_FP, _NPRIME_FP,
-                         enable_x64,
                          _pad_lanes, fadd, fsub, mont_mul)
 
 _XI_A = params.XI[0]          # XI = (3, 1): (x0+x1 i)(3+i)
@@ -471,7 +470,7 @@ def _miller_flat(px, py, qx, qy, interpret: bool):
         np.asarray(_ATE_BITS, dtype=np.uint32)[:, None],
         (len(_ATE_BITS), LANES)).copy(), dtype=jnp.uint32)
 
-    with enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             _miller_kernel,
             grid=(n_tiles,),
@@ -701,7 +700,7 @@ def _f12_slotmul_flat(a, which: str, interpret: bool):
                                           lambda i: (0, 0, 0),
                                           memory_space=pltpu.VMEM))
     conj_fp2 = which in ("frob1", "frob3")
-    with enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             functools.partial(_f12_slotmul_kernel, conj_fp2=conj_fp2),
             interpret=interpret, **io)(m_in, np_in, c_in, _to_tiles(a, Np))
@@ -751,7 +750,7 @@ def _f12_mul_flat(a, b, interpret: bool):
     n_tiles = max((N + LANES - 1) // LANES, 1)
     Np = n_tiles * LANES
     m_in, np_in = _mnp()
-    with enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(_f12_mul_kernel, interpret=interpret,
                              **_f12_io(n_tiles, Np, 2))(
             m_in, np_in, _to_tiles(a, Np), _to_tiles(b, Np))
@@ -774,7 +773,7 @@ def _f12_inv_flat(a, interpret: bool):
     io["in_specs"].insert(2, pl.BlockSpec(
         (len(_PM2_BITS), LANES), lambda i: (0, 0),
         memory_space=pltpu.VMEM))
-    with enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(_f12_inv_kernel, interpret=interpret, **io)(
             m_in, np_in, bits_in, _to_tiles(a, Np))
     return _from_tiles(out, N)
@@ -799,7 +798,7 @@ def _f12_pow_flat(f, k, n_bits: int, interpret: bool):
                                           memory_space=pltpu.VMEM))
     io["in_specs"].append(pl.BlockSpec((NL, LANES), lambda i: (0, i),
                                        memory_space=pltpu.VMEM))
-    with enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             functools.partial(_f12_pow_kernel, n_bits=n_bits),
             scratch_shapes=[pltpu.VMEM((n_bits, LANES), jnp.uint32)],
@@ -831,7 +830,7 @@ def _f12_wpow_flat(f, k, n_bits: int, wbits: int, cyc: bool,
                                           memory_space=pltpu.VMEM))
     io["in_specs"].append(pl.BlockSpec((NL, LANES), lambda i: (0, i),
                                        memory_space=pltpu.VMEM))
-    with enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             functools.partial(_f12_wpow_kernel, n_bits=n_bits, wbits=wbits,
                               cyc=cyc),
@@ -860,7 +859,7 @@ def _f12_csqr_flat(a, interpret: bool):
     n_tiles = max((N + LANES - 1) // LANES, 1)
     Np = n_tiles * LANES
     m_in, np_in = _mnp()
-    with enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(_f12_csqr_kernel, interpret=interpret,
                              **_f12_io(n_tiles, Np, 1))(
             m_in, np_in, _to_tiles(a, Np))
@@ -884,7 +883,7 @@ def _f12_mulreduce8_flat(g, interpret: bool):
     io["in_specs"].append(pl.BlockSpec((8, 12, NL, LANES),
                                        lambda i: (0, 0, 0, i),
                                        memory_space=pltpu.VMEM))
-    with enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(_f12_mulreduce8_kernel, interpret=interpret,
                              **io)(m_in, np_in, gt)
     return _from_tiles(out, N)
@@ -964,7 +963,7 @@ def _fp_inv_flat(x, interpret: bool):
     m_in, np_in = _mnp()
     bits_in = jnp.asarray(_pm2_bits_tiles(), dtype=jnp.uint32)
     xt = _pad_lanes(jnp.transpose(x, (1, 0)), Np)
-    with enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             _fp_inv_kernel,
             grid=(n_tiles,),
@@ -1009,7 +1008,7 @@ def _f2_inv_flat(a, interpret: bool):
     m_in, np_in = _mnp()
     bits_in = jnp.asarray(_pm2_bits_tiles(), dtype=jnp.uint32)
     at = _pad_lanes(jnp.transpose(a, (1, 2, 0)), Np)
-    with enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             _f2_inv_kernel,
             grid=(n_tiles,),
@@ -1163,7 +1162,7 @@ def _g2_scalar_mul_flat(p, k, interpret: bool):
     pt = _pad_lanes(jnp.transpose(p.reshape(N, 6, NL), (1, 2, 0)), Np)
     kt = _pad_lanes(jnp.transpose(k, (1, 0)), Np)
     m_in, np_in = _mnp()
-    with enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             _g2_scalar_mul_kernel,
             grid=(n_tiles,),

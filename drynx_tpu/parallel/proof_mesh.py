@@ -41,19 +41,14 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
+from jax.sharding import PartitionSpec as P
 
 from ..crypto import curve as C
 from ..crypto import fp12 as F12
 from ..crypto import pairing as PAIR
 from ..crypto import params
 from . import collective as col
-
-try:
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # newer jax
-    from jax import shard_map
-
-from jax.sharding import PartitionSpec as P
 
 
 def _flatten_pad(n_dev: int, *arrs):
@@ -144,7 +139,7 @@ def rlc_total_sharded(mesh, proof, sigs_pub, r_int, gtb_pow_s):
     f = jax.jit(shard_map(
         shard, mesh=flat_mesh,
         in_specs=(spec, spec, spec, spec, spec, spec, spec),
-        out_specs=(P(), P()), check_rep=False))
+        out_specs=(P(), P()), check_vma=False))
     m_tot, a_tot = f(px, py, qx, qy, ca, rr,
                      mask.astype(jnp.uint32))
     fe = PAIR.final_exp(m_tot[None])[0]

@@ -70,7 +70,11 @@ def _load_lib():
             lib.pdb_close.restype = None
             lib.pdb_close.argtypes = [ctypes.c_void_p]
             _LIB = lib
-        except Exception:
+        except Exception as e:  # no toolchain / build error
+            from ..utils.native_build import warn_unavailable
+
+            warn_unavailable("native proof store", e,
+                             "the pure-Python ProofDB")
             _LIB_FAILED = True
     return _LIB
 

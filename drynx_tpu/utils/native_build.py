@@ -2,9 +2,10 @@
 
 One copy of the concurrent-build rules used by every ctypes binding
 (crypto/native_pairing.py, service/store.py):
-  * staleness = sha256 of (compiler flags, every source file's bytes) in a
-    stamp file next to the .so — so a flag change or a tree moved between
-    hosts (-march=native!) rebuilds, which a bare mtime check misses;
+  * staleness = sha256 of (compiler flags, the host CPU's identity, every
+    source file's bytes) in a stamp file next to the .so — so a flag
+    change, or a tree copied to a machine with another CPU (the flags
+    include -march=native), rebuilds; a bare mtime check misses both;
   * compile to a per-pid temp name and os.replace into place — parallel
     test processes (per-file isolation) may all build at once, and none
     may ever dlopen a half-written ELF;
@@ -15,10 +16,26 @@ from __future__ import annotations
 
 import hashlib
 import os
+import platform
 import subprocess
+import warnings
 
 FLAGS = ["-O3", "-march=native", "-funroll-loops",
          "-shared", "-fPIC", "-std=c++17"]
+
+
+def _cpu_identity() -> str:
+    """What -march=native compiles for: the CPU's model and feature flags
+    as the kernel reports them. A library stamped on one CPU must never be
+    dlopen'ed on another (SIGILL at the first unsupported instruction)."""
+    ident = [platform.machine()]
+    try:
+        with open("/proc/cpuinfo") as f:
+            ident += sorted({ln.strip() for ln in f if ln.startswith(
+                ("model name", "flags", "Features"))})
+    except OSError:
+        ident.append(platform.processor())
+    return "\n".join(ident)
 
 
 def build_native_lib(srcs: list[str], lib_path: str,
@@ -28,6 +45,7 @@ def build_native_lib(srcs: list[str], lib_path: str,
     staleness hash."""
     flags = FLAGS if flags is None else flags
     h = hashlib.sha256(" ".join(flags).encode())
+    h.update(_cpu_identity().encode())
     for s in srcs:
         with open(s, "rb") as f:
             h.update(f.read())
@@ -58,4 +76,15 @@ def build_native_lib(srcs: list[str], lib_path: str,
     return lib_path
 
 
-__all__ = ["FLAGS", "build_native_lib"]
+def warn_unavailable(what: str, e: Exception, fallback: str) -> None:
+    """The LOUD fallback every binding uses when its library cannot be
+    built or loaded: a silent flip to the slow path would also skip the
+    parity tests that are skipif-unavailable."""
+    detail = ""
+    if isinstance(e, subprocess.CalledProcessError):
+        detail = (e.stderr or "")[-500:]
+    warnings.warn(f"{what} unavailable ({e!r}) {detail} — "
+                  f"falling back to {fallback}")
+
+
+__all__ = ["FLAGS", "build_native_lib", "warn_unavailable"]

@@ -149,9 +149,6 @@ def _child_env(overrides, delay_ms):
         # dispatch_shards (enqueue/upload/block spans) actually runs
         flags += " --xla_force_host_platform_device_count=8"
     env["XLA_FLAGS"] = flags.strip()
-    cache = os.environ.get("DRYNX_BENCH_JAX_CACHE") or \
-        os.path.join(ROOT, ".jax_cache_bench")
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", cache)
     env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
     env["DRYNX_LINK_DELAY_MS"] = str(delay_ms)
     env["DRYNX_LINK_MBPS"] = "100.0"
@@ -538,6 +535,9 @@ def main():
     ap.add_argument("--record-path", default=None)
     args = ap.parse_args()
     if args.measure_child:
+        from drynx_tpu.utils.cache import enable_compilation_cache
+
+        enable_compilation_cache()
         sys.exit(main_child(args))
     sys.exit(main_parent(args))
 

@@ -126,9 +126,6 @@ def _child_env():
         # compile for tens of minutes on this box
         flags += " --xla_backend_optimization_level=0"
     env["XLA_FLAGS"] = flags.strip()
-    cache = os.environ.get("DRYNX_BENCH_JAX_CACHE") or \
-        os.path.join(ROOT, ".jax_cache_bench")
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", cache)
     env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
     # the sections construct servers with explicit knobs; a stray
     # operator override must not skew the curve
@@ -487,6 +484,9 @@ def main():
     ap.add_argument("--timeout", type=float)
     args = ap.parse_args()
     if args.child:
+        from drynx_tpu.utils.cache import enable_compilation_cache
+
+        enable_compilation_cache()
         sys.exit(main_child(args))
     sys.exit(main_parent(args))
 

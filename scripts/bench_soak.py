@@ -129,9 +129,6 @@ def _child_env():
     if "xla_backend_optimization_level" not in flags:
         flags += " --xla_backend_optimization_level=0"
     env["XLA_FLAGS"] = flags.strip()
-    cache = os.environ.get("DRYNX_BENCH_JAX_CACHE") or \
-        os.path.join(ROOT, ".jax_cache_bench")
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", cache)
     env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
     for k in ("DRYNX_TOPOLOGY", "DRYNX_TREE_FANOUT", "DRYNX_FANOUT",
               "DRYNX_PROBE_TTL"):
@@ -573,6 +570,9 @@ def child_multiproc(args):
     rng = np.random.default_rng(DATA_SEED)
     env = dict(os.environ)
     env["DRYNX_PROOF_PLANE"] = "off"
+    # the node processes share this child's persistent cache
+    from drynx_tpu.utils.cache import ENV_VAR, enable_compilation_cache
+    env[ENV_VAR] = enable_compilation_cache()
     procs, entries, datas = [], [], []
     cn = None
     wr("boot", n_dps=MP_DPS)
@@ -703,6 +703,9 @@ def main():
     args = ap.parse_args()
     if args.measure_child:
         global _REC_PATH
+        from drynx_tpu.utils.cache import enable_compilation_cache
+
+        enable_compilation_cache()
         _REC_PATH = args.record_path
         if args.sched:
             sys.exit(child_sched(args))

@@ -113,9 +113,6 @@ def _child_env():
     if "xla_backend_optimization_level" not in flags:
         flags += " --xla_backend_optimization_level=0"
     env["XLA_FLAGS"] = flags.strip()
-    cache = os.environ.get("DRYNX_BENCH_JAX_CACHE") or \
-        os.path.join(ROOT, ".jax_cache_bench")
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", cache)
     env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
     for k in ("DRYNX_PANE_WIDTH", "DRYNX_STREAM_WINDOW",
               "DRYNX_EPSILON_BUDGET", "DRYNX_EPSILON_PER_ADVANCE",
@@ -447,6 +444,9 @@ def main():
     args = ap.parse_args()
     if args.measure_child:
         global _REC_PATH
+        from drynx_tpu.utils.cache import enable_compilation_cache
+
+        enable_compilation_cache()
         _REC_PATH = args.record_path
         if args.epsilon:
             sys.exit(child_epsilon(args))

@@ -125,9 +125,6 @@ def _child_env():
     if "xla_backend_optimization_level" not in flags:
         flags += " --xla_backend_optimization_level=0"
     env["XLA_FLAGS"] = flags.strip()
-    cache = os.environ.get("DRYNX_BENCH_JAX_CACHE") or \
-        os.path.join(ROOT, ".jax_cache_bench")
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", cache)
     env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
     # children install the LinkModel themselves AFTER warmup; topology
     # and fanout are flipped per measured survey inside the child
@@ -493,6 +490,9 @@ def child_multiproc(args):
     roles = ["cn"] + ["dp"] * MULTIPROC_DPS
     env = dict(os.environ)
     env["DRYNX_PROOF_PLANE"] = "off"   # per-process plane policy
+    # the node processes share this child's persistent cache
+    from drynx_tpu.utils.cache import ENV_VAR, enable_compilation_cache
+    env[ENV_VAR] = enable_compilation_cache()
     procs, entries, datas = [], [], []
     wr("boot", n_procs=len(roles))
     try:
@@ -572,6 +572,9 @@ def main():
     args = ap.parse_args()
     if args.measure_child:
         global _REC_PATH
+        from drynx_tpu.utils.cache import enable_compilation_cache
+
+        enable_compilation_cache()
         _REC_PATH = args.record_path
         if args.transcript:
             sys.exit(child_transcript(args))

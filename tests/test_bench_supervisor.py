@@ -134,6 +134,44 @@ def test_result_nonzero_rc_strips_stale_metric_fields():
     assert out["error"] == "boom"
 
 
+@pytest.mark.parametrize("outcome,rc,record,code", [
+    ("ok", 0, {"stage": "complete", "metric": "m", "value": 1.0}, 0),
+    ("ok", 0, {"stage": "starting"}, 1),
+    ("rc:1", 1, {"stage": "failed", "error": "boom"}, 1),
+    ("rc:1", 1, {"stage": "complete", "metric": "m", "value": 1.0}, 1),
+    ("signal:SIGSEGV", -11, {"stage": "warmup_done"}, 1),
+    ("timeout", None, {}, 1),
+])
+def test_parent_exit_code_is_zero_only_for_a_complete_record(
+        outcome, rc, record, code):
+    result = bench.supervisor_result(outcome, rc, 1.0, record, "ok")
+    assert bench.result_exit_code(result) == code
+
+
+def test_failed_proofs_on_child_has_no_fallback_metric(tmp_path):
+    """The exec-only fallback is gone: a measured child whose proofs-on run
+    raises files a 'failed' record and exits non-zero, and the parent's
+    line for it is a failure label with a non-zero exit code."""
+    path = str(tmp_path / "rec.json")
+    code = (
+        "import sys; sys.path.insert(0, %r); import bench\n"
+        "def boom(): raise RuntimeError('proofs-on died')\n"
+        "bench._proofs_on_cluster = boom\n"
+        "bench._RECORD_PATH = %r\n"
+        "sys.argv.append(bench.NO_CACHE_FLAG)\n"
+        "sys.exit(bench.main_child())\n"
+        % (os.path.dirname(os.path.abspath(bench.__file__)), path))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    outcome, rc, elapsed, _ = bench.supervise_child([PY, "-c", code], 120,
+                                                    env=env)
+    assert outcome == "rc:1"
+    rec = bench.read_record(path)
+    assert rec["stage"] == "failed" and "proofs-on died" in rec["error"]
+    result = bench.supervisor_result(outcome, rc, elapsed, rec, "ok")
+    assert result["metric"] == "bench_child_failed_rc1"
+    assert bench.result_exit_code(result) == 1
+
+
 # ---------------------------------------------------------------------------
 # the one-JSON-line contract + record round-trip
 # ---------------------------------------------------------------------------

@@ -9,12 +9,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-try:
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # newer jax
-    from jax import shard_map
 
 from drynx_tpu.crypto import elgamal as eg
 from drynx_tpu.parallel import collective as col
@@ -55,7 +51,7 @@ def test_aggregate_then_keyswitch(setup):
 
     f = shard_map(prog, mesh=s["mesh"],
                   in_specs=(P("srv"), P("srv"), P("srv")),
-                  out_specs=P("srv"), check_rep=False)
+                  out_specs=P("srv"), check_vma=False)
     out = f(cts, xs, rs)  # (NS, 2, 3, 16) — identical switched ct per device
 
     dec, found = eg.decrypt_ints(out[0], s["qx"], s["table"])
@@ -75,7 +71,7 @@ def test_obfuscation_preserves_zero_semantics(setup):
         return col.obfuscate_collective(ct[0], sc[0], "srv", NS)
 
     f = shard_map(prog, mesh=s["mesh"], in_specs=(P("srv"), P("srv")),
-                  out_specs=P("srv"), check_rep=False)
+                  out_specs=P("srv"), check_vma=False)
     out = f(cts, scalars)
 
     xsum = sum(s["secrets"])  # decrypt under collective secret
@@ -96,7 +92,7 @@ def test_allreduce_scalar_product_matches_host(setup):
         return col.allreduce_scalar_mul(x, "srv", NS)
 
     f = shard_map(prog, mesh=s["mesh"], in_specs=(P("srv"),),
-                  out_specs=P("srv"), check_rep=False)
+                  out_specs=P("srv"), check_vma=False)
     out = f(sc)
     ints = F.to_int(np.asarray(sc))
     want = 1

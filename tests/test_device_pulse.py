@@ -294,6 +294,13 @@ def test_rotation_covers_all_validated_kernels():
     assert {m for _, m, _ in ROTATION} == {"execute", "trace", "glue"}
 
 
+@pytest.mark.slow(
+    reason="30-190 s depending on the calendar day (159 s on the day of "
+           "PR 21), in a one-process tier-1 that came back from 161 s to "
+           "over 1400 s cold when its imports were repaired; what its "
+           "trace entries checked one kernel a day, tests/test_tpu_compile"
+           ".py now checks for every kernel in every run, by compiling it "
+           "for the chip")
 def test_rotating_kernel_pulse():
     idx = rotation_index()
     name, mode, fn = ROTATION[idx]

@@ -1,0 +1,199 @@
+"""Compile the main path's Pallas kernels for a described TPU v5e, no chip.
+
+The TPU compiler is installed in the CPU sandbox and compiles for a chip
+that is described, not attached (on-chip-measurement guide, section 2,
+rehearsal 3). Each case lowers one kernel entry point at the bucket the
+registry dispatches (compilecache/registry.py: 2048 for the flat pairing
+family) with interpret=False and checks that a Mosaic kernel
+(`tpu_custom_call`) is in the compiled program. Nothing runs, so this says
+nothing about results or times on the device; it catches block shapes,
+VMEM budgets and dtypes that Mosaic refuses before any chip time is spent.
+
+This is the only test file that describes a topology. Only one process may
+load libtpu at a time, so the call lives in a module-scoped fixture and
+never runs at import; see the guide for why.
+
+Each case prints one `TPU_COMPILE {...}` line with its lower and compile
+seconds (sandbox seconds, not device metrics; run with -s to see them).
+A case stays in tier-1 only while it takes under 30 s alone on the 8-core
+sandbox and the file under 120 s in all; the others are marked slow with
+the lower+compile seconds measured there in PR 21 (seven cases at a time,
+so some 1.5x what each takes alone).
+"""
+import json
+import os
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from drynx_tpu.crypto import pallas_ops as po
+from drynx_tpu.crypto import pallas_pairing as pp
+
+NL = po.NL
+B = 2048        # registry._FLAT: the pairing family's max_bucket
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described device is written to the persistent cache
+    but cannot be read back without a chip: keep it off around these."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _slow(seconds):
+    return pytest.mark.slow(
+        reason=f"{seconds} s lower+compile on the 8-core sandbox (PR 21)")
+
+
+# name -> builder(s) returning the jax.stages.Lowered; `s(shape)` gives a
+# ShapeDtypeStruct (uint32 unless told) placed on the described chip.
+def _g1(s, n=B):
+    return s((n, 3, NL))
+
+
+def _gt(s, n=B):
+    return s((n, 6, 2, NL))
+
+
+def _k(s, n=B):
+    return s((n, NL))
+
+
+def _pair_args(s, n=B):
+    return s((n, NL)), s((n, NL)), s((n, 2, NL)), s((n, 2, NL))
+
+
+CASES = [
+    ("point_add_flat",
+     lambda s: po._point_add_flat.lower(_g1(s), _g1(s), interpret=False)),
+    ("point_reduce_flat/R3",
+     lambda s: po._point_reduce_flat.lower(s((3, B, 3, NL)),
+                                           interpret=False)),
+    ("point_reduce_flat/R10",
+     lambda s: po._point_reduce_flat.lower(s((10, B, 3, NL)),
+                                           interpret=False)),
+    ("fixed_base_mul_flat/64w",
+     lambda s: po._fixed_base_mul_flat.lower(
+         s((64, 16, 3, NL)), _k(s), n_windows=64, interpret=False)),
+    ("fixed_base_mul_flat/16w",
+     lambda s: po._fixed_base_mul_flat.lower(
+         s((64, 16, 3, NL)), _k(s), n_windows=16, interpret=False)),
+    ("f12_csqr_flat",
+     lambda s: pp._f12_csqr_flat.lower(_gt(s), interpret=False)),
+    ("f12_mul_flat",
+     lambda s: pp._f12_mul_flat.lower(_gt(s), _gt(s), interpret=False)),
+    ("f12_slotmul_flat/frob1",
+     lambda s: pp._f12_slotmul_flat.lower(_gt(s), which="frob1",
+                                          interpret=False)),
+    ("f12_slotmul_flat/frob2",
+     lambda s: pp._f12_slotmul_flat.lower(_gt(s), which="frob2",
+                                          interpret=False)),
+    ("fp_inv_flat",
+     lambda s: pp._fp_inv_flat.lower(_k(s), interpret=False)),
+    ("f2_inv_flat",
+     lambda s: pp._f2_inv_flat.lower(s((B, 2, NL)), interpret=False)),
+    ("f12_inv_flat",
+     lambda s: pp._f12_inv_flat.lower(_gt(s), interpret=False)),
+    ("scalar_mul_flat/16w",
+     lambda s: po._scalar_mul_flat.lower(_g1(s), _k(s), n_windows=16,
+                                         interpret=False)),
+    ("scalar_mul_flat/64w",
+     lambda s: po._scalar_mul_flat.lower(_g1(s), _k(s), n_windows=64,
+                                         interpret=False)),
+    ("f12_mulreduce8_flat",
+     lambda s: pp._f12_mulreduce8_flat.lower(s((B, 8, 6, 2, NL)),
+                                             interpret=False)),
+    ("f12_wpow_flat/63c",
+     lambda s: pp._f12_wpow_flat.lower(_gt(s), _k(s), n_bits=63, wbits=3,
+                                       cyc=True, interpret=False)),
+    ("f12_wpow_flat/128c",
+     lambda s: pp._f12_wpow_flat.lower(_gt(s), _k(s), n_bits=128, wbits=3,
+                                       cyc=True, interpret=False)),
+    ("f12_wpow_flat/256c",
+     lambda s: pp._f12_wpow_flat.lower(_gt(s), _k(s), n_bits=256, wbits=3,
+                                       cyc=True, interpret=False)),
+    ("g2_scalar_mul_flat",
+     lambda s: pp._g2_scalar_mul_flat.lower(s((B, 3, 2, NL)), _k(s),
+                                            interpret=False)),
+    ("miller_flat",
+     lambda s: pp._miller_flat.lower(*_pair_args(s), interpret=False)),
+    # the three below are compositions of the kernels above under one jit,
+    # as batching.bucketed dispatches them (final_exp@8, pair@2048,
+    # gt_pow_fixed_multi@2048 with the 3 CN x u=16 window tables)
+    ("final_exp_flat@8",
+     lambda s: jax.jit(pp.final_exp_flat).lower(_gt(s, 8))),
+    ("pair_flat",
+     lambda s: jax.jit(pp.pair_flat).lower(*_pair_args(s))),
+    ("gt_pow_fixed_multi",
+     lambda s: jax.jit(pp.gt_pow_fixed_multi).lower(
+         s((48, 64, 16, 6, 2, NL)), s((B,), jnp.int32), _k(s))),
+]
+
+_MARKS = {
+    "f12_mul_flat": _slow(43),
+    "f12_inv_flat": _slow(108),
+    "point_reduce_flat/R10": _slow(129),
+    "scalar_mul_flat/16w": _slow(197),
+    "scalar_mul_flat/64w": _slow(222),
+    "miller_flat": _slow(385),
+    "f12_wpow_flat/128c": _slow(462),
+    "f12_wpow_flat/63c": _slow(478),
+    "f12_mulreduce8_flat": _slow(482),
+    "f12_wpow_flat/256c": _slow(495),
+    "g2_scalar_mul_flat": _slow(700),
+    "final_exp_flat@8": _slow(745),
+    "pair_flat": _slow(852),
+    "gt_pow_fixed_multi": _slow(941),
+}
+
+
+@pytest.mark.parametrize(
+    "name,build",
+    [pytest.param(n, b, id=n, marks=_MARKS.get(n, ())) for n, b in CASES])
+def test_kernel_compiles_for_v5e(name, build, one_chip, no_persistent_cache,
+                                 monkeypatch):
+    # the composed entry points read the module flag at trace time
+    monkeypatch.setattr(po, "INTERPRET", False)
+    monkeypatch.setattr(pp, "INTERPRET", False)
+
+    def s(shape, dtype=jnp.uint32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    t0 = time.perf_counter()
+    lowered = build(s)
+    t1 = time.perf_counter()
+    compiled = lowered.compile()
+    t2 = time.perf_counter()
+    mem = compiled.memory_analysis()
+    print("TPU_COMPILE " + json.dumps({
+        "kernel": name, "lower_s": round(t1 - t0, 1),
+        "compile_s": round(t2 - t1, 1),
+        "code_bytes": mem.generated_code_size_in_bytes,
+        "temp_bytes": mem.temp_size_in_bytes}))
+    assert "tpu_custom_call" in compiled.as_text()
