@@ -54,41 +54,26 @@ def emit(obj) -> None:
 
 
 class CompileMeter:
-    """Sums jax's own compile events (jax.monitoring) so each phase can
+    """A view of the program's own tracer (drynx_tpu/utils/timers.py), whose
+    jax.monitoring listener sums jax's compile events, so each phase can
     report what it compiled. `requests` counts backend compile requests,
     persistent-cache hits included; a warm in-process run makes none.
     Trace events nest (an inner jit's trace is inside its caller's), so
     they only go to stderr; lower and compile events do not nest."""
 
-    EVENTS = {"/jax/core/compile/jaxpr_trace_duration": "trace",
-              "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
-              "/jax/core/compile/backend_compile_duration": "compile"}
-
     def __init__(self):
-        from jax import monitoring
+        from drynx_tpu.utils import timers
 
-        from drynx_tpu import compilecache as cc
-
-        cc.install_cache_listener()
-        self._stats = cc.STATS
-        self.totals = {"lower_seconds": 0.0, "compile_seconds": 0.0,
-                       "requests": 0}
-        monitoring.register_event_duration_secs_listener(self._on_duration)
-
-    def _on_duration(self, event: str, seconds: float, **kw) -> None:
-        kind = self.EVENTS.get(event)
-        if kind is None:
-            return
-        if kind != "trace":
-            self.totals[f"{kind}_seconds"] += seconds
-        if kind == "compile":
-            self.totals["requests"] += 1
-        if seconds >= 1.0:
-            print(f"[{kind}] {kw.get('fun_name', '?')}: {seconds:.1f}s",
-                  file=sys.stderr, flush=True)
+        timers.install_listener()
+        self._tracer = timers.PROCESS
+        self._tracer.echo_over_s = 1.0  # such jax events go to stderr
 
     def snapshot(self) -> dict:
-        return dict(self.totals, hits=self._stats.listener_hits)
+        t = self._tracer
+        return {"lower_seconds": t["jax/lower"],
+                "compile_seconds": t["jax/compile"],
+                "requests": t.counter("compile_requests"),
+                "hits": t.counter("cache_hits")}
 
 
 def run_phase(name: str, meter: CompileMeter, cache_dir: str, fn) -> None:

@@ -10,9 +10,9 @@ the same rationale as PhaseTimers.echo (utils/timers.py).
 from __future__ import annotations
 
 import sys
-import threading
 
 from ..resilience.policy import named_lock
+from ..utils import timers
 
 
 class CompileStats:
@@ -35,9 +35,13 @@ class CompileStats:
         self.rows: dict[str, dict] = {}
         self.persistent_hits = 0
         self.persistent_misses = 0
-        # raw event count from the jax.monitoring listener; the serial
-        # driver diffs it around each .compile() to classify hit/miss
-        self.listener_hits = 0
+
+    @property
+    def listener_hits(self) -> int:
+        """Raw count of persistent-cache hits from the program's one
+        jax.monitoring listener (utils/timers.py); the serial driver diffs
+        it around each .compile() to classify hit/miss."""
+        return timers.PROCESS.counter("cache_hits")
 
     def record(self, name: str, status: str, lower_s: float = 0.0,
                compile_s: float = 0.0, cache: str | None = None,
@@ -121,29 +125,13 @@ class CompileStats:
 # bench.py reads .headline() into the bonus JSON keys.
 STATS = CompileStats()
 
-_LISTENER_INSTALLED = False
-
-
-# The event jax records on every persistent-cache deserialization
-# (jax/_src/compiler.py of the installed jax 0.9).
-CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+# The event jax records on every persistent-cache deserialization.
+CACHE_HIT_EVENT = timers.CACHE_HIT_EVENT
 
 
 def install_cache_listener() -> None:
-    """Count persistent-compilation-cache hits via jax.monitoring.
-
-    Idempotent. A jax without the monitoring API raises here; hit counts
-    are what the chip smoke and the bench report as evidence that the
-    cache works, so they are never silently zero."""
-    global _LISTENER_INSTALLED
-    if _LISTENER_INSTALLED:
-        return
-    from jax import monitoring
-
-    def _on_event(event: str, **_kw) -> None:
-        if event == CACHE_HIT_EVENT:
-            with STATS._lock:
-                STATS.listener_hits += 1
-
-    monitoring.register_event_listener(_on_event)
-    _LISTENER_INSTALLED = True
+    """Count persistent-compilation-cache hits: installs the program's one
+    jax.monitoring listener (utils/timers.install_listener; idempotent,
+    raises on a jax without the monitoring API), which `listener_hits`
+    reads."""
+    timers.install_listener()

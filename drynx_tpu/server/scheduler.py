@@ -377,7 +377,8 @@ class SurveyServer:
                               only=lambda s: (s.family == "device"
                                               and s.op in cc.WORKER_OPS),
                               log=lambda m: log.lvl2(f"server warm: {m}"))
-        self.timers.span(f"Compile.{survey_id}", t0, time.perf_counter())
+        self.timers.span(f"Compile.{survey_id}", t0, time.perf_counter(),
+                         survey=survey_id)
         self.admission.note_warmed(profile)
 
     def _promote(self, entry: _Entry) -> None:
@@ -429,7 +430,8 @@ class SurveyServer:
             self.refill_slabs += 1
             if pool.dro_balance(digest) >= target:
                 break
-        self.timers.span(f"Refill.{sid}", t0, time.perf_counter())
+        self.timers.span(f"Refill.{sid}", t0, time.perf_counter(),
+                         survey=sid)
         entry.admission = self.admission.triage(entry.sq,
                                                 tenant=entry.tenant)
         with self._lock:
@@ -577,7 +579,7 @@ class SurveyServer:
                                                 responders=e.responders)
             except Exception as exc:
                 self.timers.span(f"Pipeline.encode.{sid}",
-                                 t0, time.perf_counter())
+                                 t0, time.perf_counter(), survey=sid)
                 budget = self._resume_budget(sid)
                 if e.retries < budget:
                     # survey resume: re-probe liveness, carry the
@@ -612,7 +614,7 @@ class SurveyServer:
                 self._record_error(sid, exc)
                 continue
             self.timers.span(f"Pipeline.encode.{sid}",
-                             t0, time.perf_counter())
+                             t0, time.perf_counter(), survey=sid)
             pendings.append(p)
         if not pendings:
             return
@@ -700,7 +702,7 @@ class SurveyServer:
                 self._record_error(sid, exc)
             finally:
                 self.timers.span(f"Pipeline.verify.{sid}",
-                                 t0, time.perf_counter())
+                                 t0, time.perf_counter(), survey=sid)
 
     # -- outcome recording (any thread) ------------------------------------
 

@@ -46,7 +46,7 @@ from ..proofs import shuffle as shuffle_proof
 from ..resilience import faults
 from ..resilience import policy as rp
 from ..utils import log
-from ..utils.timers import PhaseTimers
+from ..utils.timers import PROCESS, PhaseTimers, install_listener
 from . import topology as topo
 from .proof_collection import VerifyCache, VerifyingNode, VNGroup
 from .store import ProofDB, SurveyCheckpoint
@@ -105,7 +105,7 @@ class Survey:
 
     def __init__(self, sq: SurveyQuery):
         self.sq = sq
-        self.timers = PhaseTimers()
+        self.timers = PhaseTimers(sq.survey_id)
         self.proof_threads: list[threading.Thread] = []
         # streaming surveys (PR 18): a per-advance survey registered by a
         # StreamEngine carries its engine here so the VN-side range
@@ -122,6 +122,7 @@ class LocalCluster:
     (reference services/service_test.go:29-66 generateNodes/repartitionDPs).
     """
 
+    @PROCESS.step("setup/cluster")
     def __init__(self, n_cns: int = 3, n_dps: int = 5, n_vns: int = 3,
                  seed: int = 1, dlog_limit: int = 10000,
                  link=None, share_verify_cache: bool = True,
@@ -132,6 +133,7 @@ class LocalCluster:
         # on a worker thread is the r05 segfault class); "on" forces the
         # warmup on any backend; "off" disables it (compilecache/registry).
         assert precompile in ("auto", "on", "off"), precompile
+        install_listener()      # jax's trace/lower/compile events -> PROCESS
         # link: an optional transport.LinkModel; when active, the in-process
         # cluster sleeps at every boundary where the reference pays a real
         # network message (DP ciphertext upload, proof delivery to each VN),
@@ -151,17 +153,21 @@ class LocalCluster:
             pool_mod.activate(pool)
         rng = np.random.default_rng(seed)
         self.rng = rng
-        self.cns = [_new_identity(f"cn{i}", rng) for i in range(n_cns)]
-        self.dp_idents = [_new_identity(f"dp{i}", rng) for i in range(n_dps)]
-        self.vn_idents = [_new_identity(f"vn{i}", rng) for i in range(n_vns)]
-        self.client = _new_identity("client", rng)
-
-        # collective key over the CN roster
-        self.coll_pub = col.collective_key([c.public for c in self.cns])
-        self.coll_tbl = eg.pub_table(self.coll_pub)
-        self.client_tbl = eg.pub_table(self.client.public)
-        self.client_pt = jnp.asarray(C.from_ref(self.client.public))
-        self.dlog = eg.DecryptionTable(limit=dlog_limit)
+        with PROCESS.step("keys"):
+            self.cns = [_new_identity(f"cn{i}", rng) for i in range(n_cns)]
+            self.dp_idents = [_new_identity(f"dp{i}", rng)
+                              for i in range(n_dps)]
+            self.vn_idents = [_new_identity(f"vn{i}", rng)
+                              for i in range(n_vns)]
+            self.client = _new_identity("client", rng)
+            # collective key over the CN roster
+            self.coll_pub = col.collective_key([c.public for c in self.cns])
+        with PROCESS.step("tables"):
+            self.coll_tbl = eg.pub_table(self.coll_pub)
+            self.client_tbl = eg.pub_table(self.client.public)
+            self.client_pt = jnp.asarray(C.from_ref(self.client.public))
+        with PROCESS.step("dlog_table"):
+            self.dlog = eg.DecryptionTable(limit=dlog_limit)
 
         # DP -> CN mapping (reference repartitionDPs round robin)
         self.server_to_dp = {}
@@ -191,13 +197,14 @@ class LocalCluster:
             # undeduped control configuration bench.py --no-verify-cache
             # records next to the headline.
             shared_cache = VerifyCache()
-            self.vns = VNGroup([
-                VerifyingNode(v.name, f"{self._vn_dir}/{v.name}.db", pubs,
-                              verify_fns=self._verify_fns(), seed=i,
-                              verify_cache=(shared_cache
-                                            if share_verify_cache
-                                            else VerifyCache(maxsize=0)))
-                for i, v in enumerate(self.vn_idents)])
+            with PROCESS.step("vns"):
+                self.vns = VNGroup([
+                    VerifyingNode(v.name, f"{self._vn_dir}/{v.name}.db", pubs,
+                                  verify_fns=self._verify_fns(), seed=i,
+                                  verify_cache=(shared_cache
+                                                if share_verify_cache
+                                                else VerifyCache(maxsize=0)))
+                    for i, v in enumerate(self.vn_idents)])
 
         # DRO slab tenant: the noise phase below consumes slabs under the
         # collective-key digest (all tenants are content-addressed —
@@ -507,6 +514,9 @@ class LocalCluster:
 
         return enc, _fused_agg, ks, _fused_dec
 
+    # the four programs of a survey, for the set-up report
+    FUSED = ("_fused_enc", "_fused_agg", "_fused_ks", "_fused_dec")
+
     # bucket-grid Profile axis: st.grid_buckets(q) — shared with admission
 
     @staticmethod
@@ -655,52 +665,54 @@ class LocalCluster:
             ck.resumes += 1
 
         def mark(phase: str) -> None:
-            ck.enter(phase)
-            ck.save(self.checkpoint_db)
+            with tm.step("checkpoint"):
+                ck.enter(phase)
+                ck.save(self.checkpoint_db)
 
         mark("probe")
 
-        # --- Quorum-degraded membership: with an active FaultPlan every
-        # DP dispatch rides transport.local_call, so the in-process path
-        # sees the same connect/request/node hooks as a TCP dispatch
-        # (service/node.py _h_survey_query): a killed, refusing, or
-        # dropped DP is simply absent. The survey proceeds over the
-        # responders iff they meet min_dp_quorum, and the VN
-        # expected-proof counters are sized to the responder set.
-        plan = faults.fault_plan()
-        allowed = None if responders is None else {str(n)
-                                                  for n in responders}
-        dp_idents: list = []
-        absent: list[str] = []
-        for d in self.dp_idents:
-            # DP names are public routing metadata even though the
-            # identity objects also carry the node's secret scalar
-            name = d.name  # drynx: declassify[secret]
-            if allowed is not None and name not in allowed:
-                # resume carried a responder set that excludes this DP:
-                # it is absent by restriction, no probe needed
-                absent.append(name)
-                continue
-            if plan is not None:
-                from . import transport as tr
-
-                try:
-                    tr.local_call(name, "survey_query", lambda: None)
-                    dp_idents.append(d)
-                except tr.TransportError:
+        with tm.step("probe"):
+            # --- Quorum-degraded membership: with an active FaultPlan every
+            # DP dispatch rides transport.local_call, so the in-process path
+            # sees the same connect/request/node hooks as a TCP dispatch
+            # (service/node.py _h_survey_query): a killed, refusing, or
+            # dropped DP is simply absent. The survey proceeds over the
+            # responders iff they meet min_dp_quorum, and the VN
+            # expected-proof counters are sized to the responder set.
+            plan = faults.fault_plan()
+            allowed = None if responders is None else {str(n)
+                                                      for n in responders}
+            dp_idents: list = []
+            absent: list[str] = []
+            for d in self.dp_idents:
+                # DP names are public routing metadata even though the
+                # identity objects also carry the node's secret scalar
+                name = d.name  # drynx: declassify[secret]
+                if allowed is not None and name not in allowed:
+                    # resume carried a responder set that excludes this DP:
+                    # it is absent by restriction, no probe needed
                     absent.append(name)
-            else:
-                dp_idents.append(d)
-        responders = [d.name for d in dp_idents]
-        need = (sq.min_dp_quorum if sq.min_dp_quorum > 0
-                else len(self.dp_idents))
-        if len(responders) < need:
-            raise RuntimeError(
-                f"survey {sq.survey_id}: only {len(responders)}/"
-                f"{len(self.dp_idents)} DPs responded (quorum {need}); "
-                f"absent: {sorted(absent)}")
-        ck.responders = list(responders)
-        ck.absent = sorted(absent)
+                    continue
+                if plan is not None:
+                    from . import transport as tr
+
+                    try:
+                        tr.local_call(name, "survey_query", lambda: None)
+                        dp_idents.append(d)
+                    except tr.TransportError:
+                        absent.append(name)
+                else:
+                    dp_idents.append(d)
+            responders = [d.name for d in dp_idents]
+            need = (sq.min_dp_quorum if sq.min_dp_quorum > 0
+                    else len(self.dp_idents))
+            if len(responders) < need:
+                raise RuntimeError(
+                    f"survey {sq.survey_id}: only {len(responders)}/"
+                    f"{len(self.dp_idents)} DPs responded (quorum {need}); "
+                    f"absent: {sorted(absent)}")
+            ck.responders = list(responders)
+            ck.absent = sorted(absent)
         log.lvl1(f"survey {sq.survey_id}: op={op.name} "
                  f"dps={len(responders)}/{len(self.dp_idents)} "
                  f"cns={len(self.cns)} "
@@ -727,56 +739,71 @@ class LocalCluster:
         # --- DP phase: encode + encrypt (+ range proofs) ----------------
         mark("collect")
         tm.start("DataCollectionProtocol")
-        dp_stats = np.stack([
-            self.dps[d.name].local_stats(op, self.rng, q.group_by)
-            for d in dp_idents])                   # (n_dps, V) or (n_dps,G,Vg)
-        if q.group_by:
-            # group-major flatten: the aligned group axis makes element-wise
-            # homomorphic addition the per-group aggregation (no same-group
-            # matching; reference data_collection_protocol.go:157-168)
-            dp_stats = dp_stats.reshape(dp_stats.shape[0], -1)
-        cf = max(int(q.cutting_factor), 1)
-        if cf > 1:
-            # CuttingFactor scale testing: replicate the output vector (and
-            # therefore every downstream ciphertext + proof) cf times
-            # (reference lib/structs.go:637-639)
-            dp_stats = np.tile(dp_stats, (1, cf))
-        V = dp_stats.shape[1]
+        with tm.step("local_stats"):
+            dp_stats = np.stack([
+                self.dps[d.name].local_stats(op, self.rng, q.group_by)
+                for d in dp_idents])               # (n_dps, V) or (n_dps,G,Vg)
+            if q.group_by:
+                # group-major flatten: the aligned group axis makes
+                # element-wise homomorphic addition the per-group aggregation
+                # (no same-group matching; reference
+                # data_collection_protocol.go:157-168)
+                dp_stats = dp_stats.reshape(dp_stats.shape[0], -1)
+            cf = max(int(q.cutting_factor), 1)
+            if cf > 1:
+                # CuttingFactor scale testing: replicate the output vector (and
+                # therefore every downstream ciphertext + proof) cf times
+                # (reference lib/structs.go:637-639)
+                dp_stats = np.tile(dp_stats, (1, cf))
+            V = dp_stats.shape[1]
 
-        # Sound range proofs for signed encodings: logreg fixed-point
-        # coefficients can be negative, which a [0, u^l) digit proof cannot
-        # express (the reference's ToBase silently emits NO digits for
-        # negative secrets, range_proof.go:584 — its LR range proofs are
-        # vacuous). We instead SHIFT each plaintext by u^l/2 so the proved
-        # statement is real, and homomorphically subtract the public
-        # n_dps*offset from the key-switched result before decryption.
-        range_offset = 0
-        if proofs_on and op.name == "log_reg" and q.ranges:
-            u0, l0 = q.ranges[0]
-            if u0:
-                range_offset = (int(u0) ** int(l0)) // 2
-                assert int(np.abs(dp_stats).max()) < range_offset, \
-                    "logreg encoding exceeds range proof bound u^l/2"
-                dp_stats = dp_stats + range_offset
-        key, k_enc = jax.random.split(key)
-        enc_rs = eg.random_scalars(k_enc, dp_stats.shape)
+            # Sound range proofs for signed encodings: logreg fixed-point
+            # coefficients can be negative, which a [0, u^l) digit proof cannot
+            # express (the reference's ToBase silently emits NO digits for
+            # negative secrets, range_proof.go:584 — its LR range proofs are
+            # vacuous). We instead SHIFT each plaintext by u^l/2 so the proved
+            # statement is real, and homomorphically subtract the public
+            # n_dps*offset from the key-switched result before decryption.
+            range_offset = 0
+            if proofs_on and op.name == "log_reg" and q.ranges:
+                u0, l0 = q.ranges[0]
+                if u0:
+                    range_offset = (int(u0) ** int(l0)) // 2
+                    assert int(np.abs(dp_stats).max()) < range_offset, \
+                        "logreg encoding exceeds range proof bound u^l/2"
+                    dp_stats = dp_stats + range_offset
+        with tm.step("randomness"):
+            key, k_enc = jax.random.split(key)
+            enc_rs = eg.random_scalars(k_enc, dp_stats.shape)
         f_enc, f_agg, f_ks, f_dec = self._fused()
         enc_tile = enc_tiles.auto_tile(V)
-        if enc_tile:
-            # bucket-tiled encryption (grid-op scale axis): the fused enc
-            # program runs per value-axis slab so no single dispatch
-            # materializes the full (n_dps, V, 2, 3, 16) ciphertext array
-            # (384 MB at 1M buckets). enc_rs is drawn full-size above and
-            # sliced, and the program is element-wise per (dp, value), so
-            # the concatenation is bit-identical to one dispatch. Balanced
-            # tiles -> at most two slab shapes compile.
-            stats_dev = jnp.asarray(dp_stats)
-            parts = [np.asarray(f_enc(stats_dev[:, a:b], enc_rs[:, a:b]))
-                     for a, b in enc_tiles.plan_tiles(V, enc_tile).tiles]
-            cts = jnp.asarray(np.concatenate(parts, axis=1))
-        else:
-            cts = f_enc(jnp.asarray(dp_stats), enc_rs)      # (n_dps, V, 2,3,16)
-        cts.block_until_ready()
+        PROCESS.count("h2d_bytes", dp_stats.nbytes)
+        with tm.step("enc"):
+            if enc_tile:
+                # bucket-tiled encryption (grid-op scale axis): the fused enc
+                # program runs per value-axis slab so no single dispatch
+                # materializes the full (n_dps, V, 2, 3, 16) ciphertext array
+                # (384 MB at 1M buckets). enc_rs is drawn full-size above and
+                # sliced, and the program is element-wise per (dp, value), so
+                # the concatenation is bit-identical to one dispatch. Balanced
+                # tiles -> at most two slab shapes compile.
+                with tm.step("upload"):
+                    stats_dev = jnp.asarray(dp_stats)
+                parts = []
+                tiles = enc_tiles.plan_tiles(V, enc_tile).tiles
+                for i, (a, b) in enumerate(tiles):
+                    with tm.step(f"tile{i}"):
+                        parts.append(np.asarray(
+                            f_enc(stats_dev[:, a:b], enc_rs[:, a:b])))
+                with tm.step("regroup"):
+                    # the tiles came down; in one piece they go up again
+                    cts = jnp.asarray(np.concatenate(parts, axis=1))
+                    cts.block_until_ready()
+                PROCESS.count("d2h_bytes", cts.nbytes)
+                PROCESS.count("h2d_bytes", cts.nbytes)
+            else:
+                cts = f_enc(jnp.asarray(dp_stats), enc_rs)  # (n_dps,V,2,3,16)
+                cts.block_until_ready()
         if self.link.active:
             # DP->CN uploads ride INDEPENDENT links in parallel (the
             # reference's per-link model): wall time = max over links =
@@ -815,8 +842,11 @@ class LocalCluster:
         # canonical aggregate (topology.canon_points): the in-process
         # plane lands on the same aggregate BYTES as the remote tree/star
         # dispatch paths, which all fold through topology.fold_cts
-        agg = topo.canon_points(f_agg(cts))
-        jax.block_until_ready(agg)
+        with tm.step("reduce"):
+            red = f_agg(cts)
+        with tm.step("canon"):
+            agg = topo.canon_points(red)
+            jax.block_until_ready(agg)
         tm.end("AggregationPhase")
         if proofs_on:
             # each CN signs its own request but the (transparent) proof body
@@ -917,19 +947,23 @@ class LocalCluster:
         # --- Key switch to the querier's key ----------------------------
         mark("keyswitch")
         tm.start("KeySwitchingPhase")
-        srv_x = jnp.asarray(np.stack([eg.secret_to_limbs(c.secret)
-                                      for c in self.cns]))
-        key, k_ks = jax.random.split(key)
-        ks_rs = eg.random_scalars(k_ks, (len(self.cns), V))
+        with tm.step("secrets"):
+            srv_x = jnp.asarray(np.stack([eg.secret_to_limbs(c.secret)
+                                          for c in self.cns]))
+            PROCESS.count("h2d_bytes", srv_x.nbytes)
+        with tm.step("randomness"):
+            key, k_ks = jax.random.split(key)
+            ks_rs = eg.random_scalars(k_ks, (len(self.cns), V))
         # per-server contributions, batched over (ns, V):
         # U = r·B,  W = r·Q − x·K   (commuting; sum replaces the CN chain);
         # the fused program also subtracts the public aggregate shift
         # (n_dps * u^l/2)·B so decrypted values are true signed statistics
         total = range_offset * len(dp_idents)  # one offset per RESPONDER
         assert total < 2 ** 62, "offset too large for int64 scalar path"
-        switched, u_pts, w_pts = f_ks(
-            agg, ks_rs, srv_x, jnp.asarray(total, dtype=jnp.int64))
-        switched.block_until_ready()
+        with tm.step("switch"):
+            switched, u_pts, w_pts = f_ks(
+                agg, ks_rs, srv_x, jnp.asarray(total, dtype=jnp.int64))
+            switched.block_until_ready()
         tm.end("KeySwitchingPhase")
         if proofs_on:
             key, k_kp = jax.random.split(key)
@@ -943,16 +977,21 @@ class LocalCluster:
         # --- Querier decrypt + decode -----------------------------------
         mark("decrypt")
         tm.start("Decryption")
-        xq = jnp.asarray(eg.secret_to_limbs(self.client.secret))
-        dl = self.dlog
-        vals, found, zeros = f_dec(switched, xq, dl.keys, dl.xs, dl.ysign,
-                                   dl.vals)
-        zeros.block_until_ready()
+        with tm.step("dec"):
+            xq = jnp.asarray(eg.secret_to_limbs(self.client.secret))
+            PROCESS.count("h2d_bytes", xq.nbytes)
+            dl = self.dlog
+            vals, found, zeros = f_dec(switched, xq, dl.keys, dl.xs,
+                                       dl.ysign, dl.vals)
+            zeros.block_until_ready()
         tm.end("Decryption")
 
-        dec = st.DecryptedVector(values=np.asarray(vals),
-                                 found=np.asarray(found),
-                                 is_zero=np.asarray(zeros))
+        with tm.step("fetch"):
+            dec = st.DecryptedVector(values=np.asarray(vals),
+                                     found=np.asarray(found),
+                                     is_zero=np.asarray(zeros))
+            PROCESS.count("d2h_bytes", dec.values.nbytes + dec.found.nbytes
+                          + dec.is_zero.nbytes)
         if cf > 1:
             # decode only the first replica (the rest are the scale-test
             # padding; they decrypt to identical values)
@@ -960,22 +999,26 @@ class LocalCluster:
             dec = st.DecryptedVector(values=dec.values[:v0],
                                      found=dec.found[:v0],
                                      is_zero=dec.is_zero[:v0])
-        if op.name == "log_reg":
-            tm.start("GradientDescent")
-            Ts = lr.unpack(jnp.asarray(dec.values), op.lr_params)
-            w = np.asarray(lr.train(Ts, op.lr_params))
-            tm.end("GradientDescent")
-            result = w
-        elif q.group_by:
-            # per-group decode at the querier (reference api.go:124-128)
-            result = st.decode_grouped(
-                op.name, dec, st.group_grid(q.group_by),
-                op.query_min, op.query_max,
-                dims=(op.nbr_input - 1) if op.name == "lin_reg" else 1)
-        else:
-            result = st.decode(op.name, dec, op.query_min, op.query_max,
-                               dims=(op.nbr_input - 1)
-                               if op.name == "lin_reg" else 1)
+        with tm.step("decode"):
+            if op.name == "log_reg":
+                tm.start("GradientDescent")
+                Ts = lr.unpack(jnp.asarray(dec.values), op.lr_params)
+                w = np.asarray(lr.train(Ts, op.lr_params))
+                tm.end("GradientDescent")
+                PROCESS.count("h2d_bytes", dec.values.nbytes)
+                PROCESS.count("d2h_bytes", w.nbytes)
+                result = w
+            elif q.group_by:
+                # per-group decode at the querier (reference api.go:124-128)
+                result = st.decode_grouped(
+                    op.name, dec, st.group_grid(q.group_by),
+                    op.query_min, op.query_max,
+                    dims=(op.nbr_input - 1) if op.name == "lin_reg" else 1)
+            else:
+                result = st.decode(op.name, dec, op.query_min, op.query_max,
+                                   dims=(op.nbr_input - 1)
+                                   if op.name == "lin_reg" else 1)
+        PROCESS.count("surveys")
 
         ck.responders = list(responders)
         ck.absent = sorted(absent)
@@ -1000,24 +1043,28 @@ class LocalCluster:
         sid = sq.survey_id
         tm = survey.timers
         block = None
-        if pending.proofs_on:
-            # generous: on a cold CPU process the proof threads' FIRST run
-            # includes all pairing-kernel compiles (tens of minutes at
-            # opt-level 0 on one core; seconds on TPU)
-            for t in survey.proof_threads:
-                t.join(timeout=rp.COLD_COMPILE_WAIT_S)
-            if pending.hold_range:
-                # safety release: a held survey reaching finalization
-                # without the scheduler's cross-survey flush (e.g. its
-                # batch partners all faulted away) flushes solo here —
-                # otherwise end_verification would stall out its timeout
-                self.vns.flush_cross_survey([sid])
-            block = self.vns.end_verification(
-                sid, timeout=rp.COLD_COMPILE_WAIT_S,
-                quorum=sq.vn_quorum)
-            log.lvl2(f"survey {sid}: audit block "
-                     f"#{block.index} committed, "
-                     f"{len(block.data.bitmap)} bitmap entries")
+        with tm.step("finalize"):
+            if pending.proofs_on:
+                # generous: on a cold CPU process the proof threads' FIRST run
+                # includes all pairing-kernel compiles (tens of minutes at
+                # opt-level 0 on one core; seconds on TPU)
+                for t in survey.proof_threads:
+                    t.join(timeout=rp.COLD_COMPILE_WAIT_S)
+                if pending.hold_range:
+                    # safety release: a held survey reaching finalization
+                    # without the scheduler's cross-survey flush (e.g. its
+                    # batch partners all faulted away) flushes solo here —
+                    # otherwise end_verification would stall out its timeout
+                    self.vns.flush_cross_survey([sid])
+                block = self.vns.end_verification(
+                    sid, timeout=rp.COLD_COMPILE_WAIT_S,
+                    quorum=sq.vn_quorum)
+                log.lvl2(f"survey {sid}: audit block "
+                         f"#{block.index} committed, "
+                         f"{len(block.data.bitmap)} bitmap entries")
+        if PROCESS.seal_setup() and log.debug_visible():
+            log.lvl1("set-up report\n" + PROCESS.setup_report(
+                tm.records(), programs=self.FUSED))
         log.lvl1(f"survey {sid}: done; phases: " + ", ".join(
             f"{k}={v:.3f}s" for k, v in tm.items()))
         ck = pending.checkpoint

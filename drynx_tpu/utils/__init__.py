@@ -1,2 +1,2 @@
 """Host-side utilities: phase timers, config, data generation."""
-from .timers import PhaseTimers, start_timer, end_timer, timers_csv  # noqa: F401
+from .timers import PhaseTimers  # noqa: F401
