@@ -19,6 +19,7 @@ while the main phase path continues.
 from __future__ import annotations
 
 import dataclasses
+import os
 import secrets
 import threading
 import time
@@ -46,6 +47,7 @@ from ..proofs import shuffle as shuffle_proof
 from ..resilience import faults
 from ..resilience import policy as rp
 from ..utils import log
+from ..utils.exec_store import StoredProgram
 from ..utils.timers import PROCESS, PhaseTimers, install_listener
 from . import topology as topo
 from .proof_collection import VerifyCache, VerifyingNode, VNGroup
@@ -1136,17 +1138,44 @@ class LocalCluster:
         survey.proof_threads.append(t)
 
 
+def _trace_reads() -> dict:
+    """What the trace of the four programs below reads beside its arguments
+    and the package's source: the executable store keys on it
+    (utils/exec_store.py). `po.available()` reads DRYNX_NO_PALLAS and
+    INTERPRET, the kernels' wrappers pass INTERPRET and `field.UNROLL`
+    (DRYNX_FIELD_UNROLL) on as static arguments, DRYNX_BUCKET_TILE sets the
+    tile that `_fused_enc` is called at. `eg.BASE_TABLE.table`, closed over
+    by enc and ks, is a function of the source; the key tables, the secrets
+    and the discrete-log table are arguments."""
+    from ..crypto import field
+    from ..crypto import pallas_ops as po
+    from ..crypto import pallas_pairing as pp
+
+    return {"DRYNX_NO_PALLAS": os.environ.get("DRYNX_NO_PALLAS", "0"),
+            enc_tiles.ENV_TILE: os.environ.get(enc_tiles.ENV_TILE, ""),
+            "field.UNROLL": field.UNROLL,
+            "pallas_ops.INTERPRET": po.INTERPRET,
+            "pallas_pairing.INTERPRET": pp.INTERPRET}
+
+
+def _stored(fn) -> StoredProgram:
+    return StoredProgram(fn, _trace_reads)
+
+
+@_stored
 @jax.jit
 def _fused_enc(coll_tbl, stats, enc_rs):
     m = eg.int_to_scalar(stats)
     return eg.encrypt_with_tables(eg.BASE_TABLE.table, coll_tbl, m, enc_rs)
 
 
+@_stored
 @jax.jit
 def _fused_agg(cts):
     return B.tree_reduce_add(cts, eg.ct_add)
 
 
+@_stored
 @jax.jit
 def _fused_ks(q_tbl, agg, ks_rs, srv_x, offset_total):
     # key switch: per-server contributions + reduce (commuting sum
@@ -1169,6 +1198,7 @@ def _fused_ks(q_tbl, agg, ks_rs, srv_x, offset_total):
     return switched, u_pts, w_pts
 
 
+@_stored
 @jax.jit
 def _fused_dec(switched, qx, keys, xs, ysign, vals):
     pts = eg.decrypt_point(switched, qx)

@@ -9,8 +9,16 @@ cache calls `enable_compilation_cache()`; nothing else in the repo writes
 (tests/conftest.py, `.jax_cache_tests`).
 
 What the cache saves is XLA/Mosaic compile time. Tracing and lowering of
-the trace-time-unrolled limb kernels run before the cache lookup and are
-paid by every process.
+the trace-time-unrolled limb kernels run before the cache lookup, so jax's
+cache cannot save them. For the four fused programs of a survey
+(`service._fused_enc/_agg/_ks/_dec`) the executable store does
+(utils/exec_store.py): serialised executables under
+`<this directory>/exec_store/`, one file per program and shape, keyed
+before any tracing by the call's abstract arguments, a digest of the
+package's source and the versions and flags underneath. It engages where
+this directory is configured and the backend is a TPU; to clear it, delete
+that sub-directory. Every other program of a process still traces and
+lowers in every process.
 """
 from __future__ import annotations
 

@@ -474,6 +474,17 @@ class ProcessTracer(PhaseTimers):
                f"{'program':<24} {'trace_s':>8} {'lower_s':>8} "
                f"{'compile_s':>9}  cache  within (cpu / wall s); "
                f"longest inner traces"]
+        if self.counter("exec_store_lookups"):
+            # the executable store (utils/exec_store.py): a program that hit
+            # it has no row below, since it neither traced nor lowered
+            took = {kind: sum(s.t1 - s.t0 for s in self.records(
+                f"setup/exec_store/{kind}:")) for kind in
+                ("load", "compile", "save")}
+            out.insert(1, (
+                f"executable store: {self.counter('exec_store_hits')} of "
+                f"{self.counter('exec_store_lookups')} look-ups hit, "
+                f"{self.counter('exec_store_load_failures')} bad entries; "
+                + ", ".join(f"{k} {v:.1f} s" for k, v in took.items())))
         for r in sorted(rows, key=lambda r: -(r["trace_s"] + r["lower_s"])):
             cache = ("-" if not r["compiles"] else "hit"
                      if r["cache_hits"] >= r["compiles"] else
