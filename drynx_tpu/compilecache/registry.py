@@ -71,8 +71,9 @@ class Profile:
     n_noise: int = 0        # DRO noise-list size of a diffp survey: > 0
                             # adds the pool/DRO slab program set
                             # (_pool_specs) at parallel/dro.slab_widths —
-                            # the raw jits the precompute/refill and
-                            # shuffle paths dispatch. 0 (default) = no
+                            # the stored programs the noise encryption,
+                            # the precompute/refill and the shuffle
+                            # run. 0 (default) = no
                             # diffp, no extra programs, so plain
                             # registries stay a subset of pooled ones
                             # (test_precompile.py enforces both
@@ -717,53 +718,44 @@ def _fused_specs(p: Profile) -> list:
 
 
 def _pool_specs(p: Profile) -> list:
-    """The DRO pool/slab program set of a diffp survey (Profile.n_noise):
-    the RAW jits `parallel.dro` dispatches for precompute (pool refill),
-    noise encryption and the shuffle re-randomization — certified at the
-    exact slab widths `dro.slab_widths` chunks n_noise into, plus the
-    monolithic n_noise width (encrypt_noise / the unchunked path). Empty
-    when n_noise <= 0, so non-diffp registries are a subset of pooled
-    ones (tests/test_precompile.py enforces both directions)."""
+    """The DRO slab program set of a diffp survey (Profile.n_noise): the
+    three stored programs `parallel.dro` runs the phase's G1 work in
+    (`dro.PROGRAMS`: noise encryption, zero encryption for a fresh pass or
+    a pool refill, gather + add), certified at the exact slab widths
+    `dro.slab_widths` chunks n_noise into; the gather reads the whole
+    n_noise list. Empty when n_noise <= 0, so non-diffp registries are a
+    subset of pooled ones (tests/test_precompile.py enforces both
+    directions)."""
     if p.n_noise <= 0:
         return []
     from ..parallel import dro as _dro
 
-    widths = sorted(set(_dro.slab_widths(p.n_noise)) | {p.n_noise})
+    def idx(w):
+        import jax
+        import numpy as np
 
-    def enc_at(w):
+        # a slab of jax.random.permutation(key, n_noise): jax's default int
+        return _z((w,), jax.dtypes.canonicalize_dtype(np.int64))
+
+    args_of = {
+        "_dro_noise_enc": lambda w: (_fb_table(), _fb_table(), _i64(w),
+                                     _scalar(w)),
+        "_dro_zero_enc": lambda w: (_fb_table(), _fb_table(), _scalar(w)),
+        "_dro_permute_add": lambda w: (_ct(p.n_noise), idx(w), _ct(w)),
+    }
+
+    def at(nm, w):
         def go(do="lower"):
-            from ..crypto import elgamal as eg
-
-            args = (_fb_table(), _fb_table(), _scalar(w), _scalar(w))
-            return (eg.encrypt_with_tables(*args) if do == "call"
-                    else eg.encrypt_with_tables.lower(*args))
-        return go
-
-    def i2s_at(w):
-        def go(do="lower"):
-            from ..crypto import elgamal as eg
-
-            args = (_i64(w),)
-            return (eg.int_to_scalar(*args) if do == "call"
-                    else eg.int_to_scalar.lower(*args))
-        return go
-
-    def add_at(w):
-        def go(do="lower"):
-            from ..crypto import elgamal as eg
-
-            args = (_ct(w), _ct(w))
-            return (eg.ct_add(*args) if do == "call"
-                    else eg.ct_add.lower(*args))
+            prog, args = getattr(_dro, nm), args_of[nm](w)
+            return prog(*args) if do == "call" else prog.lower(*args)
         return go
 
     specs = []
-    for w in widths:
-        for nm, th in (("encrypt_with_tables", enc_at(w)),
-                       ("int_to_scalar", i2s_at(w)),
-                       ("ct_add", add_at(w))):
+    for w in _dro.slab_widths(p.n_noise):
+        for nm in _dro.PROGRAMS:
+            th = at(nm, w)
             specs.append(ProgramSpec(
-                f"pool:{nm}@{w}", nm, "pool", "DROPool", th,
+                f"pool:{nm[1:]}@{w}", nm[1:], "pool", "DROPool", th,
                 lambda: True, lambda th=th: th("call"),
                 family="device"))
     return specs

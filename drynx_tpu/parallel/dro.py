@@ -18,13 +18,20 @@ composition over servers is the reference's Neff-shuffle pipeline's effect.
 The shuffle proof itself lives in drynx_tpu.proofs.
 
 Scale (reference TIFS/diffPri.py: noise lists 10k -> 1M, 81.9 -> 5872 s):
-above CHUNK elements the precompute and the shuffle re-randomization run in
-fixed-size slabs dispatched over the proof plane's `dp`-axis devices
-(parallel/proof_plane.dispatch_shards) instead of one (S, 2, 3, 16)
-dispatch. The global permutation stays exact — indices are permuted on the
-host and each slab gathers its slice — and every chunked output is
-byte-identical to the unchunked path for the same key (all the per-element
-crypto is element-wise; tests/test_scale_axes.py asserts it).
+the list is device state handled slab by slab from end to end. Three
+module-level programs do the phase's G1 work, each on one slab of at most
+CHUNK elements: `_dro_noise_enc` (a slab of the noise list encrypted),
+`_dro_zero_enc` (a slab of encryptions of zero) and `_dro_permute_add` (a
+slab of the permuted list gathered from the whole list and re-randomised).
+They are `StoredProgram`s like the four fused survey programs
+(utils/exec_store.py): a warm process on a TPU loads them. The scalars and
+the permutation are always drawn in ONE call and stay on the device, every
+per-element operation is element-wise, so the output is byte-identical
+whatever the slab width (one slab of the whole list included;
+tests/test_scale_axes.py, tests/test_dro.py). The two encryption programs'
+slabs are placed over the proof plane's devices where it has several
+(parallel/proof_plane.dispatch_shards); the gather runs where the list
+lives.
 
 API convention: `FixedBase` objects stop at the encryption boundary
 (encrypt_noise, dro_pipeline); the shuffle/precompute layer takes raw
@@ -34,6 +41,8 @@ which hid a real type error in dro_pipeline.
 """
 from __future__ import annotations
 
+import contextlib
+import functools
 import math
 
 import jax
@@ -42,6 +51,8 @@ import numpy as np
 
 from ..crypto import elgamal as eg
 from ..resilience.policy import named_lock
+from ..utils.exec_store import stored
+from ..utils.timers import PROCESS
 
 # Slab width for chunked precompute / shuffle re-randomization: matches
 # the g1 family's max_bucket (crypto/batching.py) and the bucket-grid
@@ -143,13 +154,47 @@ def _require_table(tbl, who: str):
     return tbl
 
 
-def encrypt_noise(key, pub_table: eg.FixedBase, noise: np.ndarray):
-    """Encrypt the noise list under the collective key."""
-    if not isinstance(pub_table, eg.FixedBase):
-        raise TypeError("encrypt_noise takes the FixedBase wrapper "
-                        "(the encryption boundary); got a raw table")
-    ct, _ = eg.encrypt_ints(key, pub_table, jnp.asarray(noise))
-    return ct
+# ---------------------------------------------------------------------------
+# The phase's three slab programs. Module-level jits of arrays with the key
+# tables as arguments, stored like service._fused_enc/_agg/_ks/_dec
+# (LocalCluster.FUSED names all seven). The two ladders' programs depend on
+# the slab's width alone, so one stored executable serves every list size.
+# ---------------------------------------------------------------------------
+
+@stored
+@jax.jit
+def _dro_noise_enc(base_tbl, pub_tbl, values, r):
+    """A slab of signed noise values encrypted: (rB, vB + rP)."""
+    return eg.encrypt_ints_with_tables(base_tbl, pub_tbl, values, r)
+
+
+@stored
+@jax.jit
+def _dro_zero_enc(base_tbl, pub_tbl, r):
+    """A slab of encryptions of zero: (rB, rP). `encrypt_with_tables` on
+    zero scalars computes (rB, 0*B + rP); 0*B is the identity and `C.add`
+    hands back its other operand untouched when one is the identity, so
+    leaving the 0*B ladder out changes no byte (tests/test_dro.py pins it
+    on the CPU, benchmarks/check_dro.py on the chip)."""
+    return jnp.stack([eg.fixed_base_mul(base_tbl, r),
+                      eg.fixed_base_mul(pub_tbl, r)], axis=-3)
+
+
+@stored
+@jax.jit
+def _dro_permute_add(cts, idx, zero_ct):
+    """A slab of one node's pass: out[i] = cts[idx[i]] + zero_ct[i]."""
+    return eg.ct_add(jnp.take(cts, idx, axis=0), zero_ct)
+
+
+PROGRAMS = ("_dro_noise_enc", "_dro_zero_enc", "_dro_permute_add")
+
+
+@functools.partial(jax.jit, static_argnames="n")
+def _slab(x, a, n: int):
+    """x[a:a+n] with the offset an argument: one small program a list size
+    and width, not one a slab."""
+    return jax.lax.dynamic_slice_in_dim(x, a, n, axis=0)
 
 
 def _chunk_of(size: int, chunk) -> int:
@@ -160,17 +205,70 @@ def _chunk_of(size: int, chunk) -> int:
     return int(chunk)
 
 
-def slab_widths(size: int, chunk: int | None = None) -> list[int]:
-    """Distinct dispatch widths the chunked DRO path uses at ``size``
-    (at most two: the slab width and a remainder). The compilecache
-    registry certifies the pool programs at exactly these widths
-    (compilecache/registry._pool_specs, Profile.n_noise)."""
-    if size <= 0:
-        return []
+def _slabs_of(size: int, chunk) -> list:
+    """[(start, stop)] of the slabs of a list of `size` elements."""
     eff = _chunk_of(size, chunk)
     if not eff or eff >= size:
-        return [size]
-    return sorted({min(a + eff, size) - a for a in range(0, size, eff)})
+        return [(0, size)]
+    return [(a, min(a + eff, size)) for a in range(0, size, eff)]
+
+
+def slab_widths(size: int, chunk: int | None = None) -> list[int]:
+    """Distinct dispatch widths the slab programs run at for a list of
+    ``size`` (at most two: the slab width and a remainder). The
+    compilecache registry certifies the three programs at exactly these
+    widths (compilecache/registry._pool_specs, Profile.n_noise)."""
+    if size <= 0:
+        return []
+    return sorted({b - a for a, b in _slabs_of(size, chunk)})
+
+
+def _step(tm, name: str):
+    """A step of the survey's timers under the phase that is open
+    (`DROPhase/<name>`), where the caller has timers."""
+    return tm.step(name) if tm is not None else contextlib.nullcontext()
+
+
+def _by_slab(phase: str, chunk, inputs: tuple, program, place: bool):
+    """program(*slab of every input) over the slabs of `inputs` (device
+    arrays of one length), the outputs in one array. Returns once the
+    device is done. `place`: a slab's inputs go to the proof plane's
+    device for it where the plane has several."""
+    from . import proof_plane as plane
+
+    slabs = _slabs_of(int(inputs[0].shape[0]), chunk)
+    if len(slabs) == 1:
+        return jax.block_until_ready(program(*inputs))
+
+    def stage(i, a, b):
+        cut = tuple(_slab(x, a, b - a) for x in inputs)
+        return plane.put_shard(cut, i, donate=True) if place else cut
+
+    parts = plane.dispatch_shards(phase, lambda i, *cut: program(*cut),
+                                  slabs, prefetch=stage)
+    return jax.block_until_ready(jnp.concatenate(parts, axis=0))
+
+
+def encrypt_noise(key, pub_table: eg.FixedBase, noise: np.ndarray,
+                  chunk: int | None = None, tm=None):
+    """Encrypt the noise list under the collective key, slab by slab. The
+    scalars are drawn in one call, so the bytes are those of one dispatch
+    (`eg.encrypt_ints`) for the same key."""
+    if not isinstance(pub_table, eg.FixedBase):
+        raise TypeError("encrypt_noise takes the FixedBase wrapper "
+                        "(the encryption boundary); got a raw table")
+    noise = np.asarray(noise, dtype=np.int64)
+    size = int(noise.shape[0])
+    with _step(tm, "noise_enc"):
+        values = jnp.asarray(noise)
+        PROCESS.count("h2d_bytes", noise.nbytes)
+        r = eg.random_scalars(key, (size,))
+        base, pub = eg.BASE_TABLE.table, pub_table.table
+        cts = _by_slab("DRONoise", chunk, (values, r),
+                       lambda v, rs: _dro_noise_enc(base, pub, v, rs),
+                       place=True)
+    PROCESS.count("dro_encryptions", size)
+    return cts
 
 
 # Builder-invocation counter: increments on every FRESH precompute (the
@@ -183,34 +281,8 @@ PRECOMPUTE_CALLS = 0
 _PRECOMPUTE_COUNT_LOCK = named_lock("precompute_count_lock")
 
 
-def _encrypt_zeros_chunked(r, pub_tbl, base_tbl, chunk: int, phase: str):
-    """Fresh zero-encryptions for blinding scalars r, in `chunk`-wide slabs
-    dispatched over the proof plane (element-wise: slab concatenation is
-    byte-identical to one full dispatch)."""
-    from . import proof_plane as plane
-
-    size = int(r.shape[0])
-    eff = _chunk_of(size, chunk)
-    if not eff or eff >= size:
-        zeros = jnp.zeros((size,), dtype=jnp.int64)
-        return eg.encrypt_with_tables(base_tbl, pub_tbl,
-                                      eg.int_to_scalar(zeros), r)
-
-    def stage(i, a, b):
-        return a, b, plane.put_shard(r[a:b], i, donate=True)
-
-    def slab(i, a, b, rs):
-        zeros = jnp.zeros((b - a,), dtype=jnp.int64)
-        return eg.encrypt_with_tables(base_tbl, pub_tbl,
-                                      eg.int_to_scalar(zeros), rs)
-
-    slabs = [(a, min(a + eff, size)) for a in range(0, size, eff)]
-    parts = plane.dispatch_shards(phase, slab, slabs, prefetch=stage)
-    return jnp.concatenate(parts, axis=0)
-
-
 def precompute_rerandomization(key, pub_tbl, size: int, base_tbl=None,
-                               chunk: int | None = None):
+                               chunk: int | None = None, tm=None):
     """Precompute the expensive half of a shuffle step: `size` fresh
     encryptions of zero (r·B, r·P) plus their scalars.
 
@@ -220,19 +292,21 @@ def precompute_rerandomization(key, pub_tbl, size: int, base_tbl=None,
     1M-element DRO noise lists survivable. Returns (zero_cts, r) usable as
     the `precomp` argument of shuffle_rerandomize.
 
-    Above CHUNK elements the fixed-base mults run in `chunk`-wide slabs
-    over the proof-plane devices (byte-identical to one dispatch; the
-    scalars r are always drawn in ONE call so chunking never changes
-    them). chunk: None = auto, 0 = force monolithic."""
+    The fixed-base mults run in slabs (`_dro_zero_enc`; byte-identical to
+    one dispatch: the scalars r are always drawn in ONE call so chunking
+    never changes them). chunk: None = auto, 0 = force monolithic."""
     global PRECOMPUTE_CALLS
 
     _require_table(pub_tbl, "precompute_rerandomization")
     with _PRECOMPUTE_COUNT_LOCK:
         PRECOMPUTE_CALLS += 1
     base_tbl = base_tbl if base_tbl is not None else eg.BASE_TABLE.table
-    r = eg.random_scalars(key, (size,))
-    zero_ct = _encrypt_zeros_chunked(r, pub_tbl, base_tbl, chunk,
-                                     "DROPrecompute")
+    with _step(tm, "zero_enc"):
+        r = eg.random_scalars(key, (size,))
+        zero_ct = _by_slab("DROPrecompute", chunk, (r,),
+                           lambda rs: _dro_zero_enc(base_tbl, pub_tbl, rs),
+                           place=True)
+    PROCESS.count("dro_encryptions", size)
     return zero_ct, r
 
 
@@ -248,55 +322,70 @@ def load_precompute(path: str):
 
 
 def shuffle_rerandomize(key, cts, pub_tbl, base_tbl=None, precomp=None,
-                        chunk: int | None = None):
+                        chunk: int | None = None, tm=None):
     """One server's DRO step: secret permutation + re-randomization.
 
     cts: (S, 2, 3, 16). Returns (shuffled cts, permutation, rerand scalars)
     — the latter two feed the shuffle proof. `precomp` (from
-    precompute_rerandomization) skips the S fixed-base scalar-mults — the
-    hot cost at reference noise sizes (10k..1M, TIFS/diffPri.py).
+    precompute_rerandomization, a pool) skips the S fixed-base
+    scalar-mults — the hot cost at reference noise sizes (10k..1M,
+    TIFS/diffPri.py); without it they are made here, fresh.
 
-    chunk (None = auto above CHUNK, 0 = force monolithic): permute the
-    indices on the host, then gather + re-randomize in `chunk`-wide slabs
-    over the proof-plane devices instead of one (S, 2, 3, 16) dispatch.
-    The permutation and blinding scalars are drawn identically either way
-    and ct_add is element-wise, so chunked output is byte-identical to
-    unchunked for the same key."""
+    The permutation stays on the device; each slab gathers its part of it
+    from the whole list and adds its zero encryptions
+    (`_dro_permute_add`). The permutation and blinding scalars are drawn
+    identically whatever `chunk` (None = auto above CHUNK, 0 = one slab)
+    and the program is element-wise, so the output is byte-identical."""
     _require_table(pub_tbl, "shuffle_rerandomize")
     S = int(cts.shape[0])
     kperm, krand = jax.random.split(key)
-    perm = jax.random.permutation(kperm, S)
-    if precomp is not None:
-        zero_ct, r = precomp
-        assert zero_ct.shape[0] == S, (zero_ct.shape, S)
-    else:
-        base_tbl = base_tbl if base_tbl is not None else eg.BASE_TABLE.table
-        r = eg.random_scalars(krand, (S,))
-        zero_ct = _encrypt_zeros_chunked(r, pub_tbl, base_tbl, chunk,
-                                         "DRORerand")
+    if precomp is None:
+        precomp = precompute_rerandomization(krand, pub_tbl, S, base_tbl,
+                                             chunk, tm)
+    zero_ct, r = precomp
+    assert zero_ct.shape[0] == S, (zero_ct.shape, S)
+    with _step(tm, "permute_add"):
+        perm = jax.random.permutation(kperm, S)
+        out = _by_slab("DROShuffle", chunk, (perm, zero_ct),
+                       lambda idx, zc: _dro_permute_add(cts, idx, zc),
+                       place=False)
+    return out, perm, r
 
-    eff = _chunk_of(S, chunk)
-    if not eff or eff >= S:
-        shuffled = jnp.take(cts, perm, axis=0)
-        return eg.ct_add(shuffled, zero_ct), perm, r
 
-    from . import proof_plane as plane
+def node_pass(key, cts, pub_tbl, precomp=None, pool=None, digest=None,
+              chunk: int | None = None, tm=None):
+    """One computing node's pass over the list, as every caller makes it
+    (LocalCluster.execute_survey, StreamEngine.advance, a remote CN's
+    shuffle_contrib, dro_pipeline): zero encryptions from `precomp` if the
+    caller holds some, else consumed from `pool` (a pool.CryptoPool, keyed
+    by the collective table's `digest`; strictly once) if it covers the
+    list, else made fresh for THIS pass; then permute and add. The
+    permutation is drawn from `key` whichever it is. Returns what
+    shuffle_rerandomize does."""
+    _require_table(pub_tbl, "node_pass")
+    S = int(cts.shape[0])
+    if precomp is None and pool is not None:
+        if digest is None:
+            from ..pool import store as _ps
 
-    perm_h = np.asarray(perm)
+            digest = _ps.key_digest(pub_tbl)
+        got = pool.try_consume_dro(digest, S)
+        if got is not None:
+            precomp = (jnp.asarray(got[0]), jnp.asarray(got[1]))
+            PROCESS.count("h2d_bytes", got[0].nbytes + got[1].nbytes)
+    return shuffle_rerandomize(key, cts, pub_tbl, precomp=precomp,
+                               chunk=chunk, tm=tm)
 
-    def stage(i, a, b):
-        # exact global permutation: host-permuted indices, per-slab gather
-        return plane.put_shard(
-            (jnp.take(cts, jnp.asarray(perm_h[a:b]), axis=0),
-             zero_ct[a:b]), i, donate=True)
 
-    def slab(i, gathered, zc):
-        return eg.ct_add(gathered, zc)
+def pick_add(agg, cts, tm=None):
+    """One ciphertext of the shuffled list added to each aggregate
+    (reference service.go:600-604): result i takes entry i mod S."""
+    from ..crypto import batching as B
 
-    slabs = [(a, min(a + eff, S)) for a in range(0, S, eff)]
-    parts = plane.dispatch_shards("DROShuffle", slab, slabs,
-                                  prefetch=stage)
-    return jnp.concatenate(parts, axis=0), perm, r
+    with _step(tm, "pick_add"):
+        idx = np.arange(int(agg.shape[0])) % int(cts.shape[0])
+        out = B.ct_add(agg, jnp.take(cts, jnp.asarray(idx), axis=0))
+        return jax.block_until_ready(out)
 
 
 def dro_pipeline(key, pub_tbl: eg.FixedBase, size: int, mean: float,
@@ -319,25 +408,15 @@ def dro_pipeline(key, pub_tbl: eg.FixedBase, size: int, mean: float,
                         "pub_tbl.table only to the shuffle layer")
     noise = generate_noise_values(size, mean, b, quanta, scale, limit)
     key, sub = jax.random.split(key)
-    cts = encrypt_noise(sub, pub_tbl, noise)
-    digest = None
-    if pool is not None:
-        from ..pool import store as _ps
-
-        digest = _ps.key_digest(pub_tbl.table)
-    S = int(cts.shape[0])
+    cts = encrypt_noise(sub, pub_tbl, noise, chunk=chunk)
     for _ in range(n_servers):
         key, sub = jax.random.split(key)
-        pc = None
-        if pool is not None:
-            got = pool.try_consume_dro(digest, S)
-            if got is not None:
-                pc = (jnp.asarray(got[0]), jnp.asarray(got[1]))
-        cts, _, _ = shuffle_rerandomize(sub, cts, pub_tbl.table,
-                                        precomp=pc, chunk=chunk)
+        cts, _, _ = node_pass(sub, cts, pub_tbl.table, pool=pool,
+                              chunk=chunk)
     return cts, noise
 
 
 __all__ = ["generate_noise_values", "encrypt_noise", "shuffle_rerandomize",
            "precompute_rerandomization", "save_precompute", "load_precompute",
-           "dro_pipeline", "slab_widths", "CHUNK"]
+           "node_pass", "pick_add", "dro_pipeline", "slab_widths", "CHUNK",
+           "PROGRAMS"]

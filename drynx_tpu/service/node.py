@@ -761,28 +761,16 @@ class DrynxNode:
         coll_pub = self.roster.collective_pub()
         tbl = self._pub_table(coll_pub)
         key = jax.random.PRNGKey(secrets.randbits(63))
-        # Consume pooled DRO precompute when the active pool covers this
-        # collective key: the fixed-base pass (the dominant cost) is
-        # skipped and the slab's single-consumption claim guarantees the
-        # randomness is never served twice, even across CN processes
-        # sharing one pool directory.
-        precomp = None
-        cpool = pool_mod.active_pool()
-        if cpool is not None:
-            got = cpool.try_consume_dro(pool_store.key_digest(tbl.table),
-                                        int(cts.shape[0]))
-            if got is not None:
-                precomp = (jnp.asarray(got[0]), jnp.asarray(got[1]))
-        if precomp is None:
-            # cold path: pay the fixed-base pass here, through the COUNTED
-            # builder (dro.PRECOMPUTE_CALLS) so pooled-vs-fresh serving is
-            # observable per process — the bench and tests assert the
-            # counter stays flat when slabs covered the need
-            k_pre, key = jax.random.split(key)
-            precomp = dro.precompute_rerandomization(k_pre, tbl.table,
-                                                     int(cts.shape[0]))
-        out_cts, perm, rs = dro.shuffle_rerandomize(key, cts, tbl.table,
-                                                    precomp=precomp)
+        # Pooled DRO precompute when the active pool covers this collective
+        # key: the fixed-base pass (the dominant cost) is skipped and the
+        # slab's single-consumption claim guarantees the randomness is
+        # never served twice, even across CN processes sharing one pool
+        # directory. Cold path: the pass pays the fixed-base mults itself,
+        # through the COUNTED builder (dro.PRECOMPUTE_CALLS) so
+        # pooled-vs-fresh serving is observable per process — the bench and
+        # tests assert the counter stays flat when slabs covered the need
+        out_cts, perm, rs = dro.node_pass(key, cts, tbl.table,
+                                          pool=pool_mod.active_pool())
         if msg.get("proofs"):
             from ..crypto.params import from_limbs
 
@@ -1178,9 +1166,7 @@ class DrynxNode:
                                       "proofs": proofs,
                                       "cts": pack_array(np.asarray(n_cts))})
                 n_cts = unpack_array_device(r["cts"])
-            V = int(agg.shape[0])
-            idx = np.arange(V) % int(n_cts.shape[0])
-            agg = B.ct_add(agg, jnp.take(n_cts, jnp.asarray(idx), axis=0))
+            agg = dro.pick_add(agg, n_cts)
 
         # key switch: gather contributions from every CN (including self).
         # A star round — every CN switches the SAME K0 component — so it

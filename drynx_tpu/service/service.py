@@ -47,7 +47,10 @@ from ..proofs import shuffle as shuffle_proof
 from ..resilience import faults
 from ..resilience import policy as rp
 from ..utils import log
-from ..utils.exec_store import StoredProgram
+from ..utils.exec_store import stored as _stored
+# `_trace_reads`: what the stored programs' keys hold of the environment,
+# under the name tests and readers of this module know
+from ..utils.exec_store import trace_reads as _trace_reads  # noqa: F401
 from ..utils.timers import PROCESS, PhaseTimers, install_listener
 from . import topology as topo
 from .proof_collection import VerifyCache, VerifyingNode, VNGroup
@@ -491,6 +494,20 @@ class LocalCluster:
             n += 1
         return n
 
+    def _take_shuffle_precomp(self, ci: int, size: int):
+        """CN `ci`'s prewarm_dro entry for a list of `size`, consumed
+        (popped, its persisted copy deleted), or None."""
+        entries = getattr(self, "_shuffle_precomp", {}).get((ci, size))
+        if not entries:
+            return None
+        pc, path = entries.pop()
+        if path is not None:
+            try:  # consume-once: drop the persisted copy
+                os.unlink(path)
+            except OSError:
+                pass
+        return pc
+
     # ------------------------------------------------------------------
     # Fused exec-path programs: the modular bucketed primitives cost one
     # trace+lower each (~25-30 medium programs, ~12 min of host lowering
@@ -516,8 +533,10 @@ class LocalCluster:
 
         return enc, _fused_agg, ks, _fused_dec
 
-    # the four programs of a survey, for the set-up report
-    FUSED = ("_fused_enc", "_fused_agg", "_fused_ks", "_fused_dec")
+    # the stored programs of a survey, for the set-up report: the four
+    # fused phases and the noise phase's three slab programs
+    FUSED = ("_fused_enc", "_fused_agg", "_fused_ks",
+             "_fused_dec") + dro.PROGRAMS
 
     # bucket-grid Profile axis: st.grid_buckets(q) — shared with admission
 
@@ -882,53 +901,28 @@ class LocalCluster:
             tm.end("ObfuscationPhase")
 
         # --- DRO / differential privacy noise phase ---------------------
-        noise_ct = None
         if q.diffp.enabled():
             mark("dro")
             tm.start("DROPhase")
             d = q.diffp
-            noise = dro.generate_noise_values(
-                d.noise_list_size, d.lap_mean, d.lap_scale, d.quanta,
-                d.scale, d.limit)
+            with tm.step("noise_values"):
+                noise = dro.generate_noise_values(
+                    d.noise_list_size, d.lap_mean, d.lap_scale, d.quanta,
+                    d.scale, d.limit)
             key, k_n = jax.random.split(key)
-            n_cts = dro.encrypt_noise(k_n, self.coll_tbl, noise)
-            # per-(CN, size) precomputation POOL (reference gob cache,
-            # service.go:34,316-317) — the fixed-base mults are the hot
-            # cost. Entries are CONSUMED (popped), never reused: re-using a
-            # re-randomization mask across surveys would let a proof
-            # observer cancel the masks and recover both permutations.
-            # Refill ahead of time with prewarm_dro().
-            pc_pool = getattr(self, "_shuffle_precomp", None)
-            if pc_pool is None:
-                pc_pool = self._shuffle_precomp = {}
+            n_cts = dro.encrypt_noise(k_n, self.coll_tbl, noise, tm=tm)
             for ci, cn in enumerate(self.cns):
                 key, k_sh = jax.random.split(key)
-                pc_key = (ci, int(n_cts.shape[0]))
-                pc = None
-                if pc_pool.get(pc_key):
-                    pc, pc_path = pc_pool[pc_key].pop()
-                    if pc_path is not None:
-                        import os
-
-                        try:  # consume-once: drop the persisted copy
-                            os.unlink(pc_path)
-                        except OSError:
-                            pass
-                if pc is None and self.pool is not None:
-                    # persistent pool (drynx_tpu.pool): slabs are claimed
-                    # strictly-once (tombstoned before release) and keyed
-                    # by the collective-key digest; a short pool falls
-                    # through to fresh precompute for this pass only
-                    got = self.pool.try_consume_dro(self._pool_digest,
-                                                    int(n_cts.shape[0]))
-                    if got is not None:
-                        pc = (jnp.asarray(got[0]), jnp.asarray(got[1]))
-                if pc is None:
-                    key, k_pc = jax.random.split(key)
-                    pc = dro.precompute_rerandomization(
-                        k_pc, self.coll_tbl.table, int(n_cts.shape[0]))
-                out_cts, perm, rs = dro.shuffle_rerandomize(
-                    k_sh, n_cts, self.coll_tbl.table, precomp=pc)
+                # zero encryptions, the pass's hot cost: this cluster's own
+                # prewarm_dro entry, else the persistent pool's slabs
+                # (drynx_tpu.pool; claimed strictly once), else made fresh
+                # for this pass. Never reused: re-using a re-randomization
+                # mask across surveys would let a proof observer cancel the
+                # masks and recover both permutations.
+                out_cts, perm, rs = dro.node_pass(
+                    k_sh, n_cts, self.coll_tbl.table,
+                    precomp=self._take_shuffle_precomp(ci, len(noise)),
+                    pool=self.pool, digest=self._pool_digest, tm=tm)
                 if proofs_on:
                     betas = [_limbs_to_int(r) for r in np.asarray(rs)]
                     pr = shuffle_proof.prove_shuffle(
@@ -941,9 +935,7 @@ class LocalCluster:
                         b=np.asarray(out_cts): _pickle((pr, a, b)))
                 n_cts = out_cts
             # one noise ct added per result (service.go:600-604)
-            idx = np.arange(V) % int(n_cts.shape[0])
-            noise_ct = jnp.take(n_cts, jnp.asarray(idx), axis=0)
-            agg = B.ct_add(agg, noise_ct)
+            agg = dro.pick_add(agg, n_cts, tm=tm)
             tm.end("DROPhase")
 
         # --- Key switch to the querier's key ----------------------------
@@ -1136,30 +1128,6 @@ class LocalCluster:
         t = threading.Thread(target=work, daemon=True)
         t.start()
         survey.proof_threads.append(t)
-
-
-def _trace_reads() -> dict:
-    """What the trace of the four programs below reads beside its arguments
-    and the package's source: the executable store keys on it
-    (utils/exec_store.py). `po.available()` reads DRYNX_NO_PALLAS and
-    INTERPRET, the kernels' wrappers pass INTERPRET and `field.UNROLL`
-    (DRYNX_FIELD_UNROLL) on as static arguments, DRYNX_BUCKET_TILE sets the
-    tile that `_fused_enc` is called at. `eg.BASE_TABLE.table`, closed over
-    by enc and ks, is a function of the source; the key tables, the secrets
-    and the discrete-log table are arguments."""
-    from ..crypto import field
-    from ..crypto import pallas_ops as po
-    from ..crypto import pallas_pairing as pp
-
-    return {"DRYNX_NO_PALLAS": os.environ.get("DRYNX_NO_PALLAS", "0"),
-            enc_tiles.ENV_TILE: os.environ.get(enc_tiles.ENV_TILE, ""),
-            "field.UNROLL": field.UNROLL,
-            "pallas_ops.INTERPRET": po.INTERPRET,
-            "pallas_pairing.INTERPRET": pp.INTERPRET}
-
-
-def _stored(fn) -> StoredProgram:
-    return StoredProgram(fn, _trace_reads)
 
 
 @_stored

@@ -72,7 +72,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..crypto import batching as B
 from ..crypto import elgamal as eg
 from ..encoding import stats as st
 from ..encoding import tiles as enc_tiles
@@ -81,7 +80,6 @@ from ..proofs import aggregation as agg_proof
 from ..proofs import range_proof as rproof
 from ..proofs import requests as rq
 from ..resilience import policy as rp
-from ..utils import log
 from ..utils.timers import PhaseTimers
 from . import topology as topo
 from .service import Survey, _once, _pickle
@@ -491,27 +489,14 @@ class StreamEngine:
                 d.scale, d.limit)
             k_adv = jax.random.fold_in(
                 self._pane_key(4, new_first), new_last)
-            n_cts = dro.encrypt_noise(k_adv, cluster.coll_tbl, noise)
+            n_cts = dro.encrypt_noise(k_adv, cluster.coll_tbl, noise, tm=tm)
             with cluster._proof_device_lock:
                 for ci in range(len(cluster.cns)):
-                    k_sh = jax.random.fold_in(k_adv, ci + 1)
-                    pc = None
-                    if cluster.pool is not None:
-                        got = cluster.pool.try_consume_dro(
-                            cluster._pool_digest, int(n_cts.shape[0]))
-                        if got is not None:
-                            pc = (jnp.asarray(got[0]), jnp.asarray(got[1]))
-                    if pc is None:
-                        log.lvl2(f"stream {self.stream_id}: pool short, "
-                                 f"fresh DRO precompute (cn {ci})")
-                        pc = dro.precompute_rerandomization(
-                            jax.random.fold_in(k_sh, 7),
-                            cluster.coll_tbl.table, int(n_cts.shape[0]))
-                    n_cts, _perm, _rs = dro.shuffle_rerandomize(
-                        k_sh, n_cts, cluster.coll_tbl.table, precomp=pc)
-                idx = np.arange(self.V) % int(n_cts.shape[0])
-                noise_ct = jnp.take(n_cts, jnp.asarray(idx), axis=0)
-                agg_n = B.ct_add(agg_n, noise_ct)
+                    n_cts, _perm, _rs = dro.node_pass(
+                        jax.random.fold_in(k_adv, ci + 1), n_cts,
+                        cluster.coll_tbl.table, pool=cluster.pool,
+                        digest=cluster._pool_digest, tm=tm)
+                agg_n = dro.pick_add(agg_n, n_cts, tm=tm)
             tm.end("DROPhase")
 
         # --- key switch + decrypt + decode (execute_survey tail) ---------

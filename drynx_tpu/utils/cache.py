@@ -10,8 +10,9 @@ cache calls `enable_compilation_cache()`; nothing else in the repo writes
 
 What the cache saves is XLA/Mosaic compile time. Tracing and lowering of
 the trace-time-unrolled limb kernels run before the cache lookup, so jax's
-cache cannot save them. For the four fused programs of a survey
-(`service._fused_enc/_agg/_ks/_dec`) the executable store does
+cache cannot save them. For the stored programs of a survey
+(`service._fused_enc/_agg/_ks/_dec`, `parallel/dro.PROGRAMS`) the
+executable store does
 (utils/exec_store.py): serialised executables under
 `<this directory>/exec_store/`, one file per program and shape, keyed
 before any tracing by the call's abstract arguments, a digest of the

@@ -253,6 +253,33 @@ class StoredProgram:
         return found(*args)
 
 
+def trace_reads() -> dict:
+    """What the trace of the program's stored G1 programs
+    (service._fused_enc/_agg/_ks/_dec, parallel/dro.PROGRAMS) reads beside
+    its arguments and the package's source: their keys hold it.
+    `po.available()` reads DRYNX_NO_PALLAS and INTERPRET, the kernels'
+    wrappers pass INTERPRET and `field.UNROLL` (DRYNX_FIELD_UNROLL) on as
+    static arguments, DRYNX_BUCKET_TILE sets the tile that `_fused_enc` is
+    called at. `eg.BASE_TABLE.table`, closed over by enc and ks, is a
+    function of the source; the key tables, the secrets and the
+    discrete-log table are arguments."""
+    from ..crypto import field
+    from ..crypto import pallas_ops as po
+    from ..crypto import pallas_pairing as pp
+    from ..encoding import tiles as enc_tiles
+
+    return {"DRYNX_NO_PALLAS": os.environ.get("DRYNX_NO_PALLAS", "0"),
+            enc_tiles.ENV_TILE: os.environ.get(enc_tiles.ENV_TILE, ""),
+            "field.UNROLL": field.UNROLL,
+            "pallas_ops.INTERPRET": po.INTERPRET,
+            "pallas_pairing.INTERPRET": pp.INTERPRET}
+
+
+def stored(fn) -> StoredProgram:
+    """`fn`, a module-level jit of the G1 kernels, kept in the store."""
+    return StoredProgram(fn, trace_reads)
+
+
 def _concrete(args) -> bool:
     import jax
 
@@ -271,4 +298,5 @@ def devices_of(args) -> list:
 
 
 __all__ = ["ExecStore", "StoredProgram", "active", "avals_of", "key_of",
-           "devices_of", "process_facts", "source_digest", "SUBDIR"]
+           "devices_of", "process_facts", "source_digest", "SUBDIR",
+           "trace_reads", "stored"]

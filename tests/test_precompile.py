@@ -220,11 +220,11 @@ def test_cli_list_buckets_includes_tile_programs(capsys):
 
 
 def test_registry_pool_program_set():
-    """Profile.n_noise > 0 must add the DRO pool/slab programs (the raw
-    jits the precompute refill + shuffle paths dispatch) at exactly the
-    dro.slab_widths chunk widths plus the monolithic width — and must
-    only ever ADD programs: the non-diffp registry stays a strict subset,
-    so pooling can never silently drop AOT coverage."""
+    """Profile.n_noise > 0 must add the DRO pool/slab programs (the three
+    stored programs the noise encryption, the precompute refill and the
+    shuffle dispatch) at exactly the dro.slab_widths chunk widths — and
+    must only ever ADD programs: the non-diffp registry stays a strict
+    subset, so pooling can never silently drop AOT coverage."""
     from drynx_tpu.parallel import dro
 
     base = cc.BENCH
@@ -237,8 +237,9 @@ def test_registry_pool_program_set():
     assert {s.phase for s in extra} == {"DROPool"}
     assert {s.kind for s in extra} == {"pool"}
     # every slab width the chunked path dispatches is certified
-    widths = set(dro.slab_widths(10000)) | {10000}
-    for op in ("encrypt_with_tables", "int_to_scalar", "ct_add"):
+    widths = set(dro.slab_widths(10000))
+    assert widths == {4096, 10000 - 2 * 4096}
+    for op in ("dro_noise_enc", "dro_zero_enc", "dro_permute_add"):
         got = {int(s.name.rsplit("@", 1)[1]) for s in extra if s.op == op}
         assert got == widths, (op, got, widths)
     # pool programs always dispatch (plain device jits, no backend gate)
@@ -286,7 +287,7 @@ def test_cli_list_noise_includes_pool_programs(capsys):
 
     assert cli.main(["--list", "--noise", "10000"]) == 0
     out = capsys.readouterr().out
-    assert "pool:encrypt_with_tables@4096" in out
+    assert "pool:dro_zero_enc@4096" in out
     assert "DROPool" in out
     # no diffp axis -> no pool programs
     assert cli.main(["--list"]) == 0

@@ -117,8 +117,12 @@ def test_survey_diffp_adds_noise(cluster):
                                        diffp=diffp)
     res = cluster.run_survey(sq)
     clear = int(np.concatenate(per_dp).sum())
-    # noise list values are bounded by limit*scale
-    assert abs(res.result - clear) <= 8
+    # the noise added is a member of the published list (the same case at
+    # tier-1 size, with the list's decryption beside it: tests/test_dro.py)
+    from drynx_tpu.parallel import dro
+
+    members = dro.generate_noise_values(16, 0.0, 2.0, 1.0, 1.0, 8.0)
+    assert res.result - clear in set(members.tolist())
 
 
 def test_survey_cutting_factor_replicates_ciphertexts(cluster):
