@@ -63,7 +63,12 @@ def secret_to_limbs(x: int) -> np.ndarray:
 class FixedBase:
     """4-bit-window fixed-base table for one long-lived base point.
 
-    table[w, d] = d * 16^w * P  as (64, 16, 3, 16) Jacobian Montgomery limbs.
+    table[w, d] = d * 16^w * P  as (64, 16, 3, 16) Montgomery limbs, every
+    entry AFFINE: Z is the Montgomery one, or zero for infinity (digit 0,
+    and every entry of the table of the point at infinity). The TPU ladder
+    (pallas_ops._fixed_base_kernel) rests on it: it adds an entry with the
+    mixed Jacobian + affine addition and never reads Z's value. This
+    constructor is the only maker of tables (`curve.from_ref` writes Z).
     """
 
     def __init__(self, point_affine):
@@ -84,7 +89,9 @@ class FixedBase:
     @classmethod
     def from_table(cls, table) -> "FixedBase":
         """Rehydrate from a persisted (64, 16, 3, 16) table, skipping the
-        host EC ladder build (crypto-pool fb tenant)."""
+        host EC ladder build (crypto-pool fb tenant). The table must have
+        been built by the constructor: its entries are affine (class
+        docstring), which no arithmetic on the device keeps."""
         fb = cls.__new__(cls)
         fb.table = jnp.asarray(table, dtype=jnp.uint32)
         return fb

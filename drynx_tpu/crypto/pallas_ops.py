@@ -33,6 +33,8 @@ MASK = np.uint32(params.LIMB_MASK)  # numpy literal: safe inside kernels
 
 _M_FP = np.asarray(params.to_limbs(params.P), dtype=np.uint32)
 _NPRIME_FP = np.uint32(params.NPRIME)
+_N_ORDER = np.asarray(params.to_limbs(params.N), dtype=np.uint32)
+_ONE_MONT = np.asarray(params.to_limbs(params.R % params.P), dtype=np.uint32)
 
 LANES = 128                      # batch tile width
 
@@ -150,7 +152,8 @@ def _pt_select(cond, p, q):
 
 
 def make_group(m_const, nprime):
-    """Bind the modulus constants once; returns (double, add_complete)."""
+    """Bind the modulus constants once; returns (double, add_complete,
+    add_mixed)."""
     mul = lambda a, b: mont_mul(a, b, m_const, nprime)
     add_ = lambda a, b: fadd(a, b, m_const)
     sub_ = lambda a, b: fsub(a, b, m_const)
@@ -208,7 +211,31 @@ def make_group(m_const, nprime):
         res = _pt_select(p_inf, q, res)
         return res
 
-    return pdouble, padd
+    def pmadd(p, x2, y2, q_inf):
+        """p + q for an AFFINE addend q = (x2, y2), q_inf (B,) bool where q
+        is the point at infinity (x2, y2 then arbitrary). 11 products
+        (Hankerson-Menezes-Vanstone mixed addition: Z2 == 1 takes Z2Z2,
+        U1, S1 and the (Z1+Z2)^2 of `padd` away), no doubling.
+
+        Complete for infinity on either side and for q == -p (H == 0 gives
+        Z3 == 0 by itself). NOT for q == p: the caller must know that the
+        two finite operands differ, as a fixed-base ladder over ascending
+        windows does for every scalar below the group order."""
+        X1, Y1, Z1 = p
+        Z1Z1 = mul(Z1, Z1)
+        H = sub_(mul(x2, Z1Z1), X1)
+        r = sub_(mul(y2, mul(Z1, Z1Z1)), Y1)
+        Z3 = mul(Z1, H)
+        HH = mul(H, H)
+        HHH = mul(H, HH)
+        V = mul(X1, HH)
+        X3 = sub_(sub_(mul(r, r), HHH), add_(V, V))
+        Y3 = sub_(mul(r, sub_(V, X3)), mul(Y1, HHH))
+        res = _pt_select(fis_zero(Z1), (x2, y2, _one_like(Z1)),
+                         (X3, Y3, Z3))
+        return _pt_select(q_inf, p, res)
+
+    return pdouble, padd, pmadd
 
 
 def _inf_like(p):
@@ -220,6 +247,19 @@ def _inf_like(p):
     return (X, X, jnp.zeros_like(p[2]))
 
 
+def _one_like(a):
+    """The Montgomery one (R mod p) as tiles shaped like a."""
+    return jnp.stack([jnp.full(a.shape[1:], l, jnp.uint32)
+                      for l in _ONE_MONT])
+
+
+def canonical_scalar(k, n):
+    """k mod n for any 256-bit k on (16, B) tiles, n (16, 1) the group
+    order's limbs: 2n > 2^256, so one conditional subtraction suffices."""
+    diff, borrow = _sub_limbs(k, jnp.broadcast_to(n, k.shape))
+    return jnp.where((borrow == 0)[None, :], diff, k)
+
+
 # ---------------------------------------------------------------------------
 # Windowed scalar-mult kernel: whole ladder in one pallas_call
 # ---------------------------------------------------------------------------
@@ -228,7 +268,7 @@ def _scalar_mul_kernel(m_ref, np_ref, p_ref, k_ref, o_ref, dig_ref,
                        *, n_windows: int = 64):
     m = m_ref[:]                              # (16, 1) modulus limbs
     nprime = np_ref[0, 0]
-    pdouble, padd = make_group(m, nprime)
+    pdouble, padd, _ = make_group(m, nprime)
 
     P = (p_ref[0], p_ref[1], p_ref[2])        # each (16, B)
     k = k_ref[:]                              # (16, B)
@@ -331,15 +371,25 @@ def _pallas_scalar_mul(m_in, np_in, pt, kt, n_tiles, Np, n_windows=64,
 # Fixed-base windowed mult kernel: shared (64, 16)-entry table, add-only
 # ---------------------------------------------------------------------------
 
-def _fixed_base_kernel(m_ref, np_ref, tab_ref, k_ref, o_ref, dig_ref):
+def _fixed_base_kernel(m_ref, np_ref, n_ref, tab_ref, k_ref, o_ref, dig_ref):
     """tab_ref: (W, 16, 48) — row w holds [16 limbs x (coord c * 16 + digit
-    v)] of the precomputed points v * 16^w * P (v=0 row is infinity).
-    W table-gather adds, no doubles (the 16^w factors are baked in); W < 64
-    serves scalars known to be < 16^W (small plaintexts)."""
+    v)] of the precomputed AFFINE points v * 16^w * P (Z the Montgomery one,
+    or zero for infinity: the v=0 column, or every column of a table of the
+    point at infinity). W mixed (Jacobian + affine) additions, no doubles
+    (the 16^w factors are baked in); W < 64 serves scalars known to be
+    < 16^W (small plaintexts).
+
+    The window step handles infinity on either side and no other special
+    case, because none can arise: the windows ascend, so at window w the
+    accumulator is (k mod 16^w) * P and the addend d * 16^w * P with d in
+    1..15; they are the same or opposite points only if d * 16^w -/+
+    (k mod 16^w) is a multiple of the group order n, which k < n excludes
+    (n / 2^252 = 8.98 and G1 has prime order). k is made canonical first
+    (n_ref: n's limbs), so that holds for every 256-bit input."""
     m = m_ref[:]
     nprime = np_ref[0, 0]
-    pdouble, padd = make_group(m, nprime)
-    k = k_ref[:]                              # (16, B)
+    _, _, pmadd = make_group(m, nprime)
+    k = canonical_scalar(k_ref[:], n_ref[:])  # (16, B)
     B = k.shape[1]
     W = dig_ref.shape[0]
 
@@ -349,22 +399,26 @@ def _fixed_base_kernel(m_ref, np_ref, tab_ref, k_ref, o_ref, dig_ref):
         rows.append((k[limb] >> np.uint32(4 * s)) & np.uint32(0xF))
     dig_ref[:] = jnp.stack(rows)              # (W, B)
 
-    def sel(row, d):
-        # row (16, 48) = limbs x (c*16+v); per-lane digit select by splat
-        pts = []
-        for c in range(3):
-            cand = row[:, c * 16:(c + 1) * 16]          # (16, 16)
-            acc = jnp.broadcast_to(cand[:, 0:1], (NL, B))
-            for v in range(1, 16):
-                splat = jnp.broadcast_to(cand[:, v:v + 1], (NL, B))
-                acc = jnp.where((d == v)[None, :], splat, acc)
-            pts.append(acc)
-        return tuple(pts)
+    def sel(cand, masks):
+        # cand (R, 16) = rows x digit v; per-lane digit select by splat
+        R = cand.shape[0]
+        acc = jnp.broadcast_to(cand[:, 0:1], (R, B))
+        for v in range(1, 16):
+            splat = jnp.broadcast_to(cand[:, v:v + 1], (R, B))
+            acc = jnp.where(masks[v], splat, acc)
+        return acc
 
     def body(w, acc):
-        row = tab_ref[pl.ds(w, 1)][0]         # (16, 48)
+        row = tab_ref[pl.ds(w, 1)][0]         # (16, 48) = limbs x (c*16+v)
         d = dig_ref[pl.ds(w, 1), :][0]        # (B,)
-        return padd(acc, sel(row, d))
+        masks = [(d == v)[None, :] for v in range(16)]
+        z = row[:, 32:48]
+        z_or = z[0:1]                         # (1, 16): OR of Z's limbs
+        for l in range(1, NL):
+            z_or = z_or | z[l:l + 1]
+        q_inf = sel(z_or, masks)[0] == 0
+        return pmadd(acc, sel(row[:, 0:16], masks),
+                     sel(row[:, 16:32], masks), q_inf)
 
     zero = jnp.zeros((NL, B), jnp.uint32)
     acc0 = _inf_like((zero, zero, zero))
@@ -388,6 +442,7 @@ def _fixed_base_mul_flat(table, k, n_windows: int, interpret: bool):
 
     m_in = jnp.asarray(_M_FP[:, None], dtype=jnp.uint32)
     np_in = jnp.asarray([[_NPRIME_FP]], dtype=jnp.uint32)
+    n_in = jnp.asarray(_N_ORDER[:, None], dtype=jnp.uint32)
     with jax.enable_x64(False):
         out = pl.pallas_call(
             _fixed_base_kernel,
@@ -397,6 +452,8 @@ def _fixed_base_mul_flat(table, k, n_windows: int, interpret: bool):
                              memory_space=pltpu.VMEM),
                 pl.BlockSpec((1, 1), lambda i: (0, 0),
                              memory_space=pltpu.SMEM),
+                pl.BlockSpec((NL, 1), lambda i: (0, 0),
+                             memory_space=pltpu.VMEM),
                 pl.BlockSpec((W, NL, 48), lambda i: (0, 0, 0),
                              memory_space=pltpu.VMEM),
                 pl.BlockSpec((NL, LANES), lambda i: (0, i),
@@ -407,14 +464,18 @@ def _fixed_base_mul_flat(table, k, n_windows: int, interpret: bool):
             out_shape=jax.ShapeDtypeStruct((3, NL, Np), jnp.uint32),
             scratch_shapes=[pltpu.VMEM((W, LANES), jnp.uint32)],
             interpret=interpret,
-        )(m_in, np_in, tt, kt)
+        )(m_in, np_in, n_in, tt, kt)
     return jnp.transpose(out, (2, 0, 1))[:N]
 
 
 def fixed_base_mul_flat(table, k, n_windows: int = 64):
     """k*P via a shared fixed-base window table. table: (64, 16, 3, 16) as
-    built by elgamal.FixedBase; k: (N, 16) plain scalars -> (N, 3, 16).
-    n_windows < 64 truncates the ladder for small scalars (k < 16^W)."""
+    built by elgamal.FixedBase, whose entries are AFFINE (Z the Montgomery
+    one, or zero for infinity): the kernel adds them with the mixed
+    addition and reads of Z only whether it is zero. k: (N, 16) plain
+    scalars, any 256-bit value (reduced mod n inside) -> (N, 3, 16)
+    Jacobian. n_windows < 64 truncates the ladder for small scalars
+    (k < 16^W)."""
     return _fixed_base_mul_flat(table, k, n_windows, INTERPRET)
 
 
@@ -424,7 +485,7 @@ def fixed_base_mul_flat(table, k, n_windows: int = 64):
 
 def _point_add_kernel(m_ref, np_ref, p_ref, q_ref, o_ref):
     m = m_ref[:]
-    _, padd = make_group(m, np_ref[0, 0])
+    _, padd, _ = make_group(m, np_ref[0, 0])
     r = padd((p_ref[0], p_ref[1], p_ref[2]),
              (q_ref[0], q_ref[1], q_ref[2]))
     o_ref[0], o_ref[1], o_ref[2] = r
@@ -433,7 +494,7 @@ def _point_add_kernel(m_ref, np_ref, p_ref, q_ref, o_ref):
 def _point_reduce_kernel(m_ref, np_ref, p_ref, o_ref):
     """p_ref: (R, 3, 16, B) — sum rows 0..R-1 with the complete group add."""
     m = m_ref[:]
-    _, padd = make_group(m, np_ref[0, 0])
+    _, padd, _ = make_group(m, np_ref[0, 0])
     R = p_ref.shape[0]
     acc = (p_ref[0, 0], p_ref[0, 1], p_ref[0, 2])
     for r in range(1, R):                     # R is small + static: unroll
@@ -534,4 +595,5 @@ def available() -> bool:
 
 __all__ = ["scalar_mul_flat", "fixed_base_mul_flat", "point_add_flat",
            "point_reduce_flat", "mont_mul", "fadd", "fsub", "make_group",
+           "canonical_scalar",
            "available", "LANES"]

@@ -15,9 +15,11 @@ Each check is individually contained — one kernel failing (or hanging the
 lowering) must not erase the record of the ones before it (partial results
 are flushed to TESTS_TPU.json after every check).
 
-Usage:  python scripts/pallas_parity.py  [--skip-slow]
+Usage:  python scripts/pallas_parity.py  [--skip-slow] [--only NAME]
 (--skip-slow drops the Miller/pair/final-exp family, whose lowering is the
-expensive tail; the GT/ladder families alone validate everything new.)
+expensive tail; the GT/ladder families alone validate everything new.
+--only runs the checks whose name contains NAME: the one kernel a PR
+touched, at that kernel's lowering cost and not the whole family's.)
 """
 import argparse
 import json
@@ -36,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 RESULTS = []
+ONLY = ""
 OUT_PATH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "TESTS_TPU.json")
 
@@ -47,6 +50,8 @@ def flush():
 
 
 def check(name, fn):
+    if ONLY not in name:
+        return
     t0 = time.perf_counter()
     try:
         fn()
@@ -69,7 +74,10 @@ def check(name, fn):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--skip-slow", action="store_true")
+    ap.add_argument("--only", default="")
     args = ap.parse_args()
+    global ONLY
+    ONLY = args.only
 
     from drynx_tpu.crypto import batching as B
     from drynx_tpu.crypto import curve as C
@@ -200,11 +208,29 @@ def main():
     check("scalar_mul_flat (full 64-window ladder)", c_ladder64)
 
     def c_fixed_base():
-        ks = [1, 2, 12345]
-        got = po.fixed_base_mul_flat(eg.BASE_TABLE.table,
-                                     jnp.asarray(F.from_int(ks)))
-        for i, k in enumerate(ks):
-            assert C.to_ref(got[i]) == refimpl.g1_mul(refimpl.G1, k), i
+        # the ladder adds affine table entries with the mixed addition and
+        # reduces k mod n first: the edges of both, over two tiles of
+        # lanes, for the generator's table and a public key's
+        n = params.N
+        ks = [0, 1, 2, 12345, n - 1, n, n + 1, 2 ** 256 - 1,
+              (8 << 252) + 12345, 15 << 248, 16 ** 63, 0xF0F0 << 100]
+        ks += [int.from_bytes(rng.bytes(32), "little") for _ in range(188)]
+        kd = jnp.asarray(F.from_int(ks))
+        pub = refimpl.g1_mul(refimpl.G1, rfp() % n)
+        for base, tbl in [(refimpl.G1, eg.BASE_TABLE),
+                          (pub, eg.pub_table(pub))]:
+            got = C.to_ref(po.fixed_base_mul_flat(tbl.table, kd))
+            for i, k in enumerate(ks):
+                assert got[i] == refimpl.g1_mul(base, k % n), (i, hex(k))
+        small = [0, 1, 15, 16, 200, 16 ** 16 - 1, 0x1234567890ABCDEF]
+        got = C.to_ref(po.fixed_base_mul_flat(
+            eg.BASE_TABLE.table, jnp.asarray(F.from_int(small)),
+            n_windows=16))
+        for i, k in enumerate(small):
+            assert got[i] == refimpl.g1_mul(refimpl.G1, k), (i, hex(k))
+        got = C.to_ref(po.fixed_base_mul_flat(
+            eg.FixedBase(None).table, kd[:8]))
+        assert got == [None] * 8
 
     check("fixed_base_mul_flat", c_fixed_base)
 

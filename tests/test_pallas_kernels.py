@@ -13,6 +13,8 @@ import pytest
 # The full ladder kernels take many minutes to compile through the
 # interpreter on CPU; they are validated on real TPU by
 # scripts/pallas_probe.py. Opt in with DRYNX_PALLAS_INTERPRET_TESTS=1.
+# (The fixed-base ladder's take 47-49 s each since its window step is the
+# mixed addition; one test of it, against the oracle alone, is tier-1.)
 heavy = pytest.mark.skipif(
     os.environ.get("DRYNX_PALLAS_INTERPRET_TESTS", "0") != "1",
     reason="ladder-kernel interpret compile is minutes-slow on CPU; "
@@ -69,10 +71,31 @@ def test_scalar_mul_kernel_matches_jnp():
 def test_fixed_base_kernel_matches_jnp():
     n = 5
     k, ss = _rand_scalars(n)
+    # edges of the mixed-addition ladder: the largest scalar, and a top
+    # digit of 8 (n's own: the last window whose addend could meet the
+    # accumulator if k were not below n)
+    ss[2], ss[3] = params.N - 1, (8 << 252) + 12345
+    k = jnp.asarray(F.from_int(ss))
     out_pallas = po.fixed_base_mul_flat(eg.BASE_TABLE.table, k)
     out_jnp = eg._fixed_base_mul_jnp(eg.BASE_TABLE.table, k)
     _assert_points_equal(out_pallas, out_jnp)
     assert C.to_ref(out_pallas[1]) == refimpl.g1_mul(refimpl.G1, ss[1])
+
+
+def test_fixed_base_kernel_edges_against_oracle():
+    """The whole 64-window kernel through the interpreter (26 s of
+    compile on the 8-core sandbox since its window step is the mixed
+    addition), against the Python oracle alone: the scalars around the
+    group order that the kernel reduces itself, a top digit of 8, zero
+    digits low and high, and the table of the point at infinity."""
+    n = params.N
+    ks = [0, 1, n - 1, n, n + 1, 2 ** 256 - 1, (8 << 252) + 12345,
+          0xF0F0 << 100]
+    k = jnp.asarray(F.from_int(ks))
+    out = C.to_ref(po.fixed_base_mul_flat(eg.BASE_TABLE.table, k))
+    assert out == [refimpl.g1_mul(refimpl.G1, s) for s in ks]
+    out = po.fixed_base_mul_flat(eg.FixedBase(None).table, k)
+    assert not np.asarray(out)[:, 2].any()
 
 
 @heavy
