@@ -490,7 +490,6 @@ def main_child():
         from drynx_tpu.proofs import requests as rq
         from drynx_tpu.utils.timers import PhaseTimers
 
-        PhaseTimers.echo = True  # stream phase completions to stderr live
         cc.CompileStats.echo = True  # per-program AOT rows to stderr live
         cc.install_cache_listener()  # count persistent-cache hits
 

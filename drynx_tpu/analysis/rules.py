@@ -42,8 +42,8 @@ class JitGlobalCapture(Rule):
     """A @jax.jit function (or a pallas_call builder — its body runs at
     trace time) reading a *mutable* module global bakes the value into the
     trace cache, keyed only on shapes/static args. Flipping the flag later
-    (monkeypatch, kill-switch) silently reuses stale traces — exactly the
-    INTERPRET trace-cache leak in ADVICE.md. Pass such values as static
+    (monkeypatch, kill-switch) silently reuses stale traces — the
+    INTERPRET trace-cache leak of round 5. Pass such values as static
     arguments, or accept the capture explicitly via the baseline + a
     cache-clearing teardown. This rule covers flags defined in the SAME
     module; imported ones are handled by cross-module-flag-capture, which

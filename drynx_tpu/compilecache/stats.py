@@ -3,9 +3,9 @@
 The precompile driver (registry.py) is SERIAL by design, so per-program
 rows are recorded in dispatch order and persistent-cache hits can be
 attributed to the program whose .compile() triggered them. With the class
-flag `echo` set (bench --verbose, the CLI), every program prints to stderr
-as it finishes — a killed cold-start run still shows where the wall went,
-the same rationale as PhaseTimers.echo (utils/timers.py).
+flag `echo` set (the precompile CLI, bench.py), every program prints to
+stderr as it finishes — a killed cold-start run still shows where the wall
+went.
 """
 from __future__ import annotations
 

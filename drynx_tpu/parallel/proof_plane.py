@@ -45,8 +45,8 @@ from ..utils.timers import PhaseTimers
 ENV_FLAG = "DRYNX_PROOF_PLANE"
 
 # Async shard pipeline kill-switch: "serial"/"off" restores the
-# block-per-shard dispatch loop (the pre-device-path behavior the
-# bench_device_path supervisor compares against).
+# block-per-shard dispatch loop (the reference side of
+# tests/test_device_path.py::test_async_dispatch_matches_serial).
 ASYNC_ENV = "DRYNX_ASYNC_DISPATCH"
 
 # Batches smaller than this never shard: the per-shard dispatch overhead

@@ -6,8 +6,8 @@ pool's slab width and deposits the result under the collective-key
 digest. The standing server (server/scheduler.py) calls ``refill_slab``
 cooperatively on its drain thread — one slab per drain iteration, under
 the cluster's proof-device lock, in the encode/verify pipeline gaps —
-which is the same pattern its compile lane uses; offline tooling
-(scripts/bench_pool.py) calls ``refill_to`` in a loop.
+which is the same pattern its compile lane uses; a caller that fills a
+pool ahead of time (tests/test_pool.py) calls ``refill_to``.
 """
 from __future__ import annotations
 

@@ -17,13 +17,13 @@ Threading contract: ``run_open``/``run_closed`` run the server's
 r05 rule drain() follows) and the submitters on side threads; submitters
 only call ``submit()``, which never traces beyond admission triage.
 
-The ``SyntheticCluster`` is a calibrated stub service plane for
-saturation sweeps: encode costs a drain-thread wait and verify costs a
-worker-side blocking wait (modeling the remote-VN RTTs and proof-thread
-joins a real deployment blocks on), so offered-load sweeps and
-worker-scaling curves run in seconds and are meaningful on a 1-core
-host. Real-crypto gates (transcript byte-identity across worker counts)
-run against a real LocalCluster in scripts/bench_load.py instead.
+The ``SyntheticCluster`` is a test fake of the service plane: encode is
+a drain-thread wait and verify a worker-side blocking wait, so the
+scheduler's accounting, shedding and fairness can be tested in seconds
+with no jax work. What it reads is waits, never a speed of this system.
+The real-crypto gate (transcript byte-identity across worker counts)
+runs against a real LocalCluster in
+tests/test_server.py::test_server_end_to_end_batched_equals_serial.
 """
 from __future__ import annotations
 

@@ -177,7 +177,6 @@ class VerifyingNode:
             return self._receive_range_buffered(req, st, joint)
         sample = self.thresholds.get(req.survey_id, {}).get(req.proof_type, 1.0)
         pub = self.pubs.get(req.sender_id)
-        t0 = time.perf_counter()
         vfn = self.verify_fns.get(req.proof_type)
         if vfn is not None:
             import hashlib
@@ -192,19 +191,8 @@ class VerifyingNode:
                 return self.verify_cache.get_or_compute(key, compute)
         code = (rq.BM_BADSIG if pub is None else rq.verify_proof_request(
             req, pub, sample, vfn, self.rng))
-        self._echo_verify(req, t0, code)
         self._record(st, req.storage_key(), req.data, code)
         return code
-
-    def _echo_verify(self, req, t0: float, code: int) -> None:
-        from ..utils.timers import PhaseTimers
-
-        if PhaseTimers.echo:
-            import sys
-
-            print(f"    [vn] {self.name} verify {req.proof_type} from "
-                  f"{req.sender_id}: {time.perf_counter() - t0:.3f}s "
-                  f"code={code}", file=sys.stderr, flush=True)
 
     def _record(self, st: SurveyProofState, key: str, data: bytes,
                 code: int) -> None:
@@ -253,7 +241,6 @@ class VerifyingNode:
         """Joint-verify a snapshot of buffered range payloads and record
         their codes. The caller must have set st.range_flushed under the
         lock before snapshotting (exactly one flush per survey)."""
-        t0 = time.perf_counter()
         keys = sorted(pending)
         to_verify = [k for k in keys if pending[k][1]]
 
@@ -291,15 +278,6 @@ class VerifyingNode:
             code = (rq.BM_TRUE if verdicts.get(k)
                     else rq.BM_FALSE) if was_sampled else rq.BM_RECVD
             self._record(st, k, r.data, code)
-        from ..utils.timers import PhaseTimers
-
-        if PhaseTimers.echo:
-            import sys
-
-            print(f"    [vn] {self.name} JOINT range verify of "
-                  f"{len(to_verify)}/{len(keys)} payloads: "
-                  f"{time.perf_counter() - t0:.3f}s", file=sys.stderr,
-                  flush=True)
 
     def range_ready(self, survey_id: str) -> bool:
         """True once every expected range payload is buffered (or the
@@ -343,7 +321,6 @@ class VerifyingNode:
             for sid, pending in snap.items():
                 self._flush_range(self.surveys[sid], sid, pending, joint)
             return list(snap)
-        t0 = time.perf_counter()
         keys_by_sid = {sid: sorted(p) for sid, p in snap.items()}
         to_verify = {sid: [k for k in keys_by_sid[sid] if snap[sid][k][1]]
                      for sid in snap}
@@ -384,16 +361,6 @@ class VerifyingNode:
                 code = (rq.BM_TRUE if verdicts.get(k)
                         else rq.BM_FALSE) if was_sampled else rq.BM_RECVD
                 self._record(st, k, r.data, code)
-        from ..utils.timers import PhaseTimers
-
-        if PhaseTimers.echo:
-            import sys
-
-            n_pay = sum(len(v) for v in payloads.values())
-            print(f"    [vn] {self.name} CROSS-SURVEY range verify of "
-                  f"{n_pay} payloads across {len(snap)} surveys: "
-                  f"{time.perf_counter() - t0:.3f}s", file=sys.stderr,
-                  flush=True)
         return list(snap)
 
     def adjust_expected(self, survey_id: str, drop: int,

@@ -370,10 +370,9 @@ def device_decode_min_bytes() -> int:
     dispatches (upload + widen program), ~1 ms on the CPU backend —
     cheaper than the host astype only once the segment is large enough
     to amortize them (and, on a real accelerator, large enough that
-    shipping half the bytes over PCIe matters). BENCH_DEVPATH_r01
-    measured the unthresholded path costing ~10x on small proof
-    payloads. ``DRYNX_DEVICE_DECODE_MIN=0`` forces the device widen for
-    every narrowed segment."""
+    shipping half the bytes over PCIe matters).
+    ``DRYNX_DEVICE_DECODE_MIN=0`` forces the device widen for every
+    narrowed segment."""
     try:
         return int(os.environ.get("DRYNX_DEVICE_DECODE_MIN",
                                   _DEVICE_MIN_DEFAULT))

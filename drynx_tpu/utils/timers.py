@@ -81,13 +81,8 @@ class _Open:
 
 class PhaseTimers:
     """Thread-safe named wall-clock timers accumulating per-phase seconds,
-    and the spans under them.
+    and the spans under them."""
 
-    With the class flag `echo` set, every completed phase prints to stderr
-    immediately — so a benchmark killed mid-run still shows where the time
-    went (round-2 driver timeouts erased all timing evidence)."""
-
-    echo = False
     step_cpu = False        # CPU seconds on steps too: the process tracer
 
     def __init__(self, survey: Optional[str] = None):
@@ -135,11 +130,7 @@ class PhaseTimers:
             op = self._open.pop(name, None)
         if op is None:
             return 0.0
-        dt = self._unwind(op, now)
-        if PhaseTimers.echo:
-            print(f"    [phase] {name}: {dt:.3f}s", file=sys.stderr,
-                  flush=True)
-        return dt
+        return self._unwind(op, now)
 
     def _unwind(self, op: _Open, now: float) -> float:
         """Close `op`, and first what was left open under it on this
@@ -174,9 +165,6 @@ class PhaseTimers:
         their time (service.py: AllProofs / Verify<Type>)."""
         with self._lock:
             self._acc[name] = self._acc.get(name, 0.0) + dt
-        if PhaseTimers.echo:
-            print(f"    [phase] {name}: +{dt:.3f}s", file=sys.stderr,
-                  flush=True)
 
     def add_split(self, phase: str, kind: str, dt: float) -> None:
         """Attribute a span to the host_glue/device_compute split of a

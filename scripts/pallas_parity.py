@@ -7,13 +7,13 @@ interpret mode needs ~10 min PER KERNEL on this box class, so hardware is
 the only realistic validator. Run me FIRST in any TPU session, before any
 bench: every kernel gets a pass/fail/time line against crypto/refimpl (the
 oracle every kernel is defined against), and the JSON verdict goes to
-stdout AND TESTS_TPU.json for the committed record.
+stdout AND chiprun_out/pallas_parity.json (what a chip call brings back).
 
 Ordering: kernels that have never run on hardware at HEAD come FIRST, so a
 session cut short by the driver still validates the highest-risk code.
 Each check is individually contained — one kernel failing (or hanging the
 lowering) must not erase the record of the ones before it (partial results
-are flushed to TESTS_TPU.json after every check).
+are flushed to chiprun_out/pallas_parity.json after every check).
 
 Usage:  python scripts/pallas_parity.py  [--skip-slow] [--only NAME]
 (--skip-slow drops the Miller/pair/final-exp family, whose lowering is the
@@ -40,10 +40,11 @@ import numpy as np
 RESULTS = []
 ONLY = ""
 OUT_PATH = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "TESTS_TPU.json")
+    os.path.abspath(__file__))), "chiprun_out", "pallas_parity.json")
 
 
 def flush():
+    os.makedirs(os.path.dirname(OUT_PATH), exist_ok=True)
     with open(OUT_PATH, "w") as f:
         json.dump({"backend": jax.default_backend(),
                    "checks": RESULTS}, f, indent=1)

@@ -1,12 +1,12 @@
 """Minimal repro hunt for the XLA:CPU accumulated-compile segfault.
 
-What the full suite observes (pytest.ini, scripts/run_suite.py): in a
+What the full suite once observed (rounds 3-4, one process per file then): in a
 long-lived process that has compiled enough DISTINCT nontrivial programs,
 a subsequent compile can segfault inside the XLA CPU backend. Sites that
 crash mid-suite pass in isolation; a process-wide compile lock and a
 512 MB compile-thread stack (drynx_tpu/__init__.py) did not change it, so
 the trigger is compiler-internal accumulated state, not concurrency or
-stack depth. The suite routes around it with per-file process isolation —
+stack depth. The suite routed around it with per-file process isolation —
 this script is the exit criterion for that quarantine (round-4 VERDICT
 weak #7): a standalone repro, independent of this repo's crypto code, that
 can back an upstream jax issue or a version bisect.

@@ -5,7 +5,7 @@ native C++ backend (crypto/host_oracle.py) because interpret-mode compiles
 of the big Mosaic kernels cost hours on this box — which left the device
 dispatch path with zero default-tier coverage (round-4 verdict weak #5).
 This file is the opt-OUT counterweight, now a ROTATION over all 14
-hardware-validated kernels (TESTS_TPU.json / scripts/pallas_parity.py):
+hardware-validated kernels (scripts/pallas_parity.py):
 
   * every run executes ONE rotation entry, picked by calendar day
     (``date.today().toordinal() % 14``) or pinned via
@@ -30,7 +30,7 @@ hardware-validated kernels (TESTS_TPU.json / scripts/pallas_parity.py):
     abstract trace of the composition costs — each stubbed child's real
     body is covered by its own day;
   * numeric parity for every trace/glue entry stays covered on-chip
-    (scripts/pallas_parity.py, TESTS_TPU.json) and behind
+    (scripts/pallas_parity.py) and behind
     DRYNX_PALLAS_INTERPRET_TESTS=1 (test_pallas_pairing);
   * one G1 kernel always runs THROUGH the full `batching.host_dispatch`
     -> bucketed kernel route with the host oracle force-disabled (the
@@ -259,7 +259,7 @@ def pulse_miller_then_fe():
     _assert_limbs(avals, (1, 6, 2))
 
 
-# Order mirrors scripts/pallas_parity.py / TESTS_TPU.json: the 14
+# Order mirrors scripts/pallas_parity.py: the 14
 # hardware-validated kernel checks. mode "execute" = interpret-mode run +
 # oracle comparison; "trace" = full jaxpr build + aval check; "glue" =
 # composition with child kernels stubbed (see module docstring).

@@ -1,6 +1,6 @@
 """The driver's multi-chip deliverable: dryrun_multichip must self-force a
 CPU virtual mesh (round-1 failure mode: it initialized the TPU backend from
-the driver process and died on a libtpu version mismatch — VERDICT.md weak #1).
+the driver process and died on a libtpu version mismatch).
 
 The env-construction logic is unit-tested cheaply; the full child-process run
 is the slow integration check (it compiles the whole sharded pipeline).

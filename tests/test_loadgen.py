@@ -13,8 +13,8 @@ SHAPES = [ShapeMix("r42", weight=2.0, ranges=((4, 2),)),
           ShapeMix("off", weight=1.0, proofs=0)]
 
 
-def _server(**kw):
-    cl = SyntheticCluster(encode_s=0.0005, verify_s=0.002)
+def _server(cl=None, **kw):
+    cl = cl or SyntheticCluster(encode_s=0.0005, verify_s=0.002)
     kw.setdefault("max_batch", 4)
     kw.setdefault("tenant_quota", 64)
     srv = SurveyServer(cl, **kw)
@@ -117,6 +117,25 @@ def test_synthetic_cluster_transient_failure_is_resumed():
     # the scheduler's resume slice retried through probe_liveness
     assert res["f-0"] == "ok-f-0"
     assert cl.executed == 2 and cl.finalized == 1
+
+
+def test_open_loop_hot_tenant_hits_its_quota_and_victims_flow():
+    # an adversarial mix: one tenant offers 8x the others, over what two
+    # workers at 20 ms a verify can serve. Shedding off (fraction 1.0), so
+    # that quotas and deficit round-robin stand alone: the hot tenant is
+    # rejected at ITS quota, typed, while every victim keeps being served
+    _, srv = _server(SyntheticCluster(encode_s=0.002, verify_s=0.02),
+                     max_depth=32, workers=2, tenant_quota=4,
+                     shed_fraction=1.0)
+    victims = ["t1", "t2"]
+    lg = LoadGen(srv, shapes=SHAPES, seed=7,
+                 tenants={"hot": 8.0, "t1": 1.0, "t2": 1.0})
+    rep = lg.run_open(120.0, 2.0)
+    assert rep["lost"] == 0 and rep["errors"] == 0
+    assert rep["rejected"]["quota"] > 0 and rep["rejected"]["shed"] == 0
+    assert rep["per_tenant"]["hot"]["rejected"] > 0
+    assert all(rep["per_tenant"][t]["completed"] > 0 for t in victims)
+    assert fairness_ratio(rep, victims) >= 0.4
 
 
 def test_fairness_ratio_bounds():

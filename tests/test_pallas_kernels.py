@@ -12,13 +12,13 @@ import pytest
 
 # The full ladder kernels take many minutes to compile through the
 # interpreter on CPU; they are validated on real TPU by
-# scripts/pallas_probe.py. Opt in with DRYNX_PALLAS_INTERPRET_TESTS=1.
+# scripts/pallas_parity.py. Opt in with DRYNX_PALLAS_INTERPRET_TESTS=1.
 # (The fixed-base ladder's take 47-49 s each since its window step is the
 # mixed addition; one test of it, against the oracle alone, is tier-1.)
 heavy = pytest.mark.skipif(
     os.environ.get("DRYNX_PALLAS_INTERPRET_TESTS", "0") != "1",
     reason="ladder-kernel interpret compile is minutes-slow on CPU; "
-           "covered on hardware by scripts/pallas_probe.py")
+           "covered on hardware by scripts/pallas_parity.py")
 
 from drynx_tpu.crypto import curve as C
 from drynx_tpu.crypto import elgamal as eg
@@ -103,7 +103,7 @@ def test_fixed_base_ladder_small_always_on():
     """Formerly always-on slice of the ladder kernel (n_windows=2): measured
     in round 4, even this truncated interpret compile runs tens of minutes
     on this box under jax 0.8, so it joins the opt-in interpret tier — the
-    kernels are validated on hardware (scripts/pallas_probe.py) and the
+    kernels are validated on hardware (scripts/pallas_parity.py) and the
     digit/table/padd logic is oracle-tested at the jnp layer."""
     ss = [0, 1, 200]  # infinity edge + generator + 2-digit scalar
     k = jnp.asarray(F.from_int(ss))

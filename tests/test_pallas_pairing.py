@@ -1,7 +1,6 @@
 """Pallas pairing kernels vs the jnp pairing + pure-Python oracle
-(interpret mode on CPU; the real-chip path is exercised by
-scripts/bench_proofs.py and the TPU benches — all kernels here were
-verified against the oracle on the actual v5e chip during development).
+(interpret mode on CPU; on the chip the same kernels are checked against
+the oracle by scripts/pallas_parity.py).
 
 Covers: Fp12 mul/inv/pow kernels, the ate Miller kernel (up to the free
 Fp2 line scales — compared after final exponentiation), and the full
@@ -28,7 +27,7 @@ pytestmark = [
     pytest.mark.skipif(
         os.environ.get("DRYNX_PALLAS_INTERPRET_TESTS", "0") != "1",
         reason="pairing-kernel interpret compile is ~1h on CPU; verified "
-               "on TPU by scripts/bench_proofs.py"),
+               "on TPU by scripts/pallas_parity.py"),
 ]
 
 RNG = np.random.default_rng(23)

@@ -1,35 +1,15 @@
 """Reference-scale axes (PR 8): the bucket-tile planner's memory bound,
 tiled-vs-monolithic bit identity for the grid encoders and the range-proof
 transcripts, chunked-vs-unchunked DRO byte identity, the vectorized noise
-generator against its loop reference, sparse-grid decode semantics, and
-the scale-bench supervisor's per-point outcome labeling (stub children).
+generator against its loop reference, and sparse-grid decode semantics.
 
 Fast by default: only the two crypto round-trip tests compile kernels and
 carry the `slow` mark."""
-import importlib.util
-import json
-import os
-import sys
-
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, ROOT)
-
-from drynx_tpu.encoding import stats as st  # noqa: E402
-from drynx_tpu.encoding import tiles  # noqa: E402
-
-PY = sys.executable
-
-
-def _scale_mod():
-    spec = importlib.util.spec_from_file_location(
-        "bench_scale_axes",
-        os.path.join(ROOT, "scripts", "bench_scale_axes.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+from drynx_tpu.encoding import stats as st
+from drynx_tpu.encoding import tiles
 
 
 # ---------------------------------------------------------------------------
@@ -236,83 +216,6 @@ def test_dro_table_convention_typeerrors():
         dro.shuffle_rerandomize(None, None, fb)
     with pytest.raises(TypeError):
         dro.dro_pipeline(None, raw, 4, 0.0, 30.0, 100.0)
-
-
-# ---------------------------------------------------------------------------
-# Scale-bench supervisor: per-point labeling (stub children, jax-free)
-# ---------------------------------------------------------------------------
-
-@pytest.fixture(scope="module")
-def scale():
-    return _scale_mod()
-
-
-def test_point_result_ok_complete(scale):
-    rec = {"stage": "complete", "encode_cold_s": 1.2}
-    pt = scale.point_result("minmax", 65536, "ok", 0, 12.34, rec)
-    assert pt["status"] == "ok" and pt["axis"] == "minmax"
-    assert pt["n"] == 65536 and pt["encode_cold_s"] == 1.2
-    assert "stage" not in pt
-
-
-def test_point_result_failure_labels(scale):
-    cases = [("ok", 0, {}, "child_exited_without_record"),
-             ("rc:2", 2, {"stage": "encode"}, "failed_rc2"),
-             ("signal:SIGSEGV", -11, {"stage": "prove"},
-              "killed_sigsegv"),
-             ("timeout", None, {"stage": "encrypt"}, "timeout")]
-    for outcome, rc, rec, want in cases:
-        pt = scale.point_result("dro", 10, outcome, rc, 1.0, rec)
-        assert pt["status"] == want, outcome
-        assert pt["last_stage"] == rec.get("stage", "none")
-
-
-def test_skip_result_records_reason(scale):
-    pt = scale.skip_result("rows", 600000, "cpu: beyond budget")
-    assert pt["status"] == "skipped" and pt["reason"]
-
-
-def test_point_result_with_real_stub_children(scale, tmp_path):
-    """Drive actual child processes through the supervisor: a clean child
-    that writes a complete record, a crasher, and a hang."""
-    import bench
-
-    rec = str(tmp_path / "rec.json")
-    prog = ("import json,sys; json.dump({'stage':'complete','x':1}, "
-            "open(sys.argv[1],'w'))")
-    out, rc, el, _ = bench.supervise_child([PY, "-c", prog, rec], 30)
-    pt = scale.point_result("minmax", 1, out, rc, el,
-                            bench.read_record(rec))
-    assert pt["status"] == "ok" and pt["x"] == 1
-
-    out, rc, el, _ = bench.supervise_child(
-        [PY, "-c", "import os,signal;os.kill(os.getpid(),signal.SIGKILL)"],
-        30)
-    pt = scale.point_result("minmax", 1, out, rc, el, {})
-    assert pt["status"] == "killed_sigkill"
-
-    out, rc, el, _ = bench.supervise_child(
-        [PY, "-c", "import time;time.sleep(60)"], 0.5)
-    pt = scale.point_result("dro", 1, out, rc, el, {})
-    assert pt["status"] == "timeout" and el < 30
-
-
-def test_progressive_record_atomic(scale, tmp_path):
-    out = str(tmp_path / "BENCH.json")
-    doc = {"points": [{"axis": "minmax", "n": 1, "status": "ok"}]}
-    scale.write_progressive(out, doc)
-    assert json.load(open(out)) == doc
-    assert not os.path.exists(out + ".tmp")
-
-
-def test_grids_cover_required_points(scale):
-    """The acceptance floor for the CPU capture."""
-    assert {1024, 4096, 16384, 65536} <= set(scale.GRIDS["minmax"])
-    assert {600, 8192, 65536} <= set(scale.GRIDS["rows"])
-    assert {10000, 100000} <= set(scale.GRIDS["dro"])
-    for axis, pts in scale.SMOKE_GRIDS.items():
-        cap = {"minmax": 256, "rows": 1024, "dro": 512}[axis]
-        assert max(pts) <= cap
 
 
 # ---------------------------------------------------------------------------

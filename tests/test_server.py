@@ -495,9 +495,9 @@ def test_resume_e2e_transient_refusal_equals_clean_run():
 
 @pytest.mark.soak
 def test_soak_pause_revive_episode_under_load(monkeypatch):
-    """Mini pause/revive soak (the check.sh soak tier; the full harness
-    is scripts/bench_soak.py): a healing partition window cuts dp1 from
-    the client while a closed-loop LoadGen drives real surveys. The
+    """Mini pause/revive soak (tier-1, and check.sh's server tier): a
+    healing partition window cuts dp1 from the client while a
+    closed-loop LoadGen drives real surveys. The
     checkpointed resume lane paces its re-entries across the heal
     boundary: zero admitted surveys lost, affected surveys resumed from
     their phase checkpoint (probe counter > 1), results equal to an

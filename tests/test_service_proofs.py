@@ -1,8 +1,7 @@
 """Proofs-on full-system survey tests, split from test_service_e2e so the
-file runs in its own process: XLA's CPU compiler degrades after the ~14
-compiles the no-proof op sweep accumulates, and the NEXT compile (these
-tests') segfaults — in isolation both pass in ~4 min (see pytest.ini /
-scripts/run_suite.py for the isolation strategy)."""
+file can run in a process of its own: heavy compiles, slow tier (the
+segfault once blamed on accumulated compiles was the process reaching
+vm.max_map_count; tests/conftest.py now releases compiled programs)."""
 import numpy as np
 import pytest
 

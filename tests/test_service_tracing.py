@@ -11,8 +11,8 @@ trace event during a cold proofs-on survey happens on MainThread.
 
 batching.TRACE_HOOK fires inside the wrapped fn body, which jax runs ONLY
 on a jit-cache miss — the hook observes real retraces, not mere calls.
-Own file so scripts/run_suite.py gives it a cold process (warm jit caches
-from a sibling test would hide trace events)."""
+Own file so that it can be given a cold process (warm jit caches from a
+sibling test would hide trace events)."""
 import threading
 
 import numpy as np

@@ -236,12 +236,17 @@ def test_tree_vs_star_same_result_fewer_root_bytes(tmp_path, monkeypatch):
     assert 0 < rx_t["cn0"] < rx_s["cn0"]
 
 
-def test_tree_relay_kill_degrades_only_that_node(tmp_path, monkeypatch):
+@pytest.mark.parametrize("heal_after_s", [None, 0.9])
+def test_tree_relay_kill_degrades_only_that_node(tmp_path, monkeypatch,
+                                                 heal_after_s):
     """FaultPlan-kill of a MID-TREE relay (dp2 under fanout 2 has the
     children dp6, dp7): only the killed node goes absent — the root
     re-dispatches its children as subtree roots — and the same plan
     yields the same responder set on a second survey across the same
-    relay hops (seeded chaos stays deterministic at depth)."""
+    relay hops (seeded chaos stays deterministic at depth). Killed with a
+    heal window, the relay is re-entered once it answers again: the survey
+    heals to the exact sum over the FULL roster, collect re-entered from
+    its checkpoint and not restarted."""
     from drynx_tpu.crypto import elgamal as eg
 
     monkeypatch.setenv(topo.ENV_FANOUT, "2")
@@ -251,25 +256,28 @@ def test_tree_relay_kill_degrades_only_that_node(tmp_path, monkeypatch):
     try:
         client = RemoteClient(Roster(entries), rng, policy=policy)
         client.broadcast_roster()
-        plan = FaultPlan(seed=5)
-        plan.kill("dp2")
-        set_fault_plan(plan)
         dl = eg.DecryptionTable(limit=1000)
-        want = int(sum(d.sum() for n, d in datas.items() if n != "dp2"))
+        gone = [] if heal_after_s else ["dp2"]
+        want = int(sum(d.sum() for n, d in datas.items() if n not in gone))
+        plan = FaultPlan(seed=5)
+        set_fault_plan(plan)
         outcomes = []
         for sid in ("kill-a", "kill-b"):
+            plan.kill("dp2", heal_after_s=heal_after_s)   # down at dispatch
             res = client.run_survey("sum", query_min=0, query_max=9,
                                     survey_id=sid, dlog=dl,
                                     min_dp_quorum=8)
             outcomes.append((res, list(client.last_responders),
                              list(client.last_absent)))
+            if heal_after_s:
+                assert client.last_phases.get("collect", 0) >= 2
     finally:
         for n in nodes:
             n.stop()
     for res, resp, absent in outcomes:
         assert res == want
-        assert absent == ["dp2"]               # dp6/dp7 recovered
-        assert resp == [f"dp{i}" for i in range(10) if i != 2]
+        assert absent == gone                  # dp6/dp7 recovered
+        assert resp == [f"dp{i}" for i in range(10) if f"dp{i}" not in gone]
     assert outcomes[0] == outcomes[1]          # deterministic at depth
 
 
