@@ -239,12 +239,12 @@ def make_group(m_const, nprime):
 
 
 def _inf_like(p):
-    """Infinity point tiles shaped like p: X=Y=1 (Mont form irrelevant,
-    any nonzero works for Z==0 semantics — use 1), Z=0."""
-    one_row = jnp.ones((1,) + p[0].shape[1:], jnp.uint32)
-    zero_rows = jnp.zeros((NL - 1,) + p[0].shape[1:], jnp.uint32)
-    X = jnp.concatenate([one_row, zero_rows], axis=0)
-    return (X, X, jnp.zeros_like(p[2]))
+    """Infinity point tiles shaped like p, as `curve.infinity` writes it:
+    X = Y = the Montgomery one, Z = 0. Any X, Y mean infinity where
+    Z == 0; the jnp layer's limbs keep `padd` byte-identical to
+    `curve.add` in every case (tests/test_dro.py)."""
+    one = _one_like(p[0])
+    return (one, one, jnp.zeros_like(p[2]))
 
 
 def _one_like(a):

@@ -31,7 +31,9 @@ whatever the slab width (one slab of the whole list included;
 tests/test_scale_axes.py, tests/test_dro.py). The two encryption programs'
 slabs are placed over the proof plane's devices where it has several
 (parallel/proof_plane.dispatch_shards); the gather runs where the list
-lives.
+lives, and on a TPU the addition behind it is the Pallas complete-add
+kernel (`pallas_ops.point_add_flat`: the same formulas on the same
+residues, so the same bytes), traced inside that stored program alone.
 
 API convention: `FixedBase` objects stop at the encryption boundary
 (encrypt_noise, dro_pipeline); the shuffle/precompute layer takes raw
@@ -183,8 +185,24 @@ def _dro_zero_enc(base_tbl, pub_tbl, r):
 @stored
 @jax.jit
 def _dro_permute_add(cts, idx, zero_ct):
-    """A slab of one node's pass: out[i] = cts[idx[i]] + zero_ct[i]."""
-    return eg.ct_add(jnp.take(cts, idx, axis=0), zero_ct)
+    """A slab of one node's pass: out[i] = cts[idx[i]] + zero_ct[i]. On a
+    TPU the slab's 2n points are added by the Pallas complete-add kernel
+    (crypto/pallas_ops.py), selected as `eg.fixed_base_mul` selects its
+    ladder; it computes `C.add`'s formulas on canonical residues, so the
+    bytes are the jnp path's (scripts/pallas_parity.py on the chip)."""
+    from ..crypto import pallas_ops as po
+
+    picked = jnp.take(cts, idx, axis=0)
+    if po.available():
+        # lanes component-major, (n, 2, 3, 16) -> (2, n, 3, 16) -> (2n, 3, 16):
+        # the device keeps these arrays batch-minor, so this order moves
+        # whole rows where an interleave of the two components would not
+        def flat(x):
+            return jnp.moveaxis(x, 1, 0).reshape((-1,) + x.shape[2:])
+
+        out = po.point_add_flat(flat(picked), flat(zero_ct))
+        return jnp.moveaxis(out.reshape((2, -1) + out.shape[1:]), 0, 1)
+    return eg.ct_add(picked, zero_ct)
 
 
 PROGRAMS = ("_dro_noise_enc", "_dro_zero_enc", "_dro_permute_add")

@@ -18,8 +18,9 @@ are flushed to chiprun_out/pallas_parity.json after every check).
 Usage:  python scripts/pallas_parity.py  [--skip-slow] [--only NAME]
 (--skip-slow drops the Miller/pair/final-exp family, whose lowering is the
 expensive tail; the GT/ladder families alone validate everything new.
---only runs the checks whose name contains NAME: the one kernel a PR
-touched, at that kernel's lowering cost and not the whole family's.)
+--only runs the checks whose name contains NAME, or one of several NAMEs
+given with commas between them: the kernels a PR touched, at their
+lowering cost and not the whole family's.)
 """
 import argparse
 import json
@@ -51,7 +52,7 @@ def flush():
 
 
 def check(name, fn):
-    if ONLY not in name:
+    if not any(part in name for part in ONLY.split(",")):
         return
     t0 = time.perf_counter()
     try:
@@ -234,6 +235,43 @@ def main():
         assert got == [None] * 8
 
     check("fixed_base_mul_flat", c_fixed_base)
+
+    def c_dro_permute_add():
+        # one 4 096-wide slab of a node's pass (parallel/dro.py) out of a
+        # list of two slabs, the kernel path against the jnp addition limb
+        # for limb, with every branch of the complete addition planted:
+        # infinity left, right and on both sides, equal operands in the
+        # same and in another representation, opposite operands
+        from drynx_tpu.parallel import dro
+
+        n, size = dro.CHUNK, 2 * dro.CHUNK
+        key = jax.random.PRNGKey(31)
+        ks = eg.random_scalars(key, (2 * size + 2 * n,))
+        pts = np.array(eg.fixed_base_mul(eg.BASE_TABLE.table, ks))
+        cts = pts[:2 * size].reshape(size, 2, 3, 16)
+        zero = pts[2 * size:].reshape(n, 2, 3, 16)
+        idx = np.asarray(jax.random.permutation(key, size))[:n]
+        inf = np.asarray(C.infinity())
+        same_point = C.from_ref(C.to_ref(cts[idx[4], 0]))     # with Z = 1
+        for c, at in ((0, 0), (1, n - 8)):      # first and last tile
+            i = at + np.arange(8)
+            cts[idx[i[0]], c] = inf
+            zero[i[1], c] = inf
+            cts[idx[i[2]], c] = zero[i[2], c] = inf
+            zero[i[3], c] = cts[idx[i[3]], c]
+            zero[i[5], c] = np.asarray(C.neg(jnp.asarray(cts[idx[i[5]], c])))
+        zero[4, 0] = same_point
+        cts, idx, zero = map(jnp.asarray, (cts, idx, zero))
+        got = np.asarray(dro._dro_permute_add(cts, idx, zero))
+        want = np.asarray(eg.ct_add(jnp.take(cts, idx, axis=0), zero))
+        off = np.argwhere((got != want).any(axis=(2, 3)))
+        assert off.size == 0, off[:8].tolist()
+        assert C.to_ref(got[4, 0]) == C.to_ref(C.double(cts[idx[4], 0]))
+        at_inf = np.argwhere(~got[:, :, 2].any(axis=-1)).tolist()
+        assert at_inf == [[2, 0], [5, 0], [n - 6, 1], [n - 3, 1]], at_inf
+
+    check("dro_permute_add (point_add_flat under the noise phase's slab)",
+          c_dro_permute_add)
 
     def c_g2_ladder():
         ks = [1, 7, params.N - 1]

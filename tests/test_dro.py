@@ -13,8 +13,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from drynx_tpu.crypto import curve as C
 from drynx_tpu.crypto import elgamal as eg
-from drynx_tpu.crypto import params
+from drynx_tpu.crypto import pallas_ops as po
+from drynx_tpu.crypto import params, refimpl
 from drynx_tpu.parallel import dro
 from drynx_tpu.service import service as svc
 from drynx_tpu.service.query import DiffPParams
@@ -142,6 +144,113 @@ def test_a_pass_permutes_and_rerandomises_every_ciphertext(passes, node):
     # both components moved: (K, C) -> (K + rB, C + rP)
     assert all(not np.array_equal(after[i, c], pre_image[i, c])
                for i in range(SIZE) for c in (0, 1))
+
+
+# --- the re-randomising addition in the Pallas kernel (a TPU's path) ----------
+
+# what a lane's two operands are, for every branch of the complete addition
+LANES = ("left_infinity", "right_infinity", "both_infinity", "equal_bytes",
+         "equal_points", "opposite", "generic")
+
+
+def _slab_with_every_case(size, n):
+    """(cts, idx, zero_ct, lanes): a slab of n of a list of `size` Jacobian
+    ciphertexts (Z != 1) whose first lanes hold LANES in the first
+    component and, further on, in the second; the others are generic."""
+    assert 2 * len(LANES) <= n <= size
+    ks = np.arange(2, 2 + 2 * (size + n))
+    aff = jnp.asarray(C.from_ref_batch(
+        [refimpl.g1_mul(refimpl.G1, int(k)) for k in ks]))
+    jac = np.asarray(C.add(aff, jnp.roll(aff, 1, axis=0)))   # (k + k') G
+    mult = ks + np.roll(ks, 1)
+    cts, zero = jac[:2 * size].copy(), jac[2 * size:].copy()
+    cts, zero = cts.reshape(size, 2, 3, 16), zero.reshape(n, 2, 3, 16)
+    idx = np.random.default_rng(7).permutation(size)[:n].astype(np.int32)
+    inf = np.asarray(C.infinity())
+    lanes = {}
+    for c in (0, 1):
+        for j, case in enumerate(LANES[:-1]):
+            i = c * len(LANES) + j
+            lanes[i, c] = case
+            if case in ("left_infinity", "both_infinity"):
+                cts[idx[i], c] = inf
+            if case in ("right_infinity", "both_infinity"):
+                zero[i, c] = inf
+            if case == "equal_bytes":
+                zero[i, c] = cts[idx[i], c]
+            if case == "equal_points":      # the affine form of the same point
+                k = int(mult[2 * idx[i] + c])
+                zero[i, c] = C.from_ref(refimpl.g1_mul(refimpl.G1, k))
+            if case == "opposite":
+                zero[i, c] = np.asarray(C.neg(jnp.asarray(cts[idx[i], c])))
+    return jnp.asarray(cts), jnp.asarray(idx), jnp.asarray(zero), lanes
+
+
+def _jnp_pass(cts, idx, zero_ct):
+    return np.asarray(eg.ct_add(jnp.take(cts, idx, axis=0), zero_ct))
+
+
+def test_the_kernel_path_flattens_and_restores_the_slab(monkeypatch):
+    """(4.4 s.) With the kernel replaced by `C.add` on the flat batch, the
+    TPU branch of `_dro_permute_add` gives the jnp path's bytes at a slab
+    that is no multiple of 128: the flatten and its inverse are pinned
+    without an interpreter compile."""
+    size, n = 50, 37
+    cts, idx, zero, _ = _slab_with_every_case(size, n)
+    seen = []
+
+    def stand_in(p, q):
+        seen.append((p.shape, q.shape))
+        return C.add(p, q)
+
+    monkeypatch.setattr(po, "available", lambda: True)
+    monkeypatch.setattr(po, "point_add_flat", stand_in)
+    got = dro._dro_permute_add.jit.__wrapped__(cts, idx, zero)
+    assert seen == [((2 * n, 3, 16), (2 * n, 3, 16))]
+    assert got.shape == zero.shape and got.dtype == zero.dtype
+    assert np.array_equal(np.asarray(got), _jnp_pass(cts, idx, zero))
+
+
+def test_the_kernels_infinity_is_the_jnp_layers():
+    """Opposite operands sum to the infinity the kernel writes: the limbs
+    of `curve.infinity`, or the two paths' bytes part there (0.1 s)."""
+    tile = jnp.zeros((16, 4), jnp.uint32)
+    got = np.asarray(jnp.stack(po._inf_like((tile, tile, tile))))
+    assert np.array_equal(np.moveaxis(got, -1, 0),
+                          np.asarray(C.infinity((4,))))
+
+
+@pytest.mark.slow(reason="64 s alone on the 8-core sandbox, test compile "
+                         "cache off (PR 31): one interpreter compile of "
+                         "the complete addition's 30 field products")
+def test_the_add_kernel_is_curve_add_byte_for_byte_in_every_case(
+        monkeypatch):
+    """The real kernel, through `_dro_permute_add`'s TPU branch, against
+    `curve.add`: infinity on either side and on both, equal operands in
+    one and in two representations, opposite operands, generic ones."""
+    size, n = 20, 2 * len(LANES)
+    cts, idx, zero, lanes = _slab_with_every_case(size, n)
+    monkeypatch.setattr(po, "INTERPRET", True)
+    assert po.available()
+    got = np.asarray(dro._dro_permute_add.jit.__wrapped__(cts, idx, zero))
+    want = _jnp_pass(cts, idx, zero)
+    differing = [(i, c, lanes.get((i, c), "generic"))
+                 for i in range(n) for c in (0, 1)
+                 if not np.array_equal(got[i, c], want[i, c])]
+    assert differing == []
+    # and the cases are the cases: which lanes come out as infinity
+    at_infinity = {k for k in lanes if not got[k][2].any()}
+    assert at_infinity == {k for k, case in lanes.items()
+                           if case in ("both_infinity", "opposite")}
+    picked = np.asarray(cts)[np.asarray(idx)]
+    for (i, c), case in lanes.items():
+        if case == "left_infinity":
+            assert np.array_equal(got[i, c], np.asarray(zero)[i, c])
+        if case == "right_infinity":
+            assert np.array_equal(got[i, c], picked[i, c])
+        if case in ("equal_bytes", "equal_points"):
+            assert np.array_equal(
+                got[i, c], np.asarray(C.double(jnp.asarray(picked[i, c]))))
 
 
 def test_a_zero_encryption_is_todays_bytes_without_the_zero_ladder(cluster):

@@ -66,13 +66,22 @@ def no_persistent_cache():
     cc.reset_cache()
 
 
+@pytest.fixture
+def s(one_chip):
+    """`s(shape)`: a ShapeDtypeStruct (uint32 unless told) placed on the
+    described chip."""
+    def spec(shape, dtype=jnp.uint32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return spec
+
+
 def _slow(seconds):
     return pytest.mark.slow(
         reason=f"{seconds} s lower+compile on the 8-core sandbox (PR 21)")
 
 
-# name -> builder(s) returning the jax.stages.Lowered; `s(shape)` gives a
-# ShapeDtypeStruct (uint32 unless told) placed on the described chip.
+# name -> builder(s) returning the jax.stages.Lowered; `s` is the fixture
+# above.
 def _g1(s, n=B):
     return s((n, 3, NL))
 
@@ -176,17 +185,17 @@ _MARKS = {
 @pytest.mark.parametrize(
     "name,build",
     [pytest.param(n, b, id=n, marks=_MARKS.get(n, ())) for n, b in CASES])
-def test_kernel_compiles_for_v5e(name, build, one_chip, no_persistent_cache,
+def test_kernel_compiles_for_v5e(name, build, s, no_persistent_cache,
                                  monkeypatch):
     # the composed entry points read the module flag at trace time
     monkeypatch.setattr(po, "INTERPRET", False)
     monkeypatch.setattr(pp, "INTERPRET", False)
+    _compile_and_report(name, lambda: build(s))
 
-    def s(shape, dtype=jnp.uint32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
+def _compile_and_report(name, lower):
     t0 = time.perf_counter()
-    lowered = build(s)
+    lowered = lower()
     t1 = time.perf_counter()
     compiled = lowered.compile()
     t2 = time.perf_counter()
@@ -197,3 +206,26 @@ def test_kernel_compiles_for_v5e(name, build, one_chip, no_persistent_cache,
         "code_bytes": mem.generated_code_size_in_bytes,
         "temp_bytes": mem.temp_size_in_bytes}))
     assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def test_the_noise_phases_add_compiles_for_v5e(s, no_persistent_cache,
+                                               monkeypatch):
+    """`parallel/dro._dro_permute_add` as a TPU traces it, at the diffp
+    cell's shapes (a slab of 4 096 of a list of 262 144): the gather, the
+    complete-add kernel under the name the benchmark's patterns read, and
+    no scratch to speak of (9 s lower + compile on the 8-core sandbox,
+    PR 31)."""
+    from drynx_tpu.parallel import dro
+
+    monkeypatch.setattr(po, "INTERPRET", False)
+    monkeypatch.setattr(po, "available", lambda: True)
+    size, slab = 262144, dro.CHUNK
+    compiled = _compile_and_report(
+        f"dro_permute_add@{size}/{slab}",
+        lambda: dro._dro_permute_add.lower(
+            s((size, 2, 3, NL)), s((slab,), jnp.int32),
+            s((slab, 2, 3, NL))))
+    text = compiled.as_text()
+    assert "%_point_add_flat" in text and "gather" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
