@@ -43,7 +43,6 @@ which hid a real type error in dro_pipeline.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 import math
 
@@ -54,7 +53,7 @@ import numpy as np
 from ..crypto import elgamal as eg
 from ..resilience.policy import named_lock
 from ..utils.exec_store import stored
-from ..utils.timers import PROCESS
+from ..utils.timers import PROCESS, step_of
 
 # Slab width for chunked precompute / shuffle re-randomization: matches
 # the g1 family's max_bucket (crypto/batching.py) and the bucket-grid
@@ -159,7 +158,7 @@ def _require_table(tbl, who: str):
 # ---------------------------------------------------------------------------
 # The phase's three slab programs. Module-level jits of arrays with the key
 # tables as arguments, stored like service._fused_enc/_agg/_ks/_dec
-# (LocalCluster.FUSED names all seven). The two ladders' programs depend on
+# (LocalCluster.FUSED names them all). The two ladders' programs depend on
 # the slab's width alone, so one stored executable serves every list size.
 # ---------------------------------------------------------------------------
 
@@ -241,12 +240,6 @@ def slab_widths(size: int, chunk: int | None = None) -> list[int]:
     return sorted({b - a for a, b in _slabs_of(size, chunk)})
 
 
-def _step(tm, name: str):
-    """A step of the survey's timers under the phase that is open
-    (`DROPhase/<name>`), where the caller has timers."""
-    return tm.step(name) if tm is not None else contextlib.nullcontext()
-
-
 def _by_slab(phase: str, chunk, inputs: tuple, program, place: bool):
     """program(*slab of every input) over the slabs of `inputs` (device
     arrays of one length), the outputs in one array. Returns once the
@@ -277,7 +270,7 @@ def encrypt_noise(key, pub_table: eg.FixedBase, noise: np.ndarray,
                         "(the encryption boundary); got a raw table")
     noise = np.asarray(noise, dtype=np.int64)
     size = int(noise.shape[0])
-    with _step(tm, "noise_enc"):
+    with step_of(tm, "noise_enc"):
         values = jnp.asarray(noise)
         PROCESS.count("h2d_bytes", noise.nbytes)
         r = eg.random_scalars(key, (size,))
@@ -319,7 +312,7 @@ def precompute_rerandomization(key, pub_tbl, size: int, base_tbl=None,
     with _PRECOMPUTE_COUNT_LOCK:
         PRECOMPUTE_CALLS += 1
     base_tbl = base_tbl if base_tbl is not None else eg.BASE_TABLE.table
-    with _step(tm, "zero_enc"):
+    with step_of(tm, "zero_enc"):
         r = eg.random_scalars(key, (size,))
         zero_ct = _by_slab("DROPrecompute", chunk, (r,),
                            lambda rs: _dro_zero_enc(base_tbl, pub_tbl, rs),
@@ -362,7 +355,7 @@ def shuffle_rerandomize(key, cts, pub_tbl, base_tbl=None, precomp=None,
                                              chunk, tm)
     zero_ct, r = precomp
     assert zero_ct.shape[0] == S, (zero_ct.shape, S)
-    with _step(tm, "permute_add"):
+    with step_of(tm, "permute_add"):
         perm = jax.random.permutation(kperm, S)
         out = _by_slab("DROShuffle", chunk, (perm, zero_ct),
                        lambda idx, zc: _dro_permute_add(cts, idx, zc),
@@ -400,7 +393,7 @@ def pick_add(agg, cts, tm=None):
     (reference service.go:600-604): result i takes entry i mod S."""
     from ..crypto import batching as B
 
-    with _step(tm, "pick_add"):
+    with step_of(tm, "pick_add"):
         idx = np.arange(int(agg.shape[0])) % int(cts.shape[0])
         out = B.ct_add(agg, jnp.take(cts, jnp.asarray(idx), axis=0))
         return jax.block_until_ready(out)

@@ -48,6 +48,7 @@ from ..crypto import refimpl
 from ..analysis import Secret
 from ..encoding import stats as st
 from ..parallel import dro
+from ..parallel import obfuscation as obf
 from ..proofs import aggregation as agg_proof
 from ..proofs import keyswitch as ks_proof
 from ..proofs import obfuscation as obf_proof
@@ -741,17 +742,15 @@ class DrynxNode:
     # prove it (lib/obfuscation/obfuscation_proof.go:47)
     def _h_obf_contrib(self, msg: dict) -> dict:
         cts = unpack_array_device(msg["cts"])
-        V = cts.shape[0]
-        key = jax.random.PRNGKey(secrets.randbits(63))
-        k_s, k_w = jax.random.split(key)
-        s = eg.random_scalars(k_s, (V,))
+        prove = None
         if msg.get("proofs"):
-            pr = obf_proof.create_obfuscation_proofs(k_w, cts, s)
-            self._send_proof_async("obfuscation", msg["survey_id"],
-                                   f"obf-{self.name}", pickle.dumps(pr))
-            out = pr.obf
-        else:
-            out = B.ct_scalar_mul(cts, s)
+            def prove(k_w, cts, s):
+                pr = obf_proof.create_obfuscation_proofs(k_w, cts, s)
+                self._send_proof_async("obfuscation", msg["survey_id"],
+                                       f"obf-{self.name}", pickle.dumps(pr))
+                return pr.obf
+        out, _ = obf.node_pass(jax.random.PRNGKey(secrets.randbits(63)),
+                               cts, prove=prove)
         return {"cts": pack_array(np.asarray(out))}
 
     # -- CN side: DRO shuffle contribution (reference unlynx shuffling

@@ -38,6 +38,7 @@ from ..encoding import tiles as enc_tiles
 from ..models import logreg as lr
 from ..parallel import collective as col
 from ..parallel import dro
+from ..parallel import obfuscation as obf
 from ..proofs import aggregation as agg_proof
 from ..proofs import keyswitch as ks_proof
 from ..proofs import obfuscation as obf_proof
@@ -534,9 +535,10 @@ class LocalCluster:
         return enc, _fused_agg, ks, _fused_dec
 
     # the stored programs of a survey, for the set-up report: the four
-    # fused phases and the noise phase's three slab programs
+    # fused phases, the noise phase's three slab programs and the
+    # obfuscation phase's pass
     FUSED = ("_fused_enc", "_fused_agg", "_fused_ks",
-             "_fused_dec") + dro.PROGRAMS
+             "_fused_dec") + dro.PROGRAMS + obf.PROGRAMS
 
     # bucket-grid Profile axis: st.grid_buckets(q) — shared with admission
 
@@ -880,24 +882,20 @@ class LocalCluster:
         # --- Obfuscation phase (zero/nonzero ops only) ------------------
         if q.obfuscation:
             mark("obfuscate")
+            key, *node_keys = jax.random.split(key, 1 + len(self.cns))
             tm.start("ObfuscationPhase")
-            obf_scalars = []
-            work = agg
-            for cn in self.cns:
-                # distinct keys for the secret scalar s and the proof's
-                # blinding w — reusing one key would make w == s and leak s
-                key, k_s, k_w = jax.random.split(key, 3)
-                s = eg.random_scalars(k_s, (V,))
+            # one pass a computing node, each by V fresh scalars of its own
+            # on the previous node's output: never one pass by a product of
+            # the nodes' scalars (parallel/obfuscation.py, the guarantee)
+            for cn, k_node in zip(self.cns, node_keys):
+                prove = None
                 if proofs_on:
-                    pr = obf_proof.create_obfuscation_proofs(k_w, work, s)
-                    self._async_proof(survey, "obfuscation", cn,
-                                      lambda pr=pr: _pickle(pr))
-                    work = pr.obf
-                else:
-                    work = B.ct_scalar_mul(work, s)
-                obf_scalars.append(s)
-            agg = work
-            agg.block_until_ready()
+                    def prove(k_w, cts, s, cn=cn):
+                        pr = obf_proof.create_obfuscation_proofs(k_w, cts, s)
+                        self._async_proof(survey, "obfuscation", cn,
+                                          lambda: _pickle(pr))
+                        return pr.obf
+                agg, _ = obf.node_pass(k_node, agg, tm=tm, prove=prove)
             tm.end("ObfuscationPhase")
 
         # --- DRO / differential privacy noise phase ---------------------

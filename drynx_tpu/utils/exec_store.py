@@ -255,7 +255,8 @@ class StoredProgram:
 
 def trace_reads() -> dict:
     """What the trace of the program's stored G1 programs
-    (service._fused_enc/_agg/_ks/_dec, parallel/dro.PROGRAMS) reads beside
+    (service._fused_enc/_agg/_ks/_dec, parallel/dro.PROGRAMS,
+    parallel/obfuscation.PROGRAMS) reads beside
     its arguments and the package's source: their keys hold it.
     `po.available()` reads DRYNX_NO_PALLAS and INTERPRET, the kernels'
     wrappers pass INTERPRET and `field.UNROLL` (DRYNX_FIELD_UNROLL) on as

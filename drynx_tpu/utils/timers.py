@@ -261,6 +261,13 @@ class PhaseTimers:
         return buf.getvalue()
 
 
+def step_of(tm, name: str):
+    """`tm.step(name)`, a step under the phase open on this thread, where
+    the caller has a survey's timers; else a context that does nothing (a
+    phase's function called outside a survey: a remote node, a script)."""
+    return tm.step(name) if tm is not None else contextlib.nullcontext()
+
+
 def union_seconds(intervals) -> float:
     total, end = 0.0, float("-inf")
     for a, b in sorted(intervals):
@@ -532,4 +539,4 @@ def install_listener() -> None:
 
 
 __all__ = ["PhaseTimers", "ProcessTracer", "Span", "PROCESS",
-           "install_listener", "union_seconds"]
+           "install_listener", "step_of", "union_seconds"]

@@ -229,3 +229,28 @@ def test_the_noise_phases_add_compiles_for_v5e(s, no_persistent_cache,
     text = compiled.as_text()
     assert "%_point_add_flat" in text and "gather" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.slow(reason="152 s lower+compile on the 8-core sandbox (PR 32): "
+                  "the 64-window variable-base ladder's lowering")
+def test_the_obfuscation_pass_compiles_for_v5e(s, no_persistent_cache,
+                                               monkeypatch):
+    """`parallel/obfuscation._obf_scalar_mul` as a TPU traces it, at the
+    obfuscated grid cell's width (12 288 ciphertexts, 12 288 lanes a
+    component, no padding to a power of two): the variable-base ladder
+    under the name the benchmark's patterns read, and no scratch (108 s
+    lower + 45 s compile on the 8-core sandbox, temp size 0 B, PR 32)."""
+    from drynx_tpu.parallel import obfuscation as obf
+
+    monkeypatch.setattr(po, "INTERPRET", False)
+    monkeypatch.setattr(po, "available", lambda: True)
+    v = 12288
+    compiled = _compile_and_report(
+        f"obf_scalar_mul@{v}",
+        lambda: obf._obf_scalar_mul.lower(s((v, 2, 3, NL)), s((v, NL))))
+    text = compiled.as_text()
+    # a component a call, V lanes each (the decryption's shape): nothing
+    # is padded to a power of two
+    assert "%_scalar_mul_flat" in text and f"u32[3,16,{v}]" in text
+    assert "16384" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
