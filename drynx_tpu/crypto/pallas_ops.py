@@ -13,11 +13,22 @@ Field elements inside a kernel are (16, B) uint32 traced values (16-bit
 limbs, little-endian, Montgomery form for Fp); points are (X, Y, Z) tuples of
 those (Jacobian, Z == 0 at infinity) — the same representation as
 crypto/field.py / crypto/curve.py, transposed.
+
+That "sublane" layout (limbs on sublanes, B = 128 lanes: a field element is
+two vregs) still describes `_scalar_mul_kernel`, `_point_add_kernel`,
+`_point_reduce_kernel` and the kernels of crypto/pallas_pairing.py. Since
+PR 33 `_fixed_base_kernel` keeps a field element as "limb tiles" instead:
+16 tiles of (8, 128) uint32, limb l of TILE_LANES = 1 024 lanes in one vreg,
+so every step of a carry, borrow or reduction chain is one whole-vreg
+operation and no row is ever moved (the sublane product spends half its
+operations on sublane rotates and selects). Each layout is a `Field` bundle;
+`make_group` writes the group law once over either.
 """
 from __future__ import annotations
 
 import functools
 import os
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -36,7 +47,9 @@ _NPRIME_FP = np.uint32(params.NPRIME)
 _N_ORDER = np.asarray(params.to_limbs(params.N), dtype=np.uint32)
 _ONE_MONT = np.asarray(params.to_limbs(params.R % params.P), dtype=np.uint32)
 
-LANES = 128                      # batch tile width
+LANES = 128                      # batch tile width, sublane layout
+TILE_LANES = 8 * LANES           # batch tile width of the limb-tile layout
+                                 # (_fixed_base_kernel only): one vreg a limb
 
 # DRYNX_PALLAS_INTERPRET=1 runs the kernels through the Pallas interpreter
 # (any backend) — used by the CPU test suite to cover the kernel code paths.
@@ -45,6 +58,18 @@ LANES = 128                      # batch tile width
 # (tests monkeypatch the module global) keys a fresh trace instead of
 # leaking a stale interpret-mode executable out of the jit cache.
 INTERPRET = os.environ.get("DRYNX_PALLAS_INTERPRET", "0") == "1"
+
+
+class Field(NamedTuple):
+    """One layout's field functions, what `make_group` builds the group
+    law from. `select(cond, p, q)` is the per-lane POINT select."""
+    mul: Callable
+    add: Callable
+    sub: Callable
+    is_zero: Callable
+    select: Callable
+    one_like: Callable
+    inf_like: Callable
 
 
 # ---------------------------------------------------------------------------
@@ -141,22 +166,170 @@ def mont_mul(a, b, m, nprime):
     return jnp.where(use_diff[None, :], diff, res)
 
 
-# ---------------------------------------------------------------------------
-# G1 group law on (X, Y, Z) tuples of (16, B) tiles (mirrors crypto/curve.py)
-# ---------------------------------------------------------------------------
-
 def _pt_select(cond, p, q):
     """Per-lane select: cond (B,) bool -> p where true else q."""
     c = cond[None, :]
     return tuple(jnp.where(c, a, b) for a, b in zip(p, q))
 
 
-def make_group(m_const, nprime):
-    """Bind the modulus constants once; returns (double, add_complete,
-    add_mixed)."""
-    mul = lambda a, b: mont_mul(a, b, m_const, nprime)
-    add_ = lambda a, b: fadd(a, b, m_const)
-    sub_ = lambda a, b: fsub(a, b, m_const)
+def _inf_like(p):
+    """Infinity point tiles shaped like p, as `curve.infinity` writes it:
+    X = Y = the Montgomery one, Z = 0. Any X, Y mean infinity where
+    Z == 0; the jnp layer's limbs keep `padd` byte-identical to
+    `curve.add` in every case (tests/test_dro.py)."""
+    one = _one_like(p[0])
+    return (one, one, jnp.zeros_like(p[2]))
+
+
+def _one_like(a):
+    """The Montgomery one (R mod p) as tiles shaped like a."""
+    return jnp.stack([jnp.full(a.shape[1:], l, jnp.uint32)
+                      for l in _ONE_MONT])
+
+
+def canonical_scalar(k, n):
+    """k mod n for any 256-bit k on (16, B) tiles, n (16, 1) the group
+    order's limbs: 2n > 2^256, so one conditional subtraction suffices."""
+    diff, borrow = _sub_limbs(k, jnp.broadcast_to(n, k.shape))
+    return jnp.where((borrow == 0)[None, :], diff, k)
+
+
+def sublane_field(m_const, nprime) -> Field:
+    """The (16, B) layout's bundle, the modulus constants bound once."""
+    return Field(mul=lambda a, b: mont_mul(a, b, m_const, nprime),
+                 add=lambda a, b: fadd(a, b, m_const),
+                 sub=lambda a, b: fsub(a, b, m_const),
+                 is_zero=fis_zero, select=_pt_select,
+                 one_like=_one_like, inf_like=_inf_like)
+
+
+# ---------------------------------------------------------------------------
+# Field arithmetic on limb tiles: a field element is 16 arrays of one shape
+# (in a kernel (8, 128): limb l of TILE_LANES lanes in one vreg), given as a
+# list or as an array with the limbs on its MAJOR axis. Every step of a chain
+# is a whole-vreg operation; the moduli's limbs are scalar constants.
+# ---------------------------------------------------------------------------
+
+def _tile_sub(a, b):
+    """a - b with borrow chain, b's limbs arrays or scalars.
+    -> (16 limbs, borrow)."""
+    outs = []
+    borrow = None
+    for k in range(NL):
+        v = a[k] - b[k]
+        if borrow is not None:
+            v = v - borrow
+        outs.append(v & MASK)
+        borrow = v >> np.uint32(31)
+    return outs, borrow
+
+
+def _tile_carry(limbs, carry=None):
+    """Propagate carries up 16 limbs (values < 2^31). -> (limbs, carry)."""
+    outs = []
+    for v in limbs:
+        if carry is not None:
+            v = v + carry
+        outs.append(v & MASK)
+        carry = v >> LB
+    return outs, carry
+
+
+def _tile_where(cond, a, b):
+    return [jnp.where(cond, x, y) for x, y in zip(a, b)]
+
+
+def tile_fadd(a, b):
+    """(a + b) mod p, inputs normalized."""
+    s, carry = _tile_carry([a[k] + b[k] for k in range(NL)])
+    diff, borrow = _tile_sub(s, _M_FP)
+    return _tile_where((borrow == 0) | (carry > 0), diff, s)
+
+
+def tile_fsub(a, b):
+    """(a - b) mod p, inputs normalized."""
+    diff, borrow = _tile_sub(a, b)
+    plus_m, _ = _tile_carry([diff[k] + _M_FP[k] for k in range(NL)])
+    return _tile_where(borrow == 1, plus_m, diff)
+
+
+def tile_fis_zero(a):
+    """Bool of the limbs' shape: all 16 limbs zero."""
+    orv = a[0]
+    for k in range(1, NL):
+        orv = orv | a[k]
+    return orv == 0
+
+
+def tile_mont_mul(a, b):
+    """Montgomery product a * b * R^-1 mod p: product scanning with lazy
+    carries. A partial product's halves go to their columns unpropagated
+    (a column sums at most 64 values under 2^16 and a carry: no overflow),
+    the 16 reduction steps add mfac * p the same way, then one carry pass
+    and one conditional subtraction."""
+    cols = [None] * (2 * NL)
+
+    def acc(k, v):
+        cols[k] = v if cols[k] is None else cols[k] + v
+
+    for i in range(NL):
+        for j in range(NL):
+            p = a[i] * b[j]
+            acc(i + j, p & MASK)
+            acc(i + j + 1, p >> LB)
+    carry = None
+    for i in range(NL):
+        v = cols[i] if carry is None else cols[i] + carry
+        mfac = ((v & MASK) * _NPRIME_FP) & MASK
+        mp = mfac * _M_FP[0]
+        carry = (v + (mp & MASK)) >> LB
+        acc(i + 1, mp >> LB)
+        for j in range(1, NL):
+            mp = mfac * _M_FP[j]
+            acc(i + j, mp & MASK)
+            acc(i + j + 1, mp >> LB)
+    res, top = _tile_carry(cols[NL:], carry)
+    diff, borrow = _tile_sub(res, _M_FP)
+    return _tile_where((borrow == 0) | (top > 0), diff, res)
+
+
+def _tile_pt_select(cond, p, q):
+    """Per-lane select: cond of the limbs' shape -> p where true else q."""
+    return tuple(_tile_where(cond, a, b) for a, b in zip(p, q))
+
+
+def _tile_one_like(a):
+    """The Montgomery one (R mod p) as limb tiles shaped like a."""
+    return [jnp.full(a[0].shape, l, jnp.uint32) for l in _ONE_MONT]
+
+
+def _tile_inf_like(p):
+    """Infinity as `_inf_like` writes it: X = Y = the Montgomery one."""
+    one = _tile_one_like(p[0])
+    return (one, one, [jnp.zeros_like(z) for z in p[2]])
+
+
+def tile_canonical_scalar(k):
+    """k mod n for any 256-bit k on limb tiles (see `canonical_scalar`)."""
+    diff, borrow = _tile_sub(k, _N_ORDER)
+    return _tile_where(borrow == 0, diff, k)
+
+
+TILE_FIELD = Field(mul=tile_mont_mul, add=tile_fadd, sub=tile_fsub,
+                   is_zero=tile_fis_zero, select=_tile_pt_select,
+                   one_like=_tile_one_like, inf_like=_tile_inf_like)
+
+
+# ---------------------------------------------------------------------------
+# G1 group law on (X, Y, Z) tuples of field elements (mirrors crypto/curve.py),
+# written once over a layout's `Field`
+# ---------------------------------------------------------------------------
+
+def make_group(field: Field):
+    """The G1 group law over one layout's field functions; returns (double,
+    add_complete, add_mixed)."""
+    mul, add_, sub_ = field.mul, field.add, field.sub
+    is_zero, select = field.is_zero, field.select
 
     def pdouble(p):
         X, Y, Z = p
@@ -201,14 +374,14 @@ def make_group(m_const, nprime):
         Z3 = mul(ZZ, H)
         res = (X3, Y3, Z3)
 
-        p_inf = fis_zero(Z1)
-        q_inf = fis_zero(Z2)
-        h0 = fis_zero(H)
-        r0 = fis_zero(r)
-        res = _pt_select(h0 & r0 & ~p_inf & ~q_inf, pdouble(p), res)
-        res = _pt_select(h0 & ~r0 & ~p_inf & ~q_inf, _inf_like(p), res)
-        res = _pt_select(q_inf, p, res)
-        res = _pt_select(p_inf, q, res)
+        p_inf = is_zero(Z1)
+        q_inf = is_zero(Z2)
+        h0 = is_zero(H)
+        r0 = is_zero(r)
+        res = select(h0 & r0 & ~p_inf & ~q_inf, pdouble(p), res)
+        res = select(h0 & ~r0 & ~p_inf & ~q_inf, field.inf_like(p), res)
+        res = select(q_inf, p, res)
+        res = select(p_inf, q, res)
         return res
 
     def pmadd(p, x2, y2, q_inf):
@@ -231,33 +404,11 @@ def make_group(m_const, nprime):
         V = mul(X1, HH)
         X3 = sub_(sub_(mul(r, r), HHH), add_(V, V))
         Y3 = sub_(mul(r, sub_(V, X3)), mul(Y1, HHH))
-        res = _pt_select(fis_zero(Z1), (x2, y2, _one_like(Z1)),
-                         (X3, Y3, Z3))
-        return _pt_select(q_inf, p, res)
+        res = select(is_zero(Z1), (x2, y2, field.one_like(Z1)),
+                     (X3, Y3, Z3))
+        return select(q_inf, p, res)
 
     return pdouble, padd, pmadd
-
-
-def _inf_like(p):
-    """Infinity point tiles shaped like p, as `curve.infinity` writes it:
-    X = Y = the Montgomery one, Z = 0. Any X, Y mean infinity where
-    Z == 0; the jnp layer's limbs keep `padd` byte-identical to
-    `curve.add` in every case (tests/test_dro.py)."""
-    one = _one_like(p[0])
-    return (one, one, jnp.zeros_like(p[2]))
-
-
-def _one_like(a):
-    """The Montgomery one (R mod p) as tiles shaped like a."""
-    return jnp.stack([jnp.full(a.shape[1:], l, jnp.uint32)
-                      for l in _ONE_MONT])
-
-
-def canonical_scalar(k, n):
-    """k mod n for any 256-bit k on (16, B) tiles, n (16, 1) the group
-    order's limbs: 2n > 2^256, so one conditional subtraction suffices."""
-    diff, borrow = _sub_limbs(k, jnp.broadcast_to(n, k.shape))
-    return jnp.where((borrow == 0)[None, :], diff, k)
 
 
 # ---------------------------------------------------------------------------
@@ -268,7 +419,7 @@ def _scalar_mul_kernel(m_ref, np_ref, p_ref, k_ref, o_ref, dig_ref,
                        *, n_windows: int = 64):
     m = m_ref[:]                              # (16, 1) modulus limbs
     nprime = np_ref[0, 0]
-    pdouble, padd, _ = make_group(m, nprime)
+    pdouble, padd, _ = make_group(sublane_field(m, nprime))
 
     P = (p_ref[0], p_ref[1], p_ref[2])        # each (16, B)
     k = k_ref[:]                              # (16, B)
@@ -371,10 +522,15 @@ def _pallas_scalar_mul(m_in, np_in, pt, kt, n_tiles, Np, n_windows=64,
 # Fixed-base windowed mult kernel: shared (64, 16)-entry table, add-only
 # ---------------------------------------------------------------------------
 
-def _fixed_base_kernel(m_ref, np_ref, n_ref, tab_ref, k_ref, o_ref, dig_ref):
-    """tab_ref: (W, 16, 48) — row w holds [16 limbs x (coord c * 16 + digit
-    v)] of the precomputed AFFINE points v * 16^w * P (Z the Montgomery one,
-    or zero for infinity: the v=0 column, or every column of a table of the
+_ENTRY = 2 * NL + 1               # words a table entry: x, y, is-infinity
+
+
+def _fixed_base_kernel(tab_ref, k_ref, o_ref, dig_ref):
+    """On limb tiles (TILE_FIELD): k_ref (16, 8, 128), o_ref (3, 16, 8, 128),
+    dig_ref (W, 8, 128). tab_ref: flat in SMEM, entry (w, v) at
+    (w * 16 + v) * _ENTRY: the 16 limbs of x, the 16 of y, then 1 where the
+    entry is the point at infinity, of the precomputed AFFINE points
+    v * 16^w * P (infinity: the v=0 entry, or every entry of a table of the
     point at infinity). W mixed (Jacobian + affine) additions, no doubles
     (the 16^w factors are baked in); W < 64 serves scalars known to be
     < 16^W (small plaintexts).
@@ -384,88 +540,79 @@ def _fixed_base_kernel(m_ref, np_ref, n_ref, tab_ref, k_ref, o_ref, dig_ref):
     accumulator is (k mod 16^w) * P and the addend d * 16^w * P with d in
     1..15; they are the same or opposite points only if d * 16^w -/+
     (k mod 16^w) is a multiple of the group order n, which k < n excludes
-    (n / 2^252 = 8.98 and G1 has prime order). k is made canonical first
-    (n_ref: n's limbs), so that holds for every 256-bit input."""
-    m = m_ref[:]
-    nprime = np_ref[0, 0]
-    _, _, pmadd = make_group(m, nprime)
-    k = canonical_scalar(k_ref[:], n_ref[:])  # (16, B)
-    B = k.shape[1]
+    (n / 2^252 = 8.98 and G1 has prime order). k is made canonical first,
+    so that holds for every 256-bit input."""
+    _, _, pmadd = make_group(TILE_FIELD)
+    k = tile_canonical_scalar([k_ref[l] for l in range(NL)])
     W = dig_ref.shape[0]
-
-    rows = []
     for w in range(W):                        # little-endian digit order
         limb, s = divmod(w, 4)
-        rows.append((k[limb] >> np.uint32(4 * s)) & np.uint32(0xF))
-    dig_ref[:] = jnp.stack(rows)              # (W, B)
-
-    def sel(cand, masks):
-        # cand (R, 16) = rows x digit v; per-lane digit select by splat
-        R = cand.shape[0]
-        acc = jnp.broadcast_to(cand[:, 0:1], (R, B))
-        for v in range(1, 16):
-            splat = jnp.broadcast_to(cand[:, v:v + 1], (R, B))
-            acc = jnp.where(masks[v], splat, acc)
-        return acc
+        dig_ref[w] = (k[limb] >> np.uint32(4 * s)) & np.uint32(0xF)
 
     def body(w, acc):
-        row = tab_ref[pl.ds(w, 1)][0]         # (16, 48) = limbs x (c*16+v)
-        d = dig_ref[pl.ds(w, 1), :][0]        # (B,)
-        masks = [(d == v)[None, :] for v in range(16)]
-        z = row[:, 32:48]
-        z_or = z[0:1]                         # (1, 16): OR of Z's limbs
-        for l in range(1, NL):
-            z_or = z_or | z[l:l + 1]
-        q_inf = sel(z_or, masks)[0] == 0
-        return pmadd(acc, sel(row[:, 0:16], masks),
-                     sel(row[:, 16:32], masks), q_inf)
+        d = dig_ref[w]                        # (8, 128)
+        base = w * np.int32(16 * _ENTRY)
+        masks = [d == np.uint32(v) for v in range(1, 16)]
 
-    zero = jnp.zeros((NL, B), jnp.uint32)
-    acc0 = _inf_like((zero, zero, zero))
+        def sel(word):
+            # per-lane digit select of one word of the window's 16
+            # entries, each a scalar: 15 whole-vreg selects
+            out = jnp.full(d.shape, tab_ref[base + word], jnp.uint32)
+            for v in range(1, 16):
+                out = jnp.where(masks[v - 1],
+                                tab_ref[base + (v * _ENTRY + word)], out)
+            return out
+
+        return pmadd(acc, [sel(l) for l in range(NL)],
+                     [sel(NL + l) for l in range(NL)], sel(2 * NL) != 0)
+
+    acc0 = _tile_inf_like((k, k, k))
     acc = jax.lax.fori_loop(jnp.int32(0), jnp.int32(W), body, acc0)
-    o_ref[0] = acc[0]
-    o_ref[1] = acc[1]
-    o_ref[2] = acc[2]
+    for c in range(3):
+        for l in range(NL):
+            o_ref[c, l] = acc[c][l]
+
+
+def _flat_table(table):
+    """(W, 16, 3, 16) window table -> the kernel's flat run of _ENTRY words
+    an entry (w, v): x's limbs, y's limbs, 1 where Z is zero. The table
+    comes from the caller (elgamal.FixedBase), so pin uint32 here as
+    `_pad_lanes` does."""
+    tab = jnp.asarray(table, dtype=jnp.uint32)
+    q_inf = jnp.all(tab[:, :, 2] == 0, axis=-1).astype(jnp.uint32)
+    return jnp.concatenate([tab[:, :, 0], tab[:, :, 1], q_inf[..., None]],
+                           axis=-1).reshape(-1)
 
 
 @functools.partial(jax.jit, static_argnames=("n_windows", "interpret"))
 def _fixed_base_mul_flat(table, k, n_windows: int, interpret: bool):
     N = k.shape[0]
     W = n_windows
-    n_tiles = max((N + LANES - 1) // LANES, 1)
-    Np = n_tiles * LANES
-    kt = _pad_lanes(jnp.transpose(k, (1, 0)), Np)      # (16, Np)
-    # (w, v, c, l) -> (w, l, c, v) -> (W, 16, 48); the table comes from the
-    # caller (elgamal.FixedBase), so pin uint32 here like _pad_lanes does
-    tt = jnp.asarray(jnp.transpose(table[:W], (0, 3, 2, 1)),
-                     dtype=jnp.uint32).reshape(W, NL, 48)
-
-    m_in = jnp.asarray(_M_FP[:, None], dtype=jnp.uint32)
-    np_in = jnp.asarray([[_NPRIME_FP]], dtype=jnp.uint32)
-    n_in = jnp.asarray(_N_ORDER[:, None], dtype=jnp.uint32)
+    n_tiles = max((N + TILE_LANES - 1) // TILE_LANES, 1)
+    Np = n_tiles * TILE_LANES
+    rows = Np // LANES
+    # (N, 16) -> (16, Np / 128, 128): lane n of the batch is row n // 128,
+    # column n % 128 of every limb's plane, one transpose either way
+    kt = jnp.transpose(_pad_lanes(k, Np, axis=0).reshape(rows, LANES, NL),
+                       (2, 0, 1))
+    tt = _flat_table(table[:W])
     with jax.enable_x64(False):
         out = pl.pallas_call(
             _fixed_base_kernel,
             grid=(n_tiles,),
             in_specs=[
-                pl.BlockSpec((NL, 1), lambda i: (0, 0),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((1, 1), lambda i: (0, 0),
-                             memory_space=pltpu.SMEM),
-                pl.BlockSpec((NL, 1), lambda i: (0, 0),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((W, NL, 48), lambda i: (0, 0, 0),
-                             memory_space=pltpu.VMEM),
-                pl.BlockSpec((NL, LANES), lambda i: (0, i),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec((NL, 8, LANES), lambda i: (0, i, 0),
                              memory_space=pltpu.VMEM),
             ],
-            out_specs=pl.BlockSpec((3, NL, LANES), lambda i: (0, 0, i),
+            out_specs=pl.BlockSpec((3, NL, 8, LANES),
+                                   lambda i: (0, 0, i, 0),
                                    memory_space=pltpu.VMEM),
-            out_shape=jax.ShapeDtypeStruct((3, NL, Np), jnp.uint32),
-            scratch_shapes=[pltpu.VMEM((W, LANES), jnp.uint32)],
+            out_shape=jax.ShapeDtypeStruct((3, NL, rows, LANES), jnp.uint32),
+            scratch_shapes=[pltpu.VMEM((W, 8, LANES), jnp.uint32)],
             interpret=interpret,
-        )(m_in, np_in, n_in, tt, kt)
-    return jnp.transpose(out, (2, 0, 1))[:N]
+        )(tt, kt)
+    return jnp.transpose(out, (2, 3, 0, 1)).reshape(Np, 3, NL)[:N]
 
 
 def fixed_base_mul_flat(table, k, n_windows: int = 64):
@@ -485,7 +632,7 @@ def fixed_base_mul_flat(table, k, n_windows: int = 64):
 
 def _point_add_kernel(m_ref, np_ref, p_ref, q_ref, o_ref):
     m = m_ref[:]
-    _, padd, _ = make_group(m, np_ref[0, 0])
+    _, padd, _ = make_group(sublane_field(m, np_ref[0, 0]))
     r = padd((p_ref[0], p_ref[1], p_ref[2]),
              (q_ref[0], q_ref[1], q_ref[2]))
     o_ref[0], o_ref[1], o_ref[2] = r
@@ -494,7 +641,7 @@ def _point_add_kernel(m_ref, np_ref, p_ref, q_ref, o_ref):
 def _point_reduce_kernel(m_ref, np_ref, p_ref, o_ref):
     """p_ref: (R, 3, 16, B) — sum rows 0..R-1 with the complete group add."""
     m = m_ref[:]
-    _, padd, _ = make_group(m, np_ref[0, 0])
+    _, padd, _ = make_group(sublane_field(m, np_ref[0, 0]))
     R = p_ref.shape[0]
     acc = (p_ref[0, 0], p_ref[0, 1], p_ref[0, 2])
     for r in range(1, R):                     # R is small + static: unroll
@@ -518,14 +665,15 @@ def _mk_point_io(n_tiles, Np, extra=None):
     )
 
 
-def _pad_lanes(x, Np):
+def _pad_lanes(x, Np, axis=-1):
     # every Mosaic operand funnels through here: pin uint32 at the choke
     # point so a weak int32/i64 limb tensor can never reach a kernel
     x = jnp.asarray(x, dtype=jnp.uint32)
-    N = x.shape[-1]
+    N = x.shape[axis]
     if N == Np:
         return x
-    pad = [(0, 0)] * (x.ndim - 1) + [(0, Np - N)]
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, Np - N)
     # pin the fill constant: a weak-typed 0 becomes i64 when traced with
     # x64 on, and mixing it into the x64-off pallas operand prep produces
     # a jaxpr that fails MLIR verification at lowering
@@ -595,5 +743,6 @@ def available() -> bool:
 
 __all__ = ["scalar_mul_flat", "fixed_base_mul_flat", "point_add_flat",
            "point_reduce_flat", "mont_mul", "fadd", "fsub", "make_group",
-           "canonical_scalar",
-           "available", "LANES"]
+           "canonical_scalar", "Field", "sublane_field", "TILE_FIELD",
+           "tile_mont_mul", "tile_fadd", "tile_fsub", "tile_canonical_scalar",
+           "available", "LANES", "TILE_LANES"]

@@ -211,12 +211,14 @@ def main():
 
     def c_fixed_base():
         # the ladder adds affine table entries with the mixed addition and
-        # reduces k mod n first: the edges of both, over two tiles of
-        # lanes, for the generator's table and a public key's
+        # reduces k mod n first: the edges of both, over two 1 024-lane
+        # tiles (the edges again at the second tile's end, in its padded
+        # last rows), for the generator's table and a public key's
         n = params.N
-        ks = [0, 1, 2, 12345, n - 1, n, n + 1, 2 ** 256 - 1,
-              (8 << 252) + 12345, 15 << 248, 16 ** 63, 0xF0F0 << 100]
-        ks += [int.from_bytes(rng.bytes(32), "little") for _ in range(188)]
+        edges = [0, 1, 2, 12345, n - 1, n, n + 1, 2 ** 256 - 1,
+                 (8 << 252) + 12345, 15 << 248, 16 ** 63, 0xF0F0 << 100]
+        ks = edges + [int.from_bytes(rng.bytes(32), "little")
+                      for _ in range(po.TILE_LANES + 76)] + edges
         kd = jnp.asarray(F.from_int(ks))
         pub = refimpl.g1_mul(refimpl.G1, rfp() % n)
         for base, tbl in [(refimpl.G1, eg.BASE_TABLE),
@@ -230,6 +232,10 @@ def main():
             n_windows=16))
         for i, k in enumerate(small):
             assert got[i] == refimpl.g1_mul(refimpl.G1, k), (i, hex(k))
+        got = C.to_ref(po.fixed_base_mul_flat(
+            eg.BASE_TABLE.table, jnp.asarray(F.from_int(small[:5])),
+            n_windows=2))
+        assert got == [refimpl.g1_mul(refimpl.G1, k) for k in small[:5]]
         got = C.to_ref(po.fixed_base_mul_flat(
             eg.FixedBase(None).table, kd[:8]))
         assert got == [None] * 8

@@ -1,13 +1,16 @@
-"""The fixed-base ladder's window step as plain functions on (16, B) tiles.
+"""The fixed-base ladder's window step as plain functions, in both layouts.
 
 `pallas_ops._fixed_base_kernel` adds a table entry to its accumulator with
 the mixed (Jacobian + affine) addition of `make_group` and makes its scalar
-canonical first. Both are plain jnp functions on limb tiles, so they run
-here eagerly, outside any `pallas_call` and outside the interpreter (the
-whole ladder through the interpreter is the opt-in tier of
-tests/test_pallas_kernels.py), against Python integers and `refimpl`.
-And the invariant the kernel rests on: every table `elgamal.FixedBase`
-makes is affine."""
+canonical first. `make_group` writes the group law once over a layout's
+`Field`: the sublane bundle ((16, B) arrays; the other G1 kernels') and the
+limb-tile bundle (16 arrays a field element; the fixed-base kernel's since
+PR 33). All are plain jnp functions, so they run here eagerly, outside any
+`pallas_call` and outside the interpreter (the whole ladder through the
+interpreter is tests/test_pallas_kernels.py), against Python integers and
+`refimpl`, and the two layouts against each other byte for byte. And the
+invariant the kernel rests on: every table `elgamal.FixedBase` makes is
+affine."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -32,7 +35,18 @@ def _tile(values):
 
 
 def _ints(tile):
-    return [int(v) for v in F.to_int(np.asarray(tile).T)]
+    """(16, B) array, or a list of 16 (B,) limbs -> ints."""
+    return [int(v) for v in F.to_int(np.stack(
+        [np.asarray(limb) for limb in tile]).T)]
+
+
+# layout -> its Field; both take and give field elements that `_tile` makes
+# and `_ints` reads (the limb-tile functions index the major axis only)
+FIELDS = {
+    "sublane": lambda: po.sublane_field(jnp.asarray(po._M_FP[:, None]),
+                                        po._NPRIME_FP),
+    "tile": lambda: po.TILE_FIELD,
+}
 
 
 def _jacobian(pt, z):
@@ -66,17 +80,21 @@ CASES = {
 }
 
 
-@pytest.fixture(scope="module")
-def madd_lanes():
-    """One eager call of the mixed addition, one lane a case."""
+def _pmadd(layout, p, x2, y2, z2):
+    with jax.enable_x64(False):
+        field = FIELDS[layout]()
+        _, _, pmadd = po.make_group(field)
+        return pmadd(p, x2, y2, field.is_zero(z2))
+
+
+@pytest.fixture(scope="module", params=list(FIELDS))
+def madd_lanes(request):
+    """One eager call of a layout's mixed addition, one lane a case."""
     accs = [_jacobian(a, z) for a, z, _ in CASES.values()]
     ents = [_jacobian(q, 1) for _, _, q in CASES.values()]
     p = tuple(_tile([a[c] for a in accs]) for c in range(3))
     x2, y2, z2 = (_tile([e[c] for e in ents]) for c in range(3))
-    m = jnp.asarray(po._M_FP[:, None])
-    with jax.enable_x64(False):
-        _, _, pmadd = po.make_group(m, po._NPRIME_FP)
-        out = pmadd(p, x2, y2, po.fis_zero(z2))
+    out = _pmadd(request.param, p, x2, y2, z2)
     return p, tuple(_ints(t) for t in out)
 
 
@@ -94,12 +112,66 @@ def test_mixed_addition_matches_oracle(madd_lanes, case):
         assert got == tuple(_ints(c)[lane] for c in p)
 
 
+def test_layouts_agree_byte_for_byte():
+    """The limb-tile mixed addition against the sublane one on random
+    lanes, infinity planted on either side and on both: every limb equal,
+    so the ladder's output bytes are the parent's."""
+    n = 24
+    accs = [_jacobian(refimpl.g1_mul(refimpl.G1, _rand(N)), _rand(P))
+            for _ in range(n)]
+    ents = [_jacobian(refimpl.g1_mul(refimpl.G1, _rand(N)), 1)
+            for _ in range(n)]
+    inf = _jacobian(None, 0)
+    accs[1] = accs[3] = inf
+    ents[2] = ents[3] = inf
+    ents[4] = (_rand(P), _rand(P), 0)       # infinity with arbitrary x, y
+    p = tuple(_tile([a[c] for a in accs]) for c in range(3))
+    x2, y2, z2 = (_tile([e[c] for e in ents]) for c in range(3))
+    sub = np.asarray(jnp.stack(_pmadd("sublane", p, x2, y2, z2)))
+    tile = np.stack([np.stack([np.asarray(limb) for limb in coord])
+                     for coord in _pmadd("tile", p, x2, y2, z2)])
+    assert sub.shape == tile.shape == (3, params.NUM_LIMBS, n)
+    assert (sub == tile).all()
+
+
+# operands whose sum or product needs the final subtraction, and whose
+# Montgomery reduction ends with a carry out of the top limb (`top > 0`):
+# 2^256 - 1 is no normalized input, and both layouts take it alike
+EDGES = [0, 1, 2, P - 1, P - 2, (P + 1) // 2, (P - 1) // 2, R % P,
+         2 ** 254 - 1, 2 ** 256 - 1]
+OPS = {
+    "mont_mul": (lambda f: f.mul,
+                 lambda a, b: a * b * pow(R, -1, P) % P),
+    "fadd": (lambda f: f.add, lambda a, b: (a + b) % P),
+    "fsub": (lambda f: f.sub, lambda a, b: (a - b) % P),
+}
+
+
+@pytest.mark.parametrize("op", list(OPS))
+def test_limb_tile_field_matches_integers(op):
+    pick, want = OPS[op]
+    a = [x for x in EDGES for _ in EDGES] + [_rand(P) for _ in range(156)]
+    b = [y for _ in EDGES for y in EDGES] + [_rand(P) for _ in range(156)]
+    with jax.enable_x64(False):
+        got = pick(po.TILE_FIELD)(_tile(a), _tile(b))
+        ref = pick(FIELDS["sublane"]())(_tile(a), _tile(b))
+    got = _ints(got)
+    assert got == _ints(ref)
+    for i, (x, y) in enumerate(zip(a, b)):
+        if x < P and y < P:
+            assert got[i] == want(x, y), (hex(x), hex(y))
+
+
+@pytest.mark.parametrize("layout", list(FIELDS))
 @pytest.mark.parametrize("k", [0, 1, N - 1, N, N + 1, 2 ** 256 - 1],
                          ids=["0", "1", "n-1", "n", "n+1", "2^256-1"])
-def test_scalar_made_canonical(k):
+def test_scalar_made_canonical(k, layout):
     with jax.enable_x64(False):
-        got = po.canonical_scalar(_tile([k, 5]),
-                                  jnp.asarray(po._N_ORDER[:, None]))
+        if layout == "tile":
+            got = po.tile_canonical_scalar(_tile([k, 5]))
+        else:
+            got = po.canonical_scalar(_tile([k, 5]),
+                                      jnp.asarray(po._N_ORDER[:, None]))
     assert _ints(got) == [k % N, 5]
 
 

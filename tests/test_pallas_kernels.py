@@ -13,8 +13,7 @@ import pytest
 # The full ladder kernels take many minutes to compile through the
 # interpreter on CPU; they are validated on real TPU by
 # scripts/pallas_parity.py. Opt in with DRYNX_PALLAS_INTERPRET_TESTS=1.
-# (The fixed-base ladder's take 47-49 s each since its window step is the
-# mixed addition; one test of it, against the oracle alone, is tier-1.)
+# (The fixed-base ladder is tier-1: see `fixed_base_interpreted`.)
 heavy = pytest.mark.skipif(
     os.environ.get("DRYNX_PALLAS_INTERPRET_TESTS", "0") != "1",
     reason="ladder-kernel interpret compile is minutes-slow on CPU; "
@@ -67,57 +66,144 @@ def test_scalar_mul_kernel_matches_jnp():
     _assert_points_equal(out_pallas, out_jnp)
 
 
-@heavy
-def test_fixed_base_kernel_matches_jnp():
-    n = 5
-    k, ss = _rand_scalars(n)
-    # edges of the mixed-addition ladder: the largest scalar, and a top
-    # digit of 8 (n's own: the last window whose addend could meet the
-    # accumulator if k were not below n)
-    ss[2], ss[3] = params.N - 1, (8 << 252) + 12345
-    k = jnp.asarray(F.from_int(ss))
-    out_pallas = po.fixed_base_mul_flat(eg.BASE_TABLE.table, k)
-    out_jnp = eg._fixed_base_mul_jnp(eg.BASE_TABLE.table, k)
-    _assert_points_equal(out_pallas, out_jnp)
-    assert C.to_ref(out_pallas[1]) == refimpl.g1_mul(refimpl.G1, ss[1])
+# What the limb-tile fixed-base kernel (PR 33) costs the interpreter: its
+# window step is 31 000 whole-vreg operations on arrays of ONE shape, and the
+# CPU compiler's instruction fusion does not come back from merging them (a
+# chain of two products compiles in 37 s for one's 5 s; the loop body ran
+# half an hour unfinished). So the kernel is compiled ahead of time with
+# that one pass off, 75-100 s a (n_windows, tiles) pair on the 8-core
+# sandbox, and a pair is compiled once for every lane count, table and test
+# that pads to it: the wrapper's own padding, transposes and table
+# flattening run eagerly around it, as `_fixed_base_mul_flat` writes them.
+_UNFUSED = {}
 
 
-def test_fixed_base_kernel_edges_against_oracle(monkeypatch):
-    """The whole 64-window kernel through the interpreter (26 s of
-    compile on the 8-core sandbox since its window step is the mixed
-    addition), against the Python oracle alone: the scalars around the
-    group order that the kernel reduces itself, a top digit of 8, zero
-    digits low and high, and the table of the point at infinity."""
-    n = params.N
-    ks = [0, 1, n - 1, n, n + 1, 2 ** 256 - 1, (8 << 252) + 12345,
-          0xF0F0 << 100]
-    k = jnp.asarray(F.from_int(ks))
-    out = po.fixed_base_mul_flat(eg.BASE_TABLE.table, k)
-    of_infinity = po.fixed_base_mul_flat(eg.FixedBase(None).table, k)
-    assert not np.asarray(of_infinity)[:, 2].any()
-    # The kernel alone runs through the interpreter. Its points are read
-    # back in the mode every other module runs in: `C.normalize` traces the
-    # inversion `po.available()` selects and jit keeps that trace for the
-    # shape, so one made here (the Pallas inversion, which the CPU cannot
-    # lower outside the interpreter) would fail here and then serve every
-    # later module's normalize of eight points.
+@pytest.fixture
+def fixed_base_interpreted(monkeypatch):
+    """`run(table, k, n_windows)`: `po._fixed_base_mul_flat`, its kernel
+    through the Pallas interpreter."""
+    real = po.pl.pallas_call
+
+    def pallas_call(kernel, **kw):
+        call = real(kernel, **kw)
+
+        def run(*args):
+            key = (kernel.__name__, kw["grid"], str(kw["scratch_shapes"]),
+                   tuple(a.shape for a in args))
+            if key not in _UNFUSED:
+                _UNFUSED[key] = jax.jit(call).lower(*args).compile(
+                    compiler_options={"xla_disable_hlo_passes": "fusion"})
+            return _UNFUSED[key](*args)
+        return run
+
+    monkeypatch.setattr(po.pl, "pallas_call", pallas_call)
+    return lambda table, k, n_windows=64: po._fixed_base_mul_flat.__wrapped__(
+        table, k, n_windows, True)
+
+
+def _leave_interpreter(monkeypatch):
+    """The kernel alone runs through the interpreter. Its points are read
+    back in the mode every other module runs in: `C.normalize` traces the
+    inversion `po.available()` selects and jit keeps that trace for the
+    shape, so one made here (the Pallas inversion, which the CPU cannot
+    lower outside the interpreter) would fail here and then serve every
+    later module's normalize of as many points."""
     monkeypatch.setattr(po, "INTERPRET", False)
-    assert C.to_ref(out) == [refimpl.g1_mul(refimpl.G1, s) for s in ks]
 
 
-@heavy
-def test_fixed_base_ladder_small_always_on():
-    """Formerly always-on slice of the ladder kernel (n_windows=2): measured
-    in round 4, even this truncated interpret compile runs tens of minutes
-    on this box under jax 0.8, so it joins the opt-in interpret tier — the
-    kernels are validated on hardware (scripts/pallas_parity.py) and the
-    digit/table/padd logic is oracle-tested at the jnp layer."""
-    ss = [0, 1, 200]  # infinity edge + generator + 2-digit scalar
+PUB = refimpl.g1_mul(refimpl.G1, 0xD1CE << 77)
+# name -> (the base point, its table)
+TABLES = {
+    "base": (refimpl.G1, lambda: eg.BASE_TABLE),
+    "pub": (PUB, lambda: eg.pub_table(PUB)),
+    "infinity": (None, lambda: eg.FixedBase(None)),
+}
+N_ = params.N
+EDGE_SCALARS = [0, 1, N_ - 1, N_, N_ + 1, 2 ** 256 - 1, (8 << 252) + 12345,
+                0xF0F0 << 100]
+
+
+def _mul(base, k):
+    return None if base is None else refimpl.g1_mul(base, k % N_)
+
+
+@pytest.mark.parametrize("table", list(TABLES))
+def test_fixed_base_kernel_edges_against_oracle(
+        fixed_base_interpreted, monkeypatch, table):
+    """All 64 windows over TWO 1 024-lane tiles (1 025 lanes: the second
+    tile one lane and padding), against the Python oracle alone: the
+    scalars around the group order that the kernel reduces itself, a top
+    digit of 8 (n's own: the last window whose addend could meet the
+    accumulator if k were not below n), zero digits low and high, at the
+    first tile's start and across the tiles' seam; random scalars between;
+    the generator's table, a public key's and the point at infinity's."""
+    base, make = TABLES[table]
+    ks = {i: k for i, k in enumerate(EDGE_SCALARS)}
+    ks.update({po.TILE_LANES - 4 + i: k
+               for i, k in enumerate(EDGE_SCALARS[2:7])})
+    ks.update({i: int.from_bytes(RNG.bytes(32), "little")
+               for i in (8, 9, 127, 128, 500)})
+    k = np.zeros((po.TILE_LANES + 1, params.NUM_LIMBS), np.uint32)
+    k[sorted(ks)] = F.from_int([ks[i] for i in sorted(ks)])
+    out = fixed_base_interpreted(make().table, jnp.asarray(k))
+    assert out.shape == (po.TILE_LANES + 1, 3, params.NUM_LIMBS)
+    if base is None:
+        assert not np.asarray(out)[:, 2].any()
+        return
+    _leave_interpreter(monkeypatch)
+    got = C.to_ref(out[np.asarray(sorted(ks))])
+    assert got == [_mul(base, ks[i]) for i in sorted(ks)]
+    rest = np.delete(np.asarray(out), sorted(ks), axis=0)
+    assert not rest[:, 2].any()               # the zero scalars between
+
+
+@pytest.mark.parametrize("table", ["base", "pub"])
+@pytest.mark.parametrize("lanes", [1, 5, 129, po.TILE_LANES])
+def test_fixed_base_kernel_short_ladder_pads_to_a_tile(
+        fixed_base_interpreted, monkeypatch, lanes, table):
+    """16 windows (scalars below 16^16: the small plaintexts' ladder) at
+    lane counts that all pad to one tile, against the jnp ladder and, on
+    eight lanes, the oracle."""
+    base, make = TABLES[table]
+    small = [0, 1, 15, 16, 200, 16 ** 16 - 1, 0x1234567890ABCDEF, 16 ** 15]
+    ss = (small + [int.from_bytes(RNG.bytes(8), "little")
+                   for _ in range(lanes)])[:lanes]
     k = jnp.asarray(F.from_int(ss))
-    out_pallas = po.fixed_base_mul_flat(eg.BASE_TABLE.table, k, n_windows=2)
-    out_jnp = eg._fixed_base_mul_jnp(eg.BASE_TABLE.table, k, n_windows=2)
-    _assert_points_equal(out_pallas, out_jnp)
-    assert C.to_ref(out_pallas[2]) == refimpl.g1_mul(refimpl.G1, 200)
+    out = fixed_base_interpreted(make().table, k, n_windows=16)
+    assert out.shape == (lanes, 3, params.NUM_LIMBS)
+    _leave_interpreter(monkeypatch)
+    _assert_points_equal(
+        out, eg._fixed_base_mul_jnp(make().table, k, n_windows=16))
+    assert C.to_ref(out[:8]) == [_mul(base, s) for s in ss[:8]]
+
+
+def test_fixed_base_kernel_two_windows(monkeypatch):
+    """The kernel's body as a plain function on arrays that stand for its
+    refs (eight lanes a limb: the limb-tile functions take any shape), two
+    windows, eagerly: the digit order, the table's slice and its flat
+    layout at the smallest `n_windows`, which the interpreter would compile
+    a third time for."""
+    class Ref:
+        def __init__(self, a):
+            self.a = np.array(a)
+            self.shape = self.a.shape
+
+        def __getitem__(self, i):          # every read is of one row
+            return jnp.asarray(self.a[int(i)])
+
+        def __setitem__(self, i, v):
+            self.a[i] = np.asarray(v)
+
+    ss = [0, 1, 15, 16, 17, 200, 255, 0xF0]
+    out = Ref(np.zeros((3, params.NUM_LIMBS, len(ss)), np.uint32))
+    with jax.disable_jit(), jax.enable_x64(False):
+        po._fixed_base_kernel(
+            Ref(po._flat_table(eg.BASE_TABLE.table[:2])),
+            Ref(F.from_int(ss).T), out,
+            Ref(np.zeros((2, len(ss)), np.uint32)))
+    _leave_interpreter(monkeypatch)
+    got = C.to_ref(jnp.asarray(out.a.transpose(2, 0, 1)))
+    assert got == [_mul(refimpl.G1, s) for s in ss]
 
 
 @heavy

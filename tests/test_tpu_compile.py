@@ -107,6 +107,9 @@ CASES = [
     ("point_reduce_flat/R10",
      lambda s: po._point_reduce_flat.lower(s((10, B, 3, NL)),
                                            interpret=False)),
+    # the limb-tile kernel since PR 33: 2 048 lanes are two of its tiles;
+    # 12.5-15 s a case, 11 of them the lowering of 31 000 operations a
+    # window, so the file's 120 s hold no case at 1 024 or 4 096 besides
     ("fixed_base_mul_flat/64w",
      lambda s: po._fixed_base_mul_flat.lower(
          s((64, 16, 3, NL)), _k(s), n_windows=64, interpret=False)),
