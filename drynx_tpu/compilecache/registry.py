@@ -649,7 +649,7 @@ def _pallas_specs(p: Profile) -> list:
 def _fused_specs(p: Profile) -> list:
     """The fused exec pipeline (service.py module-level jits), at the exact
     survey shapes run_survey dispatches."""
-    V, nd, nc, T = p.n_values, p.n_dps, p.n_cns, 2 * p.dlog_limit
+    V, nd, T = p.n_values, p.n_dps, 2 * p.dlog_limit
 
     def enc_at(w):
         def go(do="lower"):
@@ -674,15 +674,25 @@ def _fused_specs(p: Profile) -> list:
         return (svc._fused_agg(*a) if do == "call"
                 else svc._fused_agg.lower(*a))
 
+    # the key switch is one pass a computing node and a finish, both at
+    # width V: no program of it knows the roster's size
     def ks(do="lower"):
+        from ..parallel import keyswitch as kswitch
+
+        args = (_fb_table(), _z((V, 3, NL)), _z((NL,)), _z((V, NL)),
+                _z((V, 3, NL)), _z((V, 3, NL)))
+        return (kswitch._ks_pass(*args) if do == "call"
+                else kswitch._ks_pass.lower(*args))
+
+    def ks_finish(do="lower"):
         import jax.numpy as jnp
 
-        from ..service import service as svc
+        from ..parallel import keyswitch as kswitch
 
-        args = (_fb_table(), _z((V, 2, 3, NL)), _z((nc, V, NL)),
-                _z((nc, NL)), jnp.asarray(0, dtype=jnp.int64))
-        return (svc._fused_ks(*args) if do == "call"
-                else svc._fused_ks.lower(*args))
+        args = (_z((V, 2, 3, NL)), _z((V, 3, NL)), _z((V, 3, NL)),
+                jnp.asarray(0, dtype=jnp.int64))
+        return (kswitch._ks_finish(*args) if do == "call"
+                else kswitch._ks_finish.lower(*args))
 
     def dec(do="lower"):
         from ..service import service as svc
@@ -697,7 +707,9 @@ def _fused_specs(p: Profile) -> list:
                                         lambda th=th: th("call"))
     specs = [mk("enc", enc, "DataCollection"),
              mk("agg", agg, "Aggregation"),
-             mk("ks", ks, "KeySwitching"), mk("dec", dec, "Decryption")]
+             mk("ks", ks, "KeySwitching"),
+             mk("ks_finish", ks_finish, "KeySwitching"),
+             mk("dec", dec, "Decryption")]
     if p.n_buckets > 0:
         # chunked encrypt of a grid survey: service.execute_survey slabs
         # the (nd, n_buckets) stats through _fused_enc at plan_tiles

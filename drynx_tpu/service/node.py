@@ -48,6 +48,7 @@ from ..crypto import refimpl
 from ..analysis import Secret
 from ..encoding import stats as st
 from ..parallel import dro
+from ..parallel import keyswitch as kswitch
 from ..parallel import obfuscation as obf
 from ..proofs import aggregation as agg_proof
 from ..proofs import keyswitch as ks_proof
@@ -790,16 +791,12 @@ class DrynxNode:
         K0 = unpack_array_device(msg["k_component"])   # (V, 3, 16)
         client_pub = tuple(msg["client_pub"])
         q_tbl = self._pub_table(client_pub)
-        V = K0.shape[0]
-        key = jax.random.PRNGKey(secrets.randbits(63))
-        rs = eg.random_scalars(key, (V,))
         x = jnp.asarray(eg.secret_to_limbs(self.secret))
-        u_pts = B.fixed_base_mul(eg.BASE_TABLE.table, rs)
-        rQ = B.fixed_base_mul(q_tbl.table, rs)
-        xK = B.g1_scalar_mul(K0, x)
-        # the switched component w = rQ - xK is ciphertext — a public
-        # protocol output even though the secret key went into it
-        w_pts = B.g1_add(rQ, B.g1_neg(xK))  # drynx: declassify[secret]
+        # the node's own pass, as LocalCluster.key_switch makes it; the
+        # switched component w = rQ - xK is ciphertext — a public protocol
+        # output even though the secret key went into it
+        _, (u_pts, w_pts, rs) = kswitch.node_pass(  # drynx: declassify[secret]
+            jax.random.PRNGKey(secrets.randbits(63)), K0, x, q_tbl.table)
         if msg.get("proofs"):
             key2 = jax.random.PRNGKey(secrets.randbits(63))
             # a ZK proof transcript (commitments + responses) is public
@@ -807,7 +804,7 @@ class DrynxNode:
             pr = ks_proof.create_keyswitch_proofs(  # drynx: declassify[secret]
                 key2, K0, x[None], rs[None],
                 jnp.asarray(C.from_ref(client_pub)), q_tbl.table,
-                jnp.asarray(u_pts)[None], jnp.asarray(w_pts)[None])
+                u_pts[None], w_pts[None])
             self._send_proof_async("keyswitch", msg["survey_id"],
                                    f"ks-{self.name}", pickle.dumps(pr))
         return {"u": pack_array(np.asarray(u_pts)),
@@ -1185,18 +1182,12 @@ class DrynxNode:
             k_sum = u if k_sum is None else B.g1_add(k_sum, u)
             c_sum = w if c_sum is None else B.g1_add(c_sum, w)
 
-        c2 = B.g1_add(agg[:, 1], c_sum)
-        if range_offset:
-            # subtract the public aggregate shift (n_responders * u^l/2)·B
-            # so the decrypted values are the true signed statistics — each
-            # RESPONDING DP added one offset; absent DPs added none
-            total = range_offset * len(responders)
-            assert total < 2 ** 62, "offset too large for int64 scalar path"
-            corr = B.fixed_base_mul(
-                eg.BASE_TABLE.table,
-                B.int_to_scalar(jnp.asarray([total], dtype=jnp.int64)))
-            c2 = B.g1_add(c2, B.g1_neg(jnp.broadcast_to(corr[0], c2.shape)))
-        switched = jnp.stack([k_sum, c2], axis=-3)
+        # C + the summed contributions, less the public aggregate shift
+        # (n_responders * u^l/2)·B so the decrypted values are the true
+        # signed statistics — each RESPONDING DP added one offset; absent
+        # DPs added none
+        switched = kswitch.finish(jnp.asarray(agg), (k_sum, c_sum),
+                                  range_offset * len(responders))
         # let this node's own proof threads drain before replying so the
         # querier's end_verification doesn't race local stragglers
         with self._state_lock:

@@ -38,6 +38,7 @@ from ..encoding import tiles as enc_tiles
 from ..models import logreg as lr
 from ..parallel import collective as col
 from ..parallel import dro
+from ..parallel import keyswitch as kswitch
 from ..parallel import obfuscation as obf
 from ..proofs import aggregation as agg_proof
 from ..proofs import keyswitch as ks_proof
@@ -52,7 +53,7 @@ from ..utils.exec_store import stored as _stored
 # `_trace_reads`: what the stored programs' keys hold of the environment,
 # under the name tests and readers of this module know
 from ..utils.exec_store import trace_reads as _trace_reads  # noqa: F401
-from ..utils.timers import PROCESS, PhaseTimers, install_listener
+from ..utils.timers import PROCESS, PhaseTimers, install_listener, step_of
 from . import topology as topo
 from .proof_collection import VerifyCache, VerifyingNode, VNGroup
 from .store import ProofDB, SurveyCheckpoint
@@ -524,21 +525,39 @@ class LocalCluster:
     # ------------------------------------------------------------------
     def _fused(self):
         coll_tbl = self.coll_tbl.table
-        q_tbl = self.client_tbl.table
 
         def enc(stats, enc_rs):
             return _fused_enc(coll_tbl, stats, enc_rs)
 
-        def ks(agg, ks_rs, srv_x, offset_total):
-            return _fused_ks(q_tbl, agg, ks_rs, srv_x, offset_total)
+        return enc, _fused_agg, _fused_dec
 
-        return enc, _fused_agg, ks, _fused_dec
+    # the stored programs of a survey, for the set-up report: the fused
+    # phases with the key switch's node pass and finish among them, the
+    # noise phase's three slab programs and the obfuscation phase's pass
+    FUSED = ("_fused_enc", "_fused_agg") + kswitch.PROGRAMS \
+        + ("_fused_dec",) + dro.PROGRAMS + obf.PROGRAMS
 
-    # the stored programs of a survey, for the set-up report: the four
-    # fused phases, the noise phase's three slab programs and the
-    # obfuscation phase's pass
-    FUSED = ("_fused_enc", "_fused_agg", "_fused_ks",
-             "_fused_dec") + dro.PROGRAMS + obf.PROGRAMS
+    def key_switch(self, key, agg, offset_total: int = 0, tm=None,
+                   keep: bool = False):
+        """The aggregate (V, 2, 3, 16) switched to the querier's key: one
+        pass a computing node, each with its own secret and V fresh scalars
+        of its own (parallel/keyswitch.py, the guarantee), the
+        contributions summed in roster order, then the finish. Every
+        roster runs the same passes, and nothing here is as wide as the
+        roster. Returns (switched, kept): with `keep` (proofs on) every
+        node's (U_i, W_i, r_i), what its proof is made of; else empty."""
+        with step_of(tm, "secrets"):
+            xs = [jnp.asarray(eg.secret_to_limbs(c.secret))
+                  for c in self.cns]
+            PROCESS.count("h2d_bytes", sum(x.nbytes for x in xs))
+        K0, q_tbl = agg[:, 0], self.client_tbl.table
+        acc, kept = None, []
+        for i, x in enumerate(xs):
+            acc, made = kswitch.node_pass(jax.random.fold_in(key, i), K0, x,
+                                          q_tbl, acc, tm=tm)
+            if keep:
+                kept.append(made)
+        return kswitch.finish(agg, acc, offset_total, tm=tm), kept
 
     # bucket-grid Profile axis: st.grid_buckets(q) — shared with admission
 
@@ -798,7 +817,7 @@ class LocalCluster:
         with tm.step("randomness"):
             key, k_enc = jax.random.split(key)
             enc_rs = eg.random_scalars(k_enc, dp_stats.shape)
-        f_enc, f_agg, f_ks, f_dec = self._fused()
+        f_enc, f_agg, f_dec = self._fused()
         enc_tile = enc_tiles.auto_tile(V)
         PROCESS.count("h2d_bytes", dp_stats.nbytes)
         with tm.step("enc"):
@@ -939,26 +958,21 @@ class LocalCluster:
         # --- Key switch to the querier's key ----------------------------
         mark("keyswitch")
         tm.start("KeySwitchingPhase")
-        with tm.step("secrets"):
-            srv_x = jnp.asarray(np.stack([eg.secret_to_limbs(c.secret)
-                                          for c in self.cns]))
-            PROCESS.count("h2d_bytes", srv_x.nbytes)
-        with tm.step("randomness"):
-            key, k_ks = jax.random.split(key)
-            ks_rs = eg.random_scalars(k_ks, (len(self.cns), V))
-        # per-server contributions, batched over (ns, V):
-        # U = r·B,  W = r·Q − x·K   (commuting; sum replaces the CN chain);
-        # the fused program also subtracts the public aggregate shift
-        # (n_dps * u^l/2)·B so decrypted values are true signed statistics
-        total = range_offset * len(dp_idents)  # one offset per RESPONDER
-        assert total < 2 ** 62, "offset too large for int64 scalar path"
-        with tm.step("switch"):
-            switched, u_pts, w_pts = f_ks(
-                agg, ks_rs, srv_x, jnp.asarray(total, dtype=jnp.int64))
-            switched.block_until_ready()
+        key, k_ks = jax.random.split(key)
+        # one pass a computing node, U = r·B, W = r·Q − x·K (commuting; the
+        # sum replaces the CN chain); the finish also subtracts the public
+        # aggregate shift (n_dps * u^l/2)·B so decrypted values are true
+        # signed statistics: one offset per RESPONDER
+        switched, kept = self.key_switch(
+            k_ks, agg, range_offset * len(dp_idents), tm=tm, keep=proofs_on)
         tm.end("KeySwitchingPhase")
         if proofs_on:
             key, k_kp = jax.random.split(key)
+            # the proof batch is as wide as the roster: every node's own
+            # contribution and scalars, stacked in roster order
+            u_pts, w_pts, ks_rs = (jnp.stack(c) for c in zip(*kept))
+            srv_x = jnp.asarray(np.stack([eg.secret_to_limbs(c.secret)
+                                          for c in self.cns]))
             pr = ks_proof.create_keyswitch_proofs(
                 k_kp, agg[:, 0], srv_x, ks_rs, self.client_pt,
                 self.client_tbl.table, u_pts, w_pts)
@@ -1139,29 +1153,6 @@ def _fused_enc(coll_tbl, stats, enc_rs):
 @jax.jit
 def _fused_agg(cts):
     return B.tree_reduce_add(cts, eg.ct_add)
-
-
-@_stored
-@jax.jit
-def _fused_ks(q_tbl, agg, ks_rs, srv_x, offset_total):
-    # key switch: per-server contributions + reduce (commuting sum
-    # replaces the CN chain — parallel/collective.py derivation)
-    base_tbl = eg.BASE_TABLE.table
-    K0 = agg[:, 0]
-    u_pts = eg.fixed_base_mul(base_tbl, ks_rs)      # (ns, V, 3, 16)
-    rQ = eg.fixed_base_mul(q_tbl, ks_rs)
-    xK = C.scalar_mul(K0[None], srv_x[:, None, :])
-    w_pts = C.add(rQ, C.neg(xK))
-    k_sum = B.tree_reduce_add(u_pts, C.add)
-    c_sum = B.tree_reduce_add(w_pts, C.add)
-    c2 = C.add(agg[:, 1], c_sum)
-    # signed-offset correction; offset 0 gives 0*B = infinity which
-    # is the group identity, so the same program serves both cases
-    corr = eg.fixed_base_mul(
-        base_tbl, eg.int_to_scalar(offset_total[None]))
-    c2 = C.add(c2, C.neg(jnp.broadcast_to(corr[0], c2.shape)))
-    switched = jnp.stack([k_sum, c2], axis=-3)
-    return switched, u_pts, w_pts
 
 
 @_stored

@@ -260,7 +260,7 @@ class StreamEngine:
                                        self.query_min, self.query_max))
             for d in dp_idents]).astype(np.int64)
         enc_rs = eg.random_scalars(self._pane_key(1, pid), stats.shape)
-        f_enc, _f_agg, _f_ks, _f_dec = self.cluster._fused()
+        f_enc, _f_agg, _f_dec = self.cluster._fused()
         with self.cluster._proof_device_lock:
             tile = enc_tiles.auto_tile(self.V)
             if tile:
@@ -501,15 +501,11 @@ class StreamEngine:
 
         # --- key switch + decrypt + decode (execute_survey tail) ---------
         tm.start("KeySwitchingPhase")
-        _f_enc, _f_agg, f_ks, f_dec = cluster._fused()
+        _f_enc, _f_agg, f_dec = cluster._fused()
         with cluster._proof_device_lock:
-            srv_x = jnp.asarray(np.stack(
-                [eg.secret_to_limbs(c.secret) for c in cluster.cns]))
-            ks_rs = eg.random_scalars(
+            switched, _ = cluster.key_switch(
                 jax.random.fold_in(self._pane_key(3, new_first), new_last),
-                (len(cluster.cns), self.V))
-            switched, _u, _w = f_ks(agg_n, ks_rs, srv_x,
-                                    jnp.asarray(0, dtype=jnp.int64))
+                agg_n, tm=tm)
             xq = jnp.asarray(eg.secret_to_limbs(cluster.client.secret))
             dl = cluster.dlog
             vals, found, zeros = f_dec(switched, xq, dl.keys, dl.xs,

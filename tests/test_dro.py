@@ -325,9 +325,9 @@ def test_a_diffp_sum_survey_adds_a_member_of_the_list(cluster, monkeypatch,
 # --- the stored programs ------------------------------------------------------
 
 def test_the_slab_programs_are_stored_beside_the_four():
-    assert svc.LocalCluster.FUSED[:4] == ("_fused_enc", "_fused_agg",
-                                          "_fused_ks", "_fused_dec")
-    assert svc.LocalCluster.FUSED[4:7] == dro.PROGRAMS == (
+    assert svc.LocalCluster.FUSED[:5] == (
+        "_fused_enc", "_fused_agg", "_ks_pass", "_ks_finish", "_fused_dec")
+    assert svc.LocalCluster.FUSED[5:8] == dro.PROGRAMS == (
         "_dro_noise_enc", "_dro_zero_enc", "_dro_permute_add")
     for name in dro.PROGRAMS:
         prog = getattr(dro, name)

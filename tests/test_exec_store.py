@@ -15,11 +15,14 @@ import pytest
 
 from drynx_tpu.crypto import field, pallas_ops, pallas_pairing
 from drynx_tpu.encoding import tiles as enc_tiles
+from drynx_tpu.parallel import keyswitch as kswitch
 from drynx_tpu.service import service as svc
 from drynx_tpu.utils import exec_store as es
 from drynx_tpu.utils.timers import PROCESS, ProcessTracer
 
-FUSED = ("_fused_enc", "_fused_agg", "_fused_ks", "_fused_dec")
+# the fused survey programs and where each lives
+FUSED = {"_fused_enc": svc, "_fused_agg": svc, "_ks_pass": kswitch,
+         "_ks_finish": kswitch, "_fused_dec": svc}
 
 
 @jax.jit
@@ -424,10 +427,10 @@ def test_counters_and_spans_land_on_the_process_tracer(tmp_path, engage,
 
 
 @pytest.mark.parametrize("name", FUSED)
-def test_on_the_cpu_the_four_names_are_the_plain_jits(name, tmp_path):
+def test_on_the_cpu_the_fused_names_are_the_plain_jits(name, tmp_path):
     """The test tier configures a persistent cache directory
     (tests/conftest.py), and still the store does not engage: no TPU."""
-    prog = getattr(svc, name)
+    prog = getattr(FUSED[name], name)
     assert isinstance(prog, es.StoredProgram) and es.active() is None
     assert prog.program == prog.__name__ == name
     assert prog.lower == prog.jit.lower

@@ -17,6 +17,7 @@ from drynx_tpu.crypto import curve as C
 from drynx_tpu.crypto import elgamal as eg
 from drynx_tpu.crypto import params, refimpl
 from drynx_tpu.parallel import dro
+from drynx_tpu.parallel import keyswitch as kswitch
 from drynx_tpu.parallel import obfuscation as obf
 from drynx_tpu.service import node as node_mod
 from drynx_tpu.service import service as svc
@@ -105,7 +106,7 @@ def test_the_pass_is_the_reference_multiplication_limb_for_limb(lanes):
 def test_the_program_is_stored_beside_the_seven():
     assert obf.PROGRAMS == ("_obf_scalar_mul",)
     assert svc.LocalCluster.FUSED == (
-        "_fused_enc", "_fused_agg", "_fused_ks", "_fused_dec") \
+        "_fused_enc", "_fused_agg") + kswitch.PROGRAMS + ("_fused_dec",) \
         + dro.PROGRAMS + obf.PROGRAMS
     prog = obf._obf_scalar_mul
     assert isinstance(prog, es.StoredProgram) and es.active() is None

@@ -326,7 +326,7 @@ STEPS = {
     None: ["probe", "checkpoint", "fetch", "decode", "finalize"],
     "DataCollectionProtocol": ["local_stats", "randomness", "enc"],
     "AggregationPhase": ["reduce", "canon"],
-    "KeySwitchingPhase": ["secrets", "randomness", "switch"],
+    "KeySwitchingPhase": ["secrets", "randomness", "pass", "finish"],
     "Decryption": ["dec"],
 }
 CTS_BYTES = N_DPS * BUCKETS * 2 * 3 * 16 * 4
@@ -401,5 +401,7 @@ def test_a_survey_leaves_every_step_inside_its_parent(tiled, monkeypatch):
         == up + down + 2 * CTS_BYTES * bool(tiled)
     assert counted["h2d_bytes"] == up + CTS_BYTES * bool(tiled)
     assert counted["surveys"] == 1
+    assert counted["ks_contributions"] == 3 * BUCKETS
     assert set(counted) <= {"h2d_bytes", "d2h_bytes", "surveys",
-                            "compile_requests", "cache_hits"}
+                            "ks_contributions", "compile_requests",
+                            "cache_hits"}
