@@ -156,7 +156,7 @@ def scalar_mul(p, k_limbs):
     """k * P. k_limbs: (..., 16) plain (non-Montgomery) scalar limbs.
 
     Dispatches to the Pallas ladder kernel on TPU (whole windowed ladder in
-    one kernel, limbs on sublanes / batch on lanes — crypto/pallas_ops.py);
+    one kernel on limb tiles, 1 024 lanes a tile — crypto/pallas_ops.py);
     elsewhere, the compact 256-step jnp ladder below (see its docstring for
     why the fallback is deliberately NOT windowed). Replaces kyber Point.Mul
     at e.g. reference lib/range/range_proof.go:326 and the ElGamal

@@ -15,14 +15,14 @@ those (Jacobian, Z == 0 at infinity) — the same representation as
 crypto/field.py / crypto/curve.py, transposed.
 
 That "sublane" layout (limbs on sublanes, B = 128 lanes: a field element is
-two vregs) still describes `_scalar_mul_kernel`, `_point_add_kernel`,
-`_point_reduce_kernel` and the kernels of crypto/pallas_pairing.py. Since
-PR 33 `_fixed_base_kernel` keeps a field element as "limb tiles" instead:
-16 tiles of (8, 128) uint32, limb l of TILE_LANES = 1 024 lanes in one vreg,
-so every step of a carry, borrow or reduction chain is one whole-vreg
-operation and no row is ever moved (the sublane product spends half its
-operations on sublane rotates and selects). Each layout is a `Field` bundle;
-`make_group` writes the group law once over either.
+two vregs) still describes `_point_add_kernel`, `_point_reduce_kernel` and
+the kernels of crypto/pallas_pairing.py. The two ladders, `_fixed_base_kernel`
+(PR 33) and `_scalar_mul_kernel` (PR 35), keep a field element as "limb
+tiles" instead: 16 tiles of (8, 128) uint32, limb l of TILE_LANES = 1 024
+lanes in one vreg, so every step of a carry, borrow or reduction chain is one
+whole-vreg operation and no row is ever moved (the sublane product spends
+half its operations on sublane rotates and selects). Each layout is a `Field`
+bundle; `make_group` writes the group law once over either.
 """
 from __future__ import annotations
 
@@ -49,7 +49,7 @@ _ONE_MONT = np.asarray(params.to_limbs(params.R % params.P), dtype=np.uint32)
 
 LANES = 128                      # batch tile width, sublane layout
 TILE_LANES = 8 * LANES           # batch tile width of the limb-tile layout
-                                 # (_fixed_base_kernel only): one vreg a limb
+                                 # (the two ladders'): one vreg a limb
 
 # DRYNX_PALLAS_INTERPRET=1 runs the kernels through the Pallas interpreter
 # (any backend) — used by the CPU test suite to cover the kernel code paths.
@@ -325,9 +325,18 @@ TILE_FIELD = Field(mul=tile_mont_mul, add=tile_fadd, sub=tile_fsub,
 # written once over a layout's `Field`
 # ---------------------------------------------------------------------------
 
-def make_group(field: Field):
-    """The G1 group law over one layout's field functions; returns (double,
-    add_complete, add_mixed)."""
+class Group(NamedTuple):
+    """`make_group`'s G1 group law: the doubling, the complete addition,
+    the mixed (Jacobian + affine) addition and the Jacobian addition of
+    operands known to be unequal."""
+    pdouble: Callable
+    padd: Callable
+    pmadd: Callable
+    paddu: Callable
+
+
+def make_group(field: Field) -> Group:
+    """The G1 group law over one layout's field functions."""
     mul, add_, sub_ = field.mul, field.add, field.sub
     is_zero, select = field.is_zero, field.select
 
@@ -350,7 +359,10 @@ def make_group(field: Field):
         Z3 = add_(YZ, YZ)
         return (X3, Y3, Z3)
 
-    def padd(p, q):
+    def _add_distinct(p, q):
+        """Jacobian + Jacobian where neither is infinity and q != p; for
+        q == -p, H == 0 gives Z3 == 0 by itself. 16 products. Also hands
+        back what `padd` reads to tell its cases apart."""
         X1, Y1, Z1 = p
         X2, Y2, Z2 = q
         Z1Z1 = mul(Z1, Z1)
@@ -372,10 +384,10 @@ def make_group(field: Field):
         t1 = add_(Z1, Z2)
         ZZ = sub_(sub_(mul(t1, t1), Z1Z1), Z2Z2)
         Z3 = mul(ZZ, H)
-        res = (X3, Y3, Z3)
+        return (X3, Y3, Z3), is_zero(Z1), is_zero(Z2), H, r
 
-        p_inf = is_zero(Z1)
-        q_inf = is_zero(Z2)
+    def padd(p, q):
+        res, p_inf, q_inf, H, r = _add_distinct(p, q)
         h0 = is_zero(H)
         r0 = is_zero(r)
         res = select(h0 & r0 & ~p_inf & ~q_inf, pdouble(p), res)
@@ -383,6 +395,19 @@ def make_group(field: Field):
         res = select(q_inf, p, res)
         res = select(p_inf, q, res)
         return res
+
+    def paddu(p, q):
+        """p + q, both Jacobian, for operands the caller knows to be
+        UNEQUAL: 16 products, the 7 of the doubling that the complete
+        `padd` computes in every lane and then discards left out.
+
+        Complete for infinity on either side and for q == -p (Z3 == 0 by
+        itself; X3, Y3 are then not `inf_like`'s). NOT for q == p (it
+        would give Z3 == 0 too): a variable-base ladder over descending
+        windows never meets it for a scalar below the group order
+        (`_scalar_mul_kernel`)."""
+        res, p_inf, q_inf, _, _ = _add_distinct(p, q)
+        return select(p_inf, q, select(q_inf, p, res))
 
     def pmadd(p, x2, y2, q_inf):
         """p + q for an AFFINE addend q = (x2, y2), q_inf (B,) bool where q
@@ -408,114 +433,128 @@ def make_group(field: Field):
                      (X3, Y3, Z3))
         return select(q_inf, p, res)
 
-    return pdouble, padd, pmadd
+    return Group(pdouble, padd, pmadd, paddu)
 
 
 # ---------------------------------------------------------------------------
 # Windowed scalar-mult kernel: whole ladder in one pallas_call
 # ---------------------------------------------------------------------------
 
-def _scalar_mul_kernel(m_ref, np_ref, p_ref, k_ref, o_ref, dig_ref,
-                       *, n_windows: int = 64):
-    m = m_ref[:]                              # (16, 1) modulus limbs
-    nprime = np_ref[0, 0]
-    pdouble, padd, _ = make_group(sublane_field(m, nprime))
+def _scalar_mul_kernel(p_ref, k_ref, o_ref, tab_ref, dig_ref):
+    """On limb tiles (TILE_FIELD): p_ref and o_ref (3, 16, 8, 128), k_ref
+    (16, 8, 128), tab_ref (15, 3, 16, 8, 128): the table d * P, d in 1..15,
+    at d - 1 (d = 0 is infinity, a constant), dig_ref (W, 8, 128): the 4-bit
+    digits, MSB first. W < 64 serves scalars known to be < 16^W (62-bit RLC
+    weights: 16 windows). The table build and the four doublings of a
+    window are loops, so that the trace holds `pdouble` and `paddu` twice
+    each and not 29 and 15 times: every embedding of this kernel in a stored
+    program lowers all of it again.
 
-    P = (p_ref[0], p_ref[1], p_ref[2])        # each (16, B)
-    k = k_ref[:]                              # (16, B)
+    A window is 4 doublings and ONE addition that handles infinity on
+    either side and opposite operands and no other special case, because
+    equal operands cannot arise: the windows descend, so before a window
+    the accumulator is 16 m P, m the digits read so far, and the addend
+    d P with d in 1..15; for m >= 1, 0 < 16 m - d and 16 m + d <= k < n, so
+    they are neither the same nor opposite points when P has order n (G1
+    has prime order, so every P but infinity has) and k < n. k is made
+    canonical first (n / 2^255 > 1: one subtraction), so that holds for
+    every 256-bit input. The same inside the table build: 2 j P + P with
+    2 j < 16. P at infinity makes every entry infinity: the selects' case."""
+    group = make_group(TILE_FIELD)
+    pdouble, paddu = group.pdouble, group.paddu
+    W = dig_ref.shape[0]
 
-    # table[d] = d*P: T[2k]=dbl(T[k]), T[2k+1]=T[2k]+P (7 dbl + 7 add)
-    tab = [_inf_like(P), P]
-    for d in range(2, 16):
-        tab.append(pdouble(tab[d // 2]) if d % 2 == 0
-                   else padd(tab[d - 1], P))
-    # stack for per-lane constant-time select: (16, 3, 16, B)
-    tabX = jnp.stack([t[0] for t in tab])
-    tabY = jnp.stack([t[1] for t in tab])
-    tabZ = jnp.stack([t[2] for t in tab])
+    def load(ref, *at):
+        return tuple([ref[at + (c, l)] for l in range(NL)] for c in range(3))
 
-    # n_windows 4-bit digits, MSB-first rows, staged in a VMEM scratch so
-    # the loop body can dynamic-slice them (register arrays cannot be
-    # dynamically indexed in Mosaic). n_windows < 64 serves scalars known
-    # to be < 16^n_windows (e.g. 62-bit RLC weights: 16 windows, 4x fewer
-    # ladder steps than the generic 256-bit path).
-    rows = []
-    for w in range(n_windows - 1, -1, -1):
-        limb, s = divmod(w, 4)
-        rows.append((k[limb] >> np.uint32(4 * s)) & np.uint32(0xF))
-    dig_ref[:] = jnp.stack(rows)              # (n_windows, B) MSB first
+    def store(ref, pt, *at):
+        for c in range(3):
+            for l in range(NL):
+                ref[at + (c, l)] = pt[c][l]
 
-    def select(d):
-        # per-lane table lookup via 16 selects (constant-time)
-        accX, accY, accZ = tabX[0], tabY[0], tabZ[0]
-        for v in range(1, 16):
-            mask = (d == v)[None, :]
-            accX = jnp.where(mask, tabX[v], accX)
-            accY = jnp.where(mask, tabY[v], accY)
-            accZ = jnp.where(mask, tabZ[v], accZ)
-        return (accX, accY, accZ)
+    k = tile_canonical_scalar([k_ref[l] for l in range(NL)])
+    for w in range(W):                        # row 0 the top digit
+        limb, s = divmod(W - 1 - w, 4)
+        dig_ref[w] = (k[limb] >> np.uint32(4 * s)) & np.uint32(0xF)
 
-    acc0 = select(dig_ref[0])
+    store(tab_ref, load(p_ref), 0)
 
-    def body(w, acc):
-        acc = pdouble(pdouble(pdouble(pdouble(acc))))
-        d = dig_ref[pl.ds(w, 1), :][0]
-        return padd(acc, select(d))
+    def build(j, _):
+        # T[2j] = 2 T[j], T[2j+1] = T[2j] + P, j in 1..7 (T[d] at d - 1)
+        even = pdouble(load(tab_ref, j - 1))
+        store(tab_ref, even, 2 * j - 1)
+        store(tab_ref, paddu(even, load(p_ref)), 2 * j)
 
     # int32 bounds: with jax_enable_x64 a python-int fori_loop carries an
     # i64 induction var, which Mosaic cannot lower
-    acc = jax.lax.fori_loop(jnp.int32(1), jnp.int32(n_windows), body, acc0)
-    o_ref[0] = acc[0]
-    o_ref[1] = acc[1]
-    o_ref[2] = acc[2]
+    jax.lax.fori_loop(jnp.int32(1), jnp.int32(8), build, None)
+
+    def select(d):
+        # per-lane table lookup: 15 whole-vreg selects a word
+        masks = [d == np.uint32(v) for v in range(1, 16)]
+        inf = _tile_inf_like(([d] * NL,) * 3)
+        out = []
+        for c in range(3):
+            words = []
+            for l in range(NL):
+                word = inf[c][l]
+                for v in range(1, 16):
+                    word = jnp.where(masks[v - 1], tab_ref[v - 1, c, l], word)
+                words.append(word)
+            out.append(words)
+        return tuple(out)
+
+    def body(w, acc):
+        acc = jax.lax.fori_loop(jnp.int32(0), jnp.int32(4),
+                                lambda _, a: pdouble(a), acc)
+        return paddu(acc, select(dig_ref[w]))
+
+    acc = jax.lax.fori_loop(jnp.int32(1), jnp.int32(W), body,
+                            select(dig_ref[0]))
+    store(o_ref, acc)
 
 
 @functools.partial(jax.jit, static_argnames=("n_windows", "interpret"))
 def _scalar_mul_flat(p, k, n_windows: int, interpret: bool):
     N = p.shape[0]
-    n_tiles = max((N + LANES - 1) // LANES, 1)
-    Np = n_tiles * LANES
-    pt = _pad_lanes(jnp.transpose(p, (1, 2, 0)), Np)   # (3, 16, Np)
-    kt = _pad_lanes(jnp.transpose(k, (1, 0)), Np)      # (16, Np)
-
-    m_in = jnp.asarray(_M_FP[:, None], dtype=jnp.uint32)
-    np_in = jnp.asarray([[_NPRIME_FP]], dtype=jnp.uint32)
+    n_tiles = max((N + TILE_LANES - 1) // TILE_LANES, 1)
+    Np = n_tiles * TILE_LANES
+    rows = Np // LANES
+    # (N, ..., 16) -> (..., 16, Np / 128, 128): lane n of the batch is row
+    # n // 128, column n % 128 of every limb's plane, one transpose either
+    # way, as `_fixed_base_mul_flat` lays its scalars out
+    pt = jnp.transpose(
+        _pad_lanes(p, Np, axis=0).reshape(rows, LANES, 3, NL), (2, 3, 0, 1))
+    kt = jnp.transpose(_pad_lanes(k, Np, axis=0).reshape(rows, LANES, NL),
+                       (2, 0, 1))
+    pt_spec = pl.BlockSpec((3, NL, 8, LANES), lambda i: (0, 0, i, 0),
+                           memory_space=pltpu.VMEM)
     # x64 mode would make BlockSpec index maps / loop bounds i64, which
     # Mosaic cannot legalize; every value here is uint32, so drop to x32
     with jax.enable_x64(False):
-        out = _pallas_scalar_mul(m_in, np_in, pt, kt, n_tiles, Np,
-                                 n_windows, interpret)
-    return jnp.transpose(out, (2, 0, 1))[:N]
+        out = pl.pallas_call(
+            _scalar_mul_kernel,
+            grid=(n_tiles,),
+            in_specs=[
+                pt_spec,
+                pl.BlockSpec((NL, 8, LANES), lambda i: (0, i, 0),
+                             memory_space=pltpu.VMEM),
+            ],
+            out_specs=pt_spec,
+            out_shape=jax.ShapeDtypeStruct((3, NL, rows, LANES), jnp.uint32),
+            scratch_shapes=[pltpu.VMEM((15, 3, NL, 8, LANES), jnp.uint32),
+                            pltpu.VMEM((n_windows, 8, LANES), jnp.uint32)],
+            interpret=interpret,
+        )(pt, kt)
+    return jnp.transpose(out, (2, 3, 0, 1)).reshape(Np, 3, NL)[:N]
 
 
 def scalar_mul_flat(p, k, n_windows: int = 64):
     """k*P batched: p (N, 3, 16) Jacobian Montgomery, k (N, 16) plain
-    scalars -> (N, 3, 16). Pads N up to a LANES multiple and tiles.
-    n_windows < 64 truncates the ladder for short scalars (k < 16^W)."""
+    scalars, any 256-bit value (reduced mod n inside) -> (N, 3, 16). Pads N
+    up to a TILE_LANES multiple and tiles. n_windows < 64 truncates the
+    ladder for short scalars (k < 16^W)."""
     return _scalar_mul_flat(p, k, n_windows, INTERPRET)
-
-
-def _pallas_scalar_mul(m_in, np_in, pt, kt, n_tiles, Np, n_windows=64,
-                       interpret=False):
-    return pl.pallas_call(
-        functools.partial(_scalar_mul_kernel, n_windows=n_windows),
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((NL, 1), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1), lambda i: (0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((3, NL, LANES), lambda i: (0, 0, i),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((NL, LANES), lambda i: (0, i),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((3, NL, LANES), lambda i: (0, 0, i),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((3, NL, Np), jnp.uint32),
-        scratch_shapes=[pltpu.VMEM((n_windows, LANES), jnp.uint32)],
-        interpret=interpret,
-    )(m_in, np_in, pt, kt)
 
 
 # ---------------------------------------------------------------------------
@@ -542,7 +581,7 @@ def _fixed_base_kernel(tab_ref, k_ref, o_ref, dig_ref):
     (k mod 16^w) is a multiple of the group order n, which k < n excludes
     (n / 2^252 = 8.98 and G1 has prime order). k is made canonical first,
     so that holds for every 256-bit input."""
-    _, _, pmadd = make_group(TILE_FIELD)
+    pmadd = make_group(TILE_FIELD).pmadd
     k = tile_canonical_scalar([k_ref[l] for l in range(NL)])
     W = dig_ref.shape[0]
     for w in range(W):                        # little-endian digit order
@@ -632,7 +671,7 @@ def fixed_base_mul_flat(table, k, n_windows: int = 64):
 
 def _point_add_kernel(m_ref, np_ref, p_ref, q_ref, o_ref):
     m = m_ref[:]
-    _, padd, _ = make_group(sublane_field(m, np_ref[0, 0]))
+    padd = make_group(sublane_field(m, np_ref[0, 0])).padd
     r = padd((p_ref[0], p_ref[1], p_ref[2]),
              (q_ref[0], q_ref[1], q_ref[2]))
     o_ref[0], o_ref[1], o_ref[2] = r
@@ -641,7 +680,7 @@ def _point_add_kernel(m_ref, np_ref, p_ref, q_ref, o_ref):
 def _point_reduce_kernel(m_ref, np_ref, p_ref, o_ref):
     """p_ref: (R, 3, 16, B) — sum rows 0..R-1 with the complete group add."""
     m = m_ref[:]
-    _, padd, _ = make_group(sublane_field(m, np_ref[0, 0]))
+    padd = make_group(sublane_field(m, np_ref[0, 0])).padd
     R = p_ref.shape[0]
     acc = (p_ref[0, 0], p_ref[0, 1], p_ref[0, 2])
     for r in range(1, R):                     # R is small + static: unroll
@@ -743,6 +782,7 @@ def available() -> bool:
 
 __all__ = ["scalar_mul_flat", "fixed_base_mul_flat", "point_add_flat",
            "point_reduce_flat", "mont_mul", "fadd", "fsub", "make_group",
+           "Group",
            "canonical_scalar", "Field", "sublane_field", "TILE_FIELD",
            "tile_mont_mul", "tile_fadd", "tile_fsub", "tile_canonical_scalar",
            "available", "LANES", "TILE_LANES"]

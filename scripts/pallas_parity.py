@@ -143,14 +143,43 @@ def main():
 
     check("gt_pow_fixed_multi (window-table digit pow)", c_gt_pow_fixed_multi)
 
+    def ladder_bases(n):
+        # a base a lane, every eighth the point at infinity; few distinct
+        # points, so that the Python oracle's bill is the multiplications'
+        pool = [refimpl.g1_mul(refimpl.G1, 3 + i) for i in range(7)] + [None]
+        return [pool[i % 8] for i in range(n)]
+
+    def ladder_check(ks, n_windows, sampled):
+        """`po.scalar_mul_flat` over the lanes of ks, a base a lane,
+        against the oracle on the lanes `sampled` and at infinity
+        everywhere a scalar is 0 mod n or the base is infinity."""
+        n = params.N
+        pts = ladder_bases(len(ks))
+        got = po.scalar_mul_flat(jnp.asarray(C.from_ref_batch(pts)),
+                                 jnp.asarray(F.from_int(ks)),
+                                 n_windows=n_windows)
+        assert got.shape == (len(ks), 3, 16)
+        at_inf = ~np.asarray(got)[:, 2].any(axis=-1)
+        want_inf = [p is None or k % n == 0 for p, k in zip(pts, ks)]
+        assert at_inf.tolist() == want_inf
+        ref = C.to_ref(got[np.asarray(sampled)])
+        for i, r in zip(sampled, ref):
+            want = None if pts[i] is None else refimpl.g1_mul(pts[i],
+                                                              ks[i] % n)
+            assert r == want, (i, hex(ks[i]))
+
     def c_ladder16():
-        ks = [0, 1, (1 << 62) - 3, 0x1234567890ABCDEF]
-        pts = [refimpl.g1_mul(refimpl.G1, 3 + i) for i in range(len(ks))]
-        pd = jnp.asarray(C.from_ref_batch(pts))
-        kd = jnp.asarray(F.from_int(ks))
-        got = po.scalar_mul_flat(pd, kd, n_windows=16)
-        for i, (p, k) in enumerate(zip(pts, ks)):
-            assert C.to_ref(got[i]) == refimpl.g1_mul(p, k), i
+        # 16 windows serve scalars below 16^16; 2 windows below 256
+        small = [0, 1, 15, 16, (1 << 62) - 3, 0x1234567890ABCDEF,
+                 16 ** 16 - 1, 16 ** 15, 0xF0F0 << 40]
+        ks = small + [int.from_bytes(rng.bytes(8), "little")
+                      for _ in range(po.TILE_LANES + 8)] + small
+        edge = list(range(len(small))) + list(
+            range(len(ks) - len(small), len(ks)))
+        seam = list(range(po.TILE_LANES - 4, po.TILE_LANES + 4))
+        ladder_check(ks, 16, edge + seam + [100, 500, 777])
+        tiny = [0, 1, 15, 16, 17, 200, 255, 0xF0, 0x0F]
+        ladder_check(tiny, 2, list(range(len(tiny))))
 
     check("scalar_mul_flat n_windows=16 (62-bit ladder)", c_ladder16)
 
@@ -200,12 +229,26 @@ def main():
     check("f12_mulreduce8_flat (8-way GT product)", c_mulreduce8)
 
     def c_ladder64():
-        ks = [0, 1, params.N - 1, rfp() % params.N]
-        pts = [refimpl.g1_mul(refimpl.G1, 11 + i) for i in range(len(ks))]
-        got = po.scalar_mul_flat(jnp.asarray(C.from_ref_batch(pts)),
-                                 jnp.asarray(F.from_int(ks)))
-        for i, (p, k) in enumerate(zip(pts, ks)):
-            assert C.to_ref(got[i]) == refimpl.g1_mul(p, k), i
+        # the kernel reduces k mod n first and adds without the doubling
+        # case: the edges of both over two 1 024-lane tiles (at the first
+        # tile's start, across the seam, at the second tile's end in its
+        # padded last rows), every eighth base the point at infinity
+        n = params.N
+        edges = [0, 1, 2, 12345, n - 1, n, n + 1, 2 ** 256 - 1,
+                 (8 << 252) + 12345, 15 << 248, 16 ** 63, 0xF0F0 << 100,
+                 (n - 1) // 2, n - 16, n + 15, 0xF]
+        seam = [n - 1, n, n + 1, 2 ** 256 - 1, 1, (8 << 252) + 12345, 0,
+                16 ** 63]
+        ks = edges + [int.from_bytes(rng.bytes(32), "little")
+                      for _ in range(po.TILE_LANES - len(edges) - 4)]
+        ks += seam + [int.from_bytes(rng.bytes(32), "little")
+                      for _ in range(72)] + edges
+        assert len(ks) > po.TILE_LANES + 64
+        sampled = (list(range(len(edges)))
+                   + list(range(po.TILE_LANES - 4, po.TILE_LANES + 4))
+                   + list(range(len(ks) - len(edges), len(ks)))
+                   + [int(i) for i in rng.integers(16, len(ks) - 16, 24)])
+        ladder_check(ks, 64, sampled)
 
     check("scalar_mul_flat (full 64-window ladder)", c_ladder64)
 

@@ -19,7 +19,8 @@ hardware-validated kernels (scripts/pallas_parity.py):
     whole kernel-body Python runs abstractly — shape/dtype/index logic
     and API drift are exercised without the XLA compile or the
     eager-interpret execution bill. Measured trace costs on this box:
-    fixed_base 4 s, ladder16/64 ~40 s, f12_mul+inv 43 s, miller 84 s,
+    fixed_base 4 s, ladder16/64 25 s (limb tiles, PR 35), f12_mul+inv 43 s,
+    miller 84 s,
     wpow@63 116 s, mulreduce8 121 s, g2_ladder 190 s (worst day);
   * "glue" — entries whose DEVICE kernels all have their own rotation
     day (order_gate = slotmul/wpow/mul; gt_pow_fixed_multi = gather +

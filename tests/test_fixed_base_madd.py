@@ -1,11 +1,12 @@
-"""The fixed-base ladder's window step as plain functions, in both layouts.
+"""The two ladders' window steps as plain functions, in both layouts.
 
 `pallas_ops._fixed_base_kernel` adds a table entry to its accumulator with
 the mixed (Jacobian + affine) addition of `make_group` and makes its scalar
-canonical first. `make_group` writes the group law once over a layout's
-`Field`: the sublane bundle ((16, B) arrays; the other G1 kernels') and the
-limb-tile bundle (16 arrays a field element; the fixed-base kernel's since
-PR 33). All are plain jnp functions, so they run here eagerly, outside any
+canonical first; `_scalar_mul_kernel` adds one with `paddu`, the Jacobian
+addition that leaves the doubling out (PR 35). `make_group` writes the group
+law once over a layout's `Field`: the sublane bundle ((16, B) arrays; the
+add and reduce kernels') and the limb-tile bundle (16 arrays a field element;
+the two ladders'). All are plain jnp functions, so they run here eagerly, outside any
 `pallas_call` and outside the interpreter (the whole ladder through the
 interpreter is tests/test_pallas_kernels.py), against Python integers and
 `refimpl`, and the two layouts against each other byte for byte. And the
@@ -38,6 +39,17 @@ def _ints(tile):
     """(16, B) array, or a list of 16 (B,) limbs -> ints."""
     return [int(v) for v in F.to_int(np.stack(
         [np.asarray(limb) for limb in tile]).T)]
+
+
+def _points(pts):
+    """[(X, Y, Z) ints] -> the three coordinates' tiles, one lane each."""
+    return tuple(_tile([pt[c] for pt in pts]) for c in range(3))
+
+
+def _limbs(pt):
+    """A layout's point -> (3, 16, B) numpy, whichever layout made it."""
+    return np.stack([np.stack([np.asarray(limb) for limb in coord])
+                     for coord in pt])
 
 
 # layout -> its Field; both take and give field elements that `_tile` makes
@@ -83,8 +95,7 @@ CASES = {
 def _pmadd(layout, p, x2, y2, z2):
     with jax.enable_x64(False):
         field = FIELDS[layout]()
-        _, _, pmadd = po.make_group(field)
-        return pmadd(p, x2, y2, field.is_zero(z2))
+        return po.make_group(field).pmadd(p, x2, y2, field.is_zero(z2))
 
 
 @pytest.fixture(scope="module", params=list(FIELDS))
@@ -92,9 +103,8 @@ def madd_lanes(request):
     """One eager call of a layout's mixed addition, one lane a case."""
     accs = [_jacobian(a, z) for a, z, _ in CASES.values()]
     ents = [_jacobian(q, 1) for _, _, q in CASES.values()]
-    p = tuple(_tile([a[c] for a in accs]) for c in range(3))
-    x2, y2, z2 = (_tile([e[c] for e in ents]) for c in range(3))
-    out = _pmadd(request.param, p, x2, y2, z2)
+    p = _points(accs)
+    out = _pmadd(request.param, p, *_points(ents))
     return p, tuple(_ints(t) for t in out)
 
 
@@ -125,13 +135,75 @@ def test_layouts_agree_byte_for_byte():
     accs[1] = accs[3] = inf
     ents[2] = ents[3] = inf
     ents[4] = (_rand(P), _rand(P), 0)       # infinity with arbitrary x, y
-    p = tuple(_tile([a[c] for a in accs]) for c in range(3))
-    x2, y2, z2 = (_tile([e[c] for e in ents]) for c in range(3))
-    sub = np.asarray(jnp.stack(_pmadd("sublane", p, x2, y2, z2)))
-    tile = np.stack([np.stack([np.asarray(limb) for limb in coord])
-                     for coord in _pmadd("tile", p, x2, y2, z2)])
+    p, q = _points(accs), _points(ents)
+    sub = _limbs(_pmadd("sublane", p, *q))
+    tile = _limbs(_pmadd("tile", p, *q))
     assert sub.shape == tile.shape == (3, params.NUM_LIMBS, n)
     assert (sub == tile).all()
+
+
+def _group_add(layout, which, p, q):
+    """`padd` or `paddu` of a layout, eagerly."""
+    with jax.enable_x64(False):
+        return getattr(po.make_group(FIELDS[layout]()), which)(p, q)
+
+
+# name -> (p affine, its Z, q affine, its Z): what a variable-base ladder's
+# window step can meet, which is everything but q == p
+ADDU_CASES = {
+    "distinct": (A, _rand(P), B_, _rand(P)),
+    "distinct_2": (C_, 1, A, _rand(P)),
+    "p_at_infinity": (None, 0, B_, _rand(P)),
+    "q_at_infinity": (A, _rand(P), None, 0),
+    "both_at_infinity": (None, 0, None, 0),
+    "opposite_points": (A, _rand(P), NEG_A, _rand(P)),
+}
+
+
+@pytest.fixture(scope="module", params=list(FIELDS))
+def addu_lanes(request):
+    """One eager call of a layout's `paddu`, one lane a case."""
+    ps = [_jacobian(a, z) for a, z, _, _ in ADDU_CASES.values()]
+    qs = [_jacobian(b, z) for _, _, b, z in ADDU_CASES.values()]
+    out = _group_add(request.param, "paddu", _points(ps), _points(qs))
+    return ps, qs, tuple(_ints(t) for t in out)
+
+
+@pytest.mark.parametrize("case", list(ADDU_CASES))
+def test_unequal_addition_matches_oracle(addu_lanes, case):
+    ps, qs, out = addu_lanes
+    lane = list(ADDU_CASES).index(case)
+    a, _, b, _ = ADDU_CASES[case]
+    got = tuple(c[lane] for c in out)
+    assert all(v < P for v in got)
+    assert _affine(*got) == refimpl.g1_add(a, b)
+    if a is None:
+        assert got == qs[lane]          # the addend, untouched
+    elif b is None:
+        assert got == ps[lane]          # the accumulator, untouched
+
+
+@pytest.mark.parametrize("layout", list(FIELDS))
+def test_unequal_addition_is_the_complete_one_on_unequal_operands(layout):
+    """Limb for limb `padd`'s answer wherever the operands differ: random
+    finite lanes, infinity planted on either side and on both (opposite
+    operands give Z3 == 0 in both, with other X3, Y3: the oracle's case
+    above). And the tile layout's bytes are the sublane layout's."""
+    n = 12
+    ps = [_jacobian(refimpl.g1_mul(refimpl.G1, _rand(N)), _rand(P))
+          for _ in range(n)]
+    qs = [_jacobian(refimpl.g1_mul(refimpl.G1, _rand(N)), _rand(P))
+          for _ in range(n)]
+    inf = _jacobian(None, 0)
+    ps[1] = ps[3] = inf
+    qs[2] = qs[3] = inf
+    qs[4] = (_rand(P), _rand(P), 0)         # infinity with arbitrary x, y
+    p, q = _points(ps), _points(qs)
+    fast = _limbs(_group_add(layout, "paddu", p, q))
+    assert fast.shape == (3, params.NUM_LIMBS, n)
+    assert (fast == _limbs(_group_add(layout, "padd", p, q))).all()
+    if layout == "tile":
+        assert (fast == _limbs(_group_add("sublane", "paddu", p, q))).all()
 
 
 # operands whose sum or product needs the final subtraction, and whose

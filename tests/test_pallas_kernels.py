@@ -55,33 +55,23 @@ def _assert_points_equal(a, b):
     assert bool(np.all(np.asarray(ay)[fin] == np.asarray(by)[fin]))
 
 
-@heavy
-def test_scalar_mul_kernel_matches_jnp():
-    n = 4
-    p, _ = _rand_points(n)
-    k, _ = _rand_scalars(n)
-    k = k.at[0].set(0)  # edge: zero scalar -> infinity
-    out_pallas = po.scalar_mul_flat(p, k)
-    out_jnp = C._scalar_mul_jnp(p, k)
-    _assert_points_equal(out_pallas, out_jnp)
-
-
-# What the limb-tile fixed-base kernel (PR 33) costs the interpreter: its
-# window step is 31 000 whole-vreg operations on arrays of ONE shape, and the
-# CPU compiler's instruction fusion does not come back from merging them (a
-# chain of two products compiles in 37 s for one's 5 s; the loop body ran
-# half an hour unfinished). So the kernel is compiled ahead of time with
-# that one pass off, 75-100 s a (n_windows, tiles) pair on the 8-core
-# sandbox, and a pair is compiled once for every lane count, table and test
-# that pads to it: the wrapper's own padding, transposes and table
-# flattening run eagerly around it, as `_fixed_base_mul_flat` writes them.
+# What a limb-tile ladder kernel costs the interpreter: the fixed-base
+# window step (PR 33) is 31 000 whole-vreg operations on arrays of ONE shape,
+# and the CPU compiler's instruction fusion does not come back from merging
+# them (a chain of two products compiles in 37 s for one's 5 s; the loop body
+# ran half an hour unfinished). So a kernel is compiled ahead of time with
+# that one pass off, 75-100 s a (n_windows, tiles) pair of the fixed-base
+# kernel on the 8-core sandbox, and a pair is compiled once for every lane
+# count, table and test that pads to it: the wrapper's own padding,
+# transposes and table flattening run eagerly around it, as
+# `_fixed_base_mul_flat` writes them.
 _UNFUSED = {}
 
 
 @pytest.fixture
-def fixed_base_interpreted(monkeypatch):
-    """`run(table, k, n_windows)`: `po._fixed_base_mul_flat`, its kernel
-    through the Pallas interpreter."""
+def unfused_interpreter(monkeypatch):
+    """`po.pl.pallas_call` through the Pallas interpreter, compiled with
+    the CPU compiler's fusion pass off."""
     real = po.pl.pallas_call
 
     def pallas_call(kernel, **kw):
@@ -97,6 +87,12 @@ def fixed_base_interpreted(monkeypatch):
         return run
 
     monkeypatch.setattr(po.pl, "pallas_call", pallas_call)
+
+
+@pytest.fixture
+def fixed_base_interpreted(unfused_interpreter):
+    """`run(table, k, n_windows)`: `po._fixed_base_mul_flat`, its kernel
+    through the Pallas interpreter."""
     return lambda table, k, n_windows=64: po._fixed_base_mul_flat.__wrapped__(
         table, k, n_windows, True)
 
@@ -177,33 +173,134 @@ def test_fixed_base_kernel_short_ladder_pads_to_a_tile(
     assert C.to_ref(out[:8]) == [_mul(base, s) for s in ss[:8]]
 
 
+class Ref:
+    """An array that stands for a kernel's ref: every read and write is
+    of one whole limb (eight lanes here: the limb-tile functions take any
+    shape), at static or traced-then-concrete indices."""
+
+    def __init__(self, a):
+        self.a = np.array(a)
+        self.shape = self.a.shape
+
+    @staticmethod
+    def _at(i):
+        return tuple(int(x) for x in (i if isinstance(i, tuple) else (i,)))
+
+    def __getitem__(self, i):
+        return jnp.asarray(self.a[self._at(i)])
+
+    def __setitem__(self, i, v):
+        self.a[self._at(i)] = np.asarray(v)
+
+
+def _python_loop(lo, hi, body, carry):
+    for i in range(int(lo), int(hi)):
+        carry = body(jnp.int32(i), carry)
+    return carry
+
+
+def _eagerly(kernel, *refs):
+    """A kernel's body as a plain function, its `fori_loop`s Python loops
+    (under `jax.disable_jit` every jnp operation would leave the dispatch
+    fast path too: four times the seconds)."""
+    with pytest.MonkeyPatch.context() as mp, jax.enable_x64(False):
+        mp.setattr(jax.lax, "fori_loop", _python_loop)
+        kernel(*refs)
+
+
 def test_fixed_base_kernel_two_windows(monkeypatch):
     """The kernel's body as a plain function on arrays that stand for its
-    refs (eight lanes a limb: the limb-tile functions take any shape), two
-    windows, eagerly: the digit order, the table's slice and its flat
-    layout at the smallest `n_windows`, which the interpreter would compile
-    a third time for."""
-    class Ref:
-        def __init__(self, a):
-            self.a = np.array(a)
-            self.shape = self.a.shape
-
-        def __getitem__(self, i):          # every read is of one row
-            return jnp.asarray(self.a[int(i)])
-
-        def __setitem__(self, i, v):
-            self.a[i] = np.asarray(v)
-
+    refs, two windows, eagerly: the digit order, the table's slice and its
+    flat layout at the smallest `n_windows`, which the interpreter would
+    compile a third time for."""
     ss = [0, 1, 15, 16, 17, 200, 255, 0xF0]
     out = Ref(np.zeros((3, params.NUM_LIMBS, len(ss)), np.uint32))
-    with jax.disable_jit(), jax.enable_x64(False):
-        po._fixed_base_kernel(
-            Ref(po._flat_table(eg.BASE_TABLE.table[:2])),
-            Ref(F.from_int(ss).T), out,
-            Ref(np.zeros((2, len(ss)), np.uint32)))
+    _eagerly(po._fixed_base_kernel,
+             Ref(po._flat_table(eg.BASE_TABLE.table[:2])),
+             Ref(F.from_int(ss).T), out,
+             Ref(np.zeros((2, len(ss)), np.uint32)))
     _leave_interpreter(monkeypatch)
     got = C.to_ref(jnp.asarray(out.a.transpose(2, 0, 1)))
     assert got == [_mul(refimpl.G1, s) for s in ss]
+
+
+# lane -> (scalar, whether its base is the point at infinity): the
+# variable-base kernel's two-window body sees (k mod n) mod 256
+TWO_WINDOWS = {
+    "0": (0, False), "1": (1, False), "15": (15, False), "16": (16, False),
+    "17": (17, False), "255": (255, False), "0xF0": (0xF0, False),
+    "base_at_infinity": (0x35, True),
+    "n-1": (N_ - 1, False), "n": (N_, False), "n+1": (N_ + 1, False),
+    "2^256-1": (2 ** 256 - 1, False),
+}
+
+
+@pytest.fixture(scope="module")
+def scalar_mul_two_windows():
+    """`po._scalar_mul_kernel`'s body as a plain function on arrays that
+    stand for its refs, ONE eager run (the table loop's 161 products and a
+    window's 44: some 15 s), every lane its own base."""
+    ks = [k for k, _ in TWO_WINDOWS.values()]
+    bases = [None if inf else refimpl.g1_mul(refimpl.G1, 0xBA5E + 77 * i)
+             for i, (_, inf) in enumerate(TWO_WINDOWS.values())]
+    n = len(ks)
+    p = np.asarray(C.from_ref_batch(bases)).transpose(1, 2, 0)
+    out = Ref(np.zeros((3, params.NUM_LIMBS, n), np.uint32))
+    tab = Ref(np.zeros((15, 3, params.NUM_LIMBS, n), np.uint32))
+    dig = Ref(np.zeros((2, n), np.uint32))
+    _eagerly(po._scalar_mul_kernel, Ref(p), Ref(F.from_int(ks).T), out, tab,
+             dig)
+    return bases, dig.a, tab.a, out.a
+
+
+def _to_ref(pt):
+    """(3, 16) Montgomery Jacobian limbs -> affine ints or None, in Python
+    integers alone."""
+    rinv = pow(params.R, -1, params.P)
+    x, y, z = (int(v) * rinv % params.P for v in F.to_int(np.asarray(pt)))
+    if z == 0:
+        return None
+    zi = pow(z, -1, params.P)
+    return x * zi * zi % params.P, y * zi ** 3 % params.P
+
+
+@pytest.mark.parametrize("lane", list(TWO_WINDOWS))
+def test_scalar_mul_kernel_two_windows(scalar_mul_two_windows, lane):
+    """Digits MSB first, of the scalar made canonical FIRST (n - 1, n,
+    n + 1, 2^256 - 1: the low byte of k mod n, not of k), the table
+    d * P at d - 1 for d in 1..15, and the window's 4 doublings and one
+    addition, against the Python oracle; a base at infinity gives
+    infinity."""
+    bases, dig, tab, out = scalar_mul_two_windows
+    i = list(TWO_WINDOWS).index(lane)
+    k, _ = TWO_WINDOWS[lane]
+    low = k % N_ % 256
+    assert (int(dig[0, i]), int(dig[1, i])) == divmod(low, 16)
+    assert _to_ref(out[:, :, i]) == _mul(bases[i], low)
+    for d in (1, 2, 3, 8, 15):
+        assert _to_ref(tab[d - 1, :, :, i]) == _mul(bases[i], d), d
+
+
+@heavy
+@pytest.mark.slow(reason="609 s on the 8-core sandbox (PR 35): the "
+                  "variable-base ladder's 140 000 operations through the "
+                  "interpreter, the fusion pass off")
+def test_scalar_mul_kernel_matches_jnp(unfused_interpreter, monkeypatch):
+    """All 64 windows of the variable-base kernel through the interpreter,
+    one tile: zero, one, the scalars around the group order, a base at
+    infinity, random scalars, against the jnp ladder and the oracle."""
+    ss = [0, 1, N_ - 1, N_, N_ + 1, 2 ** 256 - 1, (8 << 252) + 12345] + [
+        int.from_bytes(RNG.bytes(32), "little") for _ in range(3)]
+    p, pts = _rand_points(len(ss))
+    p = p.at[4].set(C.infinity())
+    pts[4] = None
+    k = jnp.asarray(F.from_int(ss))
+    out = po._scalar_mul_flat.__wrapped__(p, k, 64, True)
+    assert out.shape == (len(ss), 3, params.NUM_LIMBS)
+    _leave_interpreter(monkeypatch)
+    assert C.to_ref(out) == [_mul(b, s) for b, s in zip(pts, ss)]
+    reduced = jnp.asarray(F.from_int([s % N_ for s in ss]))
+    _assert_points_equal(out, C._scalar_mul_jnp(p, reduced))
 
 
 @heavy

@@ -18,7 +18,8 @@ seconds (sandbox seconds, not device metrics; run with -s to see them).
 A case stays in tier-1 only while it takes under 30 s alone on the 8-core
 sandbox and the file under 120 s in all; the others are marked slow with
 the lower+compile seconds measured there in PR 21 (seven cases at a time,
-so some 1.5x what each takes alone).
+so some 1.5x what each takes alone; the variable-base ladder's two on limb
+tiles alone, PR 35).
 """
 import json
 import os
@@ -75,9 +76,9 @@ def s(one_chip):
     return spec
 
 
-def _slow(seconds):
+def _slow(seconds, pr=21):
     return pytest.mark.slow(
-        reason=f"{seconds} s lower+compile on the 8-core sandbox (PR 21)")
+        reason=f"{seconds} s lower+compile on the 8-core sandbox (PR {pr})")
 
 
 # name -> builder(s) returning the jax.stages.Lowered; `s` is the fixture
@@ -132,6 +133,8 @@ CASES = [
      lambda s: pp._f2_inv_flat.lower(s((B, 2, NL)), interpret=False)),
     ("f12_inv_flat",
      lambda s: pp._f12_inv_flat.lower(_gt(s), interpret=False)),
+    # on limb tiles since PR 35: 2 048 lanes are two tiles; 54 s of the 62
+    # the lowering of 140 000 operations (30 s from a script)
     ("scalar_mul_flat/16w",
      lambda s: po._scalar_mul_flat.lower(_g1(s), _k(s), n_windows=16,
                                          interpret=False)),
@@ -171,8 +174,8 @@ _MARKS = {
     "f12_mul_flat": _slow(43),
     "f12_inv_flat": _slow(108),
     "point_reduce_flat/R10": _slow(129),
-    "scalar_mul_flat/16w": _slow(197),
-    "scalar_mul_flat/64w": _slow(222),
+    "scalar_mul_flat/16w": _slow(62, pr=35),
+    "scalar_mul_flat/64w": _slow(63, pr=35),
     "miller_flat": _slow(385),
     "f12_wpow_flat/128c": _slow(462),
     "f12_wpow_flat/63c": _slow(478),
@@ -234,15 +237,16 @@ def test_the_noise_phases_add_compiles_for_v5e(s, no_persistent_cache,
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
-@pytest.mark.slow(reason="152 s lower+compile on the 8-core sandbox (PR 32): "
-                  "the 64-window variable-base ladder's lowering")
+@pytest.mark.slow(reason="44 s lower+compile on the 8-core sandbox (PR 35; "
+                  "152 s before the ladder moved to limb tiles): the "
+                  "64-window variable-base ladder's lowering")
 def test_the_obfuscation_pass_compiles_for_v5e(s, no_persistent_cache,
                                                monkeypatch):
     """`parallel/obfuscation._obf_scalar_mul` as a TPU traces it, at the
     obfuscated grid cell's width (12 288 ciphertexts, 12 288 lanes a
     component, no padding to a power of two): the variable-base ladder
-    under the name the benchmark's patterns read, and no scratch (108 s
-    lower + 45 s compile on the 8-core sandbox, temp size 0 B, PR 32)."""
+    under the name the benchmark's patterns read, and no scratch (36 s
+    lower + 7 s compile on the 8-core sandbox, temp size 0 B, PR 35)."""
     from drynx_tpu.parallel import obfuscation as obf
 
     monkeypatch.setattr(po, "INTERPRET", False)
@@ -252,8 +256,10 @@ def test_the_obfuscation_pass_compiles_for_v5e(s, no_persistent_cache,
         f"obf_scalar_mul@{v}",
         lambda: obf._obf_scalar_mul.lower(s((v, 2, 3, NL)), s((v, NL))))
     text = compiled.as_text()
-    # a component a call, V lanes each (the decryption's shape): nothing
-    # is padded to a power of two
-    assert "%_scalar_mul_flat" in text and f"u32[3,16,{v}]" in text
-    assert "16384" not in text
+    # a component a call, V lanes each (the decryption's shape), as limb
+    # tiles: 12 whole tiles of 1 024 lanes, nothing is padded to a power
+    # of two
+    assert "%_scalar_mul_flat" in text
+    assert f"u32[3,16,{v // po.LANES},{po.LANES}]" in text
+    assert "16384" not in text and "u32[3,16,128,128]" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
