@@ -146,6 +146,24 @@ def add(p, q):
     return out
 
 
+def sum_points(pts):
+    """The sum over axis 0 of points (R, ..., 3, 16) -> (..., 3, 16).
+
+    On a TPU one call of the Pallas reduce kernel (crypto/pallas_ops.py),
+    every point of a row a lane, the rows added one after another;
+    elsewhere `batching.tree_reduce_add`'s jnp log-depth tree. The two fold
+    in other orders, so their Jacobian limbs differ and the group element
+    does not: `topology.canon_points` makes the bytes one."""
+    from . import pallas_ops as po
+    from .batching import tree_reduce_add
+
+    R = pts.shape[0]
+    if po.available() and R > 1:
+        out = po.point_reduce_flat(pts.reshape((R, -1, 3, NUM_LIMBS)))
+        return out.reshape(pts.shape[1:])
+    return tree_reduce_add(pts, add)
+
+
 @jax.jit
 def neg(p):
     Y = F.neg(p[..., 1, :], FP)

@@ -678,13 +678,18 @@ def _point_add_kernel(m_ref, np_ref, p_ref, q_ref, o_ref):
 
 
 def _point_reduce_kernel(m_ref, np_ref, p_ref, o_ref):
-    """p_ref: (R, 3, 16, B) — sum rows 0..R-1 with the complete group add."""
+    """p_ref: (R, 3, 16, B) — sum rows 0..R-1 with the complete group add,
+    one row a step of a loop over the ref's leading axis, so that one
+    `padd` is traced and lowered whatever R is."""
     m = m_ref[:]
     padd = make_group(sublane_field(m, np_ref[0, 0])).padd
-    R = p_ref.shape[0]
+
+    def body(r, acc):
+        return padd(acc, (p_ref[r, 0], p_ref[r, 1], p_ref[r, 2]))
+
     acc = (p_ref[0, 0], p_ref[0, 1], p_ref[0, 2])
-    for r in range(1, R):                     # R is small + static: unroll
-        acc = padd(acc, (p_ref[r, 0], p_ref[r, 1], p_ref[r, 2]))
+    acc = jax.lax.fori_loop(jnp.int32(1), jnp.int32(p_ref.shape[0]), body,
+                            acc)
     o_ref[0], o_ref[1], o_ref[2] = acc
 
 
@@ -763,7 +768,8 @@ def _point_reduce_flat(pts, interpret: bool):
 
 def point_reduce_flat(pts):
     """Group-add reduce over axis 0: (R, N, 3, 16) -> (N, 3, 16), one
-    kernel call (replaces log2(R) jnp tree-reduce rounds)."""
+    kernel call (replaces log2(R) jnp tree-reduce rounds; the DPs'
+    aggregate, `service._fused_agg`, and flagship's reductions)."""
     return _point_reduce_flat(pts, INTERPRET)
 
 

@@ -55,29 +55,6 @@ class SurveySetup:
         )
 
 
-def _tree_reduce_points(pts):
-    """Reduce axis 0 of a point/ct tensor by repeated halving (log2 depth);
-    on TPU the whole reduction is one Pallas kernel call."""
-    from .crypto import pallas_ops as po
-
-    if po.available() and pts.shape[0] > 1:
-        R = pts.shape[0]
-        mid = pts.shape[1:-2]
-        out = po.point_reduce_flat(pts.reshape((R, -1, 3, 16)))
-        return out.reshape(mid + (3, 16))
-    n = pts.shape[0]
-    while n > 1:
-        half = n // 2
-        even = pts[: 2 * half : 2]
-        odd = pts[1 : 2 * half : 2]
-        red = C.add(even, odd)
-        if n % 2:
-            red = jnp.concatenate([red, pts[-1:]], axis=0)
-        pts = red
-        n = pts.shape[0]
-    return pts[0]
-
-
 def build_pipeline(setup: SurveySetup, params: lr.LRParams):
     """Single-chip jitted survey step.
 
@@ -100,13 +77,13 @@ def build_pipeline(setup: SurveySetup, params: lr.LRParams):
         cts = eg.encrypt_ints_with_tables(base_tbl, coll_tbl, dp_stats,
                                           enc_rs)
         # Collective aggregation (CN tree -> on-chip tree reduce).
-        agg = _tree_reduce_points(cts)
+        agg = C.sum_points(cts)
         # Key switch: per-server contributions (broadcast batch — one big
         # flat batch feeds the Pallas ladder kernel), then reduce.
         kc, cc = col.keyswitch_contribution(
             agg[None], srv_x[:, None, :], ks_rs, q_tbl)
         switched = col.keyswitch_finish(
-            agg, _tree_reduce_points(kc), _tree_reduce_points(cc))
+            agg, C.sum_points(kc), C.sum_points(cc))
         # Querier decrypt + discrete log.
         pts = eg.decrypt_point(switched, qx)
         dec, found = eg._table_lookup(keys, xs, ysign, vals, pts)

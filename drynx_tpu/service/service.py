@@ -529,7 +529,7 @@ class LocalCluster:
         def enc(stats, enc_rs):
             return _fused_enc(coll_tbl, stats, enc_rs)
 
-        return enc, _fused_agg, _fused_dec
+        return enc, _aggregate, _fused_dec
 
     # the stored programs of a survey, for the set-up report: the fused
     # phases with the key switch's node pass and finish among them, the
@@ -1152,7 +1152,23 @@ def _fused_enc(coll_tbl, stats, enc_rs):
 @_stored
 @jax.jit
 def _fused_agg(cts):
-    return B.tree_reduce_add(cts, eg.ct_add)
+    """The DPs' ciphertexts (n_dps, V, 2, 3, 16) summed over the DPs: on a
+    TPU one call of the Pallas reduce kernel, each component of each
+    bucket a lane (`curve.sum_points`)."""
+    return C.sum_points(cts)
+
+
+def _aggregate(cts):
+    """`_fused_agg(cts)`, and on the kernel path the point additions the
+    kernel made, (n_dps - 1) x 2V, under the counter
+    `agg_kernel_additions`; the jnp tree counts none."""
+    from ..crypto import pallas_ops as po
+
+    out = _fused_agg(cts)
+    if po.available():
+        n_dps, V, n_comps = cts.shape[:3]
+        PROCESS.count("agg_kernel_additions", (n_dps - 1) * V * n_comps)
+    return out
 
 
 @_stored

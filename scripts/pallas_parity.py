@@ -322,6 +322,50 @@ def main():
     check("dro_permute_add (point_add_flat under the noise phase's slab)",
           c_dro_permute_add)
 
+    def c_point_reduce():
+        # the grid cells' aggregate (service._fused_agg): 10 DPs' rows over
+        # 2 x 12 288 lanes, one call of the reduce kernel against the jnp
+        # tree, byte for byte after canon_points (the two fold in other
+        # orders), with every branch of the complete addition planted in
+        # the first tile, across a seam and in the last tile: a lane at
+        # infinity in the first row, in the last and in all rows, the
+        # second row equal to the first and the negation of the first, and
+        # a row equal to the running sum before it in another
+        # representation
+        from drynx_tpu.service import service as svc
+        from drynx_tpu.service import topology as topo
+
+        R, v = 10, 12288
+        n = 2 * v
+        ks = eg.random_scalars(jax.random.PRNGKey(37), (R * n,))
+        pts = np.array(eg.fixed_base_mul(eg.BASE_TABLE.table, ks)).reshape(
+            R, n, 3, 16)
+        inf = np.asarray(C.infinity())
+        for at in (0, 5 * po.LANES - 3, n - 8):
+            pts[0, at] = inf
+            pts[R - 1, at + 1] = inf
+            pts[:, at + 2] = inf
+            pts[1, at + 3] = pts[0, at + 3]
+            pts[1, at + 4] = np.asarray(C.neg(jnp.asarray(pts[0, at + 4])))
+            run = jnp.asarray(pts[0, at + 5])
+            for r in range(1, 4):
+                run = C.add(run, jnp.asarray(pts[r, at + 5]))
+            pts[4, at + 5] = np.asarray(topo.canon_points(run))
+        d = jnp.asarray(pts)
+        got = np.asarray(po.point_reduce_flat(d))
+        tree = jax.jit(lambda x: B.tree_reduce_add(x, C.add))(d)
+        want = np.asarray(topo.canon_points(tree))
+        canon = np.asarray(topo.canon_points(jnp.asarray(got)))
+        off = np.argwhere((canon != want).any(axis=(1, 2)))
+        assert off.size == 0, off[:8].tolist()
+        at_inf = np.flatnonzero(~want[:, 2].any(axis=-1)).tolist()
+        assert at_inf == [2, 5 * po.LANES - 1, n - 6], at_inf
+        agg = svc._fused_agg(d.reshape(R, v, 2, 3, 16))
+        assert np.array_equal(np.asarray(agg).reshape(n, 3, 16), got)
+
+    check("point_reduce_flat (service._fused_agg at the grid cells' "
+          "10 x 24 576 lanes)", c_point_reduce)
+
     def c_g2_ladder():
         ks = [1, 7, params.N - 1]
         from drynx_tpu.crypto import g2 as G2

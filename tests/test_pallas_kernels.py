@@ -60,19 +60,32 @@ def _assert_points_equal(a, b):
 # and the CPU compiler's instruction fusion does not come back from merging
 # them (a chain of two products compiles in 37 s for one's 5 s; the loop body
 # ran half an hour unfinished). So a kernel is compiled ahead of time with
-# that one pass off, 75-100 s a (n_windows, tiles) pair of the fixed-base
-# kernel on the 8-core sandbox, and a pair is compiled once for every lane
-# count, table and test that pads to it: the wrapper's own padding,
-# transposes and table flattening run eagerly around it, as
-# `_fixed_base_mul_flat` writes them.
+# that one pass off, and a (n_windows, tiles) pair of the fixed-base kernel
+# is compiled once for every lane count, table and test that pads to it:
+# the wrapper's own padding, transposes and table flattening run eagerly
+# around it, as `_fixed_base_mul_flat` writes them.
 _UNFUSED = {}
+_NO_FUSION = {"xla_disable_hlo_passes": "fusion"}
+# The interpreter's field functions, each one jitted callee: the kernel's
+# products and sums are calls of three computations, traced, lowered and
+# compiled once each, where inlined every product was 1 500 operations of
+# its own. 15 s lower + compile for the 16-window fixed-base kernel on one
+# tile, 116 s inlined (8-core CPU host).
+_NO_FUSION_NO_INLINING = {"xla_disable_hlo_passes": "fusion,call-inliner"}
+_TILE_FIELD_CALLS = po.TILE_FIELD._replace(
+    mul=jax.jit(po.tile_mont_mul), add=jax.jit(po.tile_fadd),
+    sub=jax.jit(po.tile_fsub))
 
 
 @pytest.fixture
 def unfused_interpreter(monkeypatch):
-    """`po.pl.pallas_call` through the Pallas interpreter, compiled with
-    the CPU compiler's fusion pass off."""
+    """`po.pl.pallas_call` through the Pallas interpreter, its field
+    functions jitted callees of both layouts, compiled with the CPU
+    compiler's fusion and call inlining off."""
     real = po.pl.pallas_call
+    monkeypatch.setattr(po, "TILE_FIELD", _TILE_FIELD_CALLS)
+    for name in ("mont_mul", "fadd", "fsub"):
+        monkeypatch.setattr(po, name, jax.jit(getattr(po, name)))
 
     def pallas_call(kernel, **kw):
         call = real(kernel, **kw)
@@ -82,7 +95,7 @@ def unfused_interpreter(monkeypatch):
                    tuple(a.shape for a in args))
             if key not in _UNFUSED:
                 _UNFUSED[key] = jax.jit(call).lower(*args).compile(
-                    compiler_options={"xla_disable_hlo_passes": "fusion"})
+                    compiler_options=_NO_FUSION_NO_INLINING)
             return _UNFUSED[key](*args)
         return run
 
@@ -199,28 +212,60 @@ def _python_loop(lo, hi, body, carry):
     return carry
 
 
+def _unfused_jit(fn):
+    """`fn` compiled whole, with the fusion pass off (a limb-tile product:
+    0.8 s for 5.7 s), once for each shape of its arguments."""
+    compiled = {}
+
+    def run(*args):
+        leaves, tree = jax.tree_util.tree_flatten(args)
+        key = (tree, tuple((np.shape(x), np.result_type(x)) for x in leaves))
+        if key not in compiled:
+            compiled[key] = jax.jit(fn).lower(*args).compile(
+                compiler_options=_NO_FUSION)
+        return compiled[key](*args)
+    return run
+
+
+# Both layouts' products, sums and differences, each compiled whole: op by
+# op a kernel's body is tens of thousands of operations, each dispatched
+# alone (a sublane `padd` 12 000 of them, 0.9 s). They compile once for a
+# lane count, so the limb-tile bodies below run on EAGER_LANES lanes each.
+EAGER_LANES = 12
+_SUBLANE_JITS = {name: _unfused_jit(getattr(po, name))
+                 for name in ("mont_mul", "fadd", "fsub")}
+_TILE_FIELD_JIT = po.TILE_FIELD._replace(
+    mul=_unfused_jit(po.tile_mont_mul), add=_unfused_jit(po.tile_fadd),
+    sub=_unfused_jit(po.tile_fsub))
+
+
 def _eagerly(kernel, *refs):
-    """A kernel's body as a plain function, its `fori_loop`s Python loops
-    (under `jax.disable_jit` every jnp operation would leave the dispatch
-    fast path too: four times the seconds)."""
+    """A kernel's body as a plain function, its `fori_loop`s Python loops,
+    its field functions `_SUBLANE_JITS` / `_TILE_FIELD_JIT`, and the group
+    law, the loops and the refs the kernel's own (under `jax.disable_jit`
+    every jnp operation would leave the dispatch fast path too: four times
+    the seconds)."""
     with pytest.MonkeyPatch.context() as mp, jax.enable_x64(False):
         mp.setattr(jax.lax, "fori_loop", _python_loop)
+        mp.setattr(po, "TILE_FIELD", _TILE_FIELD_JIT)
+        for name, fn in _SUBLANE_JITS.items():
+            mp.setattr(po, name, fn)
         kernel(*refs)
 
 
-def test_fixed_base_kernel_two_windows(monkeypatch):
+def test_fixed_base_kernel_two_windows():
     """The kernel's body as a plain function on arrays that stand for its
     refs, two windows, eagerly: the digit order, the table's slice and its
     flat layout at the smallest `n_windows`, which the interpreter would
     compile a third time for."""
-    ss = [0, 1, 15, 16, 17, 200, 255, 0xF0]
+    ss = [0, 1, 15, 16, 17, 200, 255, 0xF0, 0x0F, 0x10, 0x80, 0xFE]
+    assert len(ss) == EAGER_LANES
     out = Ref(np.zeros((3, params.NUM_LIMBS, len(ss)), np.uint32))
     _eagerly(po._fixed_base_kernel,
              Ref(po._flat_table(eg.BASE_TABLE.table[:2])),
              Ref(F.from_int(ss).T), out,
              Ref(np.zeros((2, len(ss)), np.uint32)))
-    _leave_interpreter(monkeypatch)
-    got = C.to_ref(jnp.asarray(out.a.transpose(2, 0, 1)))
+    got = [_to_ref(out.a[:, :, i]) for i in range(len(ss))]
     assert got == [_mul(refimpl.G1, s) for s in ss]
 
 
@@ -239,8 +284,9 @@ TWO_WINDOWS = {
 def scalar_mul_two_windows():
     """`po._scalar_mul_kernel`'s body as a plain function on arrays that
     stand for its refs, ONE eager run (the table loop's 161 products and a
-    window's 44: some 15 s), every lane its own base."""
+    window's 44), every lane its own base."""
     ks = [k for k, _ in TWO_WINDOWS.values()]
+    assert len(ks) == EAGER_LANES
     bases = [None if inf else refimpl.g1_mul(refimpl.G1, 0xBA5E + 77 * i)
              for i, (_, inf) in enumerate(TWO_WINDOWS.values())]
     n = len(ks)
@@ -282,9 +328,9 @@ def test_scalar_mul_kernel_two_windows(scalar_mul_two_windows, lane):
 
 
 @heavy
-@pytest.mark.slow(reason="609 s on the 8-core sandbox (PR 35): the "
-                  "variable-base ladder's 140 000 operations through the "
-                  "interpreter, the fusion pass off")
+@pytest.mark.slow(reason="103 s on an 8-core CPU host: the variable-base "
+                  "ladder's 140 000 operations through the interpreter, "
+                  "the field functions jitted callees (609 s inlined)")
 def test_scalar_mul_kernel_matches_jnp(unfused_interpreter, monkeypatch):
     """All 64 windows of the variable-base kernel through the interpreter,
     one tile: zero, one, the scalars around the group order, a base at
@@ -301,6 +347,93 @@ def test_scalar_mul_kernel_matches_jnp(unfused_interpreter, monkeypatch):
     assert C.to_ref(out) == [_mul(b, s) for b, s in zip(pts, ss)]
     reduced = jnp.asarray(F.from_int([s % N_ for s in ss]))
     _assert_points_equal(out, C._scalar_mul_jnp(p, reduced))
+
+
+# --- the DPs' aggregate on the reduce kernel (service._fused_agg) ---------------
+
+V_AGG = 3                    # buckets: 2 x 3 = 6 lanes, one tile
+
+
+def _reduce_eagerly(pts):
+    """`po.point_reduce_flat` with `_point_reduce_kernel`'s body run as a
+    plain function on arrays that stand for its refs: one tile, the row
+    loop a Python loop."""
+    R, N = pts.shape[:2]
+    rows = Ref(np.asarray(pts).transpose(0, 2, 3, 1))      # (R, 3, 16, N)
+    out = Ref(np.zeros((3, params.NUM_LIMBS, N), np.uint32))
+    _eagerly(po._point_reduce_kernel, po._M_FP[:, None],
+             np.full((1, 1), po._NPRIME_FP), rows, out)
+    return jnp.asarray(out.a.transpose(2, 0, 1))
+
+
+def _dp_stack(n_dps):
+    """(n_dps, V_AGG, 2, 3, 16) ciphertext components, every case of the
+    complete addition planted: bucket 0 has DP 1 equal to DP 0 (a
+    doubling) in one component and its negation in the other, and DP 2
+    equal to their sum, 2 P, in affine form where the running sum is
+    not; bucket 1 starts at infinity, bucket 2 ends there; DP 5's whole
+    row is infinity."""
+    def mul(k):
+        return refimpl.g1_mul(refimpl.G1, k)
+
+    pts = [[[mul(1 + 11 * d + 3 * v + c) for c in range(2)]
+            for v in range(V_AGG)] for d in range(n_dps)]
+    if n_dps > 1:
+        pts[1][0] = [pts[0][0][0], refimpl.g1_neg(pts[0][0][1])]
+    if n_dps > 2:
+        pts[2][0][0] = refimpl.g1_add(pts[0][0][0], pts[0][0][0])
+    pts[0][1] = [None, None]
+    pts[-1][2] = [None, None]
+    if n_dps > 5:
+        pts[5] = [[None, None]] * V_AGG
+    flat = [p for dp in pts for bucket in dp for p in bucket]
+    return jnp.asarray(C.from_ref_batch(flat).reshape(
+        n_dps, V_AGG, 2, 3, params.NUM_LIMBS))
+
+
+@pytest.mark.parametrize("n_dps", [1, 2, 3, 10])
+def test_fused_agg_on_the_kernel_is_the_trees_sum(n_dps, monkeypatch):
+    """`service._fused_agg` as a TPU traces it, one call of the reduce
+    kernel over the DPs, each component of each bucket a lane: after
+    `topology.canon_points` its bytes are the jnp tree's (`fold_cts`, the
+    fold of the remote paths: `canon_points(B.tree_reduce_add(cts,
+    B.ct_add))`), whatever the fold order; the caller counts
+    (n_dps - 1) x 2V kernel additions."""
+    from drynx_tpu.service import service as svc
+    from drynx_tpu.service import topology as topo
+    from drynx_tpu.utils.timers import PROCESS
+
+    _leave_interpreter(monkeypatch)
+    cts = _dp_stack(n_dps)
+    before = PROCESS.counter("agg_kernel_additions")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(po, "available", lambda: True)
+        mp.setattr(po, "point_reduce_flat", _reduce_eagerly)
+        mp.setattr(svc, "_fused_agg", svc._fused_agg.jit.__wrapped__)
+        got = svc._aggregate(cts)
+    assert PROCESS.counter("agg_kernel_additions") - before \
+        == (n_dps - 1) * 2 * V_AGG
+    assert got.shape == (V_AGG, 2, 3, params.NUM_LIMBS)
+    got = np.asarray(topo.canon_points(got))
+    assert got.tobytes() == np.asarray(topo.fold_cts(cts)).tobytes()
+    assert not got[1, :, 2].any() if n_dps == 1 else got[1, :, 2].any()
+    if n_dps == 2:
+        assert [_to_ref(p) for p in got[0]] == [_mul(refimpl.G1, 2), None]
+
+
+@heavy
+@pytest.mark.parametrize("R", [1, 2, 3, 10])
+def test_point_reduce_kernel_rows(R, monkeypatch):
+    """The rolled row loop through the interpreter, one tile, against a
+    `C.add` fold, with every case of the complete addition among the
+    lanes (those of `_dp_stack`)."""
+    pts = _dp_stack(R).reshape(R, 2 * V_AGG, 3, params.NUM_LIMBS)
+    out = po._point_reduce_flat.__wrapped__(pts, True)
+    _leave_interpreter(monkeypatch)
+    want = pts[0]
+    for r in range(1, R):
+        want = C.add(want, pts[r])
+    _assert_points_equal(out, want)
 
 
 @heavy

@@ -21,6 +21,7 @@ import pytest
 from drynx_tpu.encoding import stats as st
 from drynx_tpu.service.query import DiffPParams
 from drynx_tpu.service.service import LocalCluster
+from drynx_tpu.utils.timers import PROCESS
 
 
 @pytest.fixture(scope="module")
@@ -65,7 +66,10 @@ def test_survey_matches_cleartext(cluster, op):
     per_dp = _install_data(cluster, op, rng)
     qmin, qmax = 0, 15
     sq = cluster.generate_survey_query(op, query_min=qmin, query_max=qmax)
+    kernel_adds = PROCESS.counter("agg_kernel_additions")
     res = cluster.run_survey(sq)
+    # the CPU sums the DPs with the jnp tree: no kernel addition is counted
+    assert PROCESS.counter("agg_kernel_additions") == kernel_adds
 
     allv = np.concatenate(per_dp)
     if op == "sum":

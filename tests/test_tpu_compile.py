@@ -20,6 +20,11 @@ sandbox and the file under 120 s in all; the others are marked slow with
 the lower+compile seconds measured there in PR 21 (seven cases at a time,
 so some 1.5x what each takes alone; the variable-base ladder's two on limb
 tiles alone, PR 35).
+The reduce kernel's rows are a loop, so `point_reduce_flat/R3` and `/R10`
+trace one addition each (8-10 s each on an 8-core CPU host, where R10
+unrolled took 129 s); R10 stays out of tier-1 because
+`test_the_aggregate_compiles_for_v5e` lowers the same 10-row kernel at the
+grid cells' width.
 """
 import json
 import os
@@ -173,7 +178,9 @@ CASES = [
 _MARKS = {
     "f12_mul_flat": _slow(43),
     "f12_inv_flat": _slow(108),
-    "point_reduce_flat/R10": _slow(129),
+    "point_reduce_flat/R10": pytest.mark.slow(
+        reason="8 s lower+compile on an 8-core CPU host, rolled; the same "
+               "10-row kernel as test_the_aggregate_compiles_for_v5e"),
     "scalar_mul_flat/16w": _slow(62, pr=35),
     "scalar_mul_flat/64w": _slow(63, pr=35),
     "miller_flat": _slow(385),
@@ -235,6 +242,27 @@ def test_the_noise_phases_add_compiles_for_v5e(s, no_persistent_cache,
     text = compiled.as_text()
     assert "%_point_add_flat" in text and "gather" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_the_aggregate_compiles_for_v5e(s, no_persistent_cache, monkeypatch):
+    """`service._fused_agg` as a TPU traces it, at the grid cells' shape
+    (10 DPs x 12 288 buckets): ONE call of the reduce kernel over the
+    DPs, each component of each bucket a lane, and a scratch under a GB,
+    where the jnp tree's levels took 12.4 GB (8 s lower + compile on an
+    8-core CPU host, temp size 377 519 616 B)."""
+    from drynx_tpu.service import service as svc
+
+    monkeypatch.setattr(po, "INTERPRET", False)
+    monkeypatch.setattr(po, "available", lambda: True)
+    n_dps, v = 10, 12288
+    compiled = _compile_and_report(
+        f"fused_agg@{n_dps}x{v}",
+        lambda: svc._fused_agg.lower(s((n_dps, v, 2, 3, NL))))
+    text = compiled.as_text()
+    assert "%_point_reduce_flat" in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"u32[{n_dps},3,16,{2 * v}]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
 @pytest.mark.slow(reason="44 s lower+compile on the 8-core sandbox (PR 35; "
